@@ -3,16 +3,17 @@
 
 PY ?= python
 
-.PHONY: test verify examples bench native serve-smoke chaos-smoke \
-	overload-smoke sim-gate lint clean
+.PHONY: test verify examples bench chip-smoke native serve-smoke \
+	chaos-smoke overload-smoke sim-gate lint clean
 
 # full suite on the 8-virtual-device CPU mesh (tests/conftest.py forces it)
 test:
 	$(PY) -m pytest tests/ -q
 
-# quick smoke: native build + fast test subset + every example vertical
-# (examples run on the default platform — TPU when present; set
-# EXAMPLE_PLATFORM=cpu to force host CPU)
+# quick smoke: native build + fast test subset + every example vertical.
+# The tests force the CPU; the examples run on whatever platform JAX is
+# given (JAX_PLATFORMS=cpu for a CPU run) — this target proves nothing
+# about the chip, `make chip-smoke` does.
 verify: native
 	$(PY) -m pytest tests/test_context.py tests/test_data.py \
 	    tests/test_estimator.py -q
@@ -41,9 +42,21 @@ lint:
 	$(PY) -m analytics_zoo_tpu.lint analytics_zoo_tpu/ \
 	    --baseline tpulint_baseline.json
 
-# one-chip benchmark suite (writes the driver-facing JSON line)
+# one-chip benchmark suite (prints the driver-facing JSON line).  Needs
+# the TPU: with no chip, or when any model's child fails, it exits
+# non-zero — nothing is skipped and nothing falls back to the CPU.
 bench:
 	$(PY) bench.py
+
+# the quickest proof that serving and training still start on the chip:
+# Qwen2.5-1.5B-width serving through ClusterServing + HttpFrontend
+# (default, then paged+chunked+fused in bf16 and int8), every Pallas
+# kernel compiled and compared with its reference, BERT-base and the
+# 111M LM through Estimator.fit.  Needs the TPU (one process holds it);
+# `$(PY) chip_smoke.py --chips 4` runs the multi-chip legs on a
+# four-chip host, `--tiny` is the CPU dry run of the same code.
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # serving smoke: the paged KV-cache + chunked-prefill + composed-mode
 # (speculative over blocks/chunks) + telemetry + QoS front-door test

@@ -94,9 +94,9 @@ def make_global_batch(mesh: Mesh, batch: Dict[str, np.ndarray],
     ``pack=True`` ships the whole batch as ONE row-major uint8 buffer
     (one ``device_put``/assembly instead of one per column) and unpacks
     on-device via slice + bitcast.  Each transfer has a fixed dispatch
-    cost — per-call runtime overhead, and a full round-trip latency on
-    tunneled devices — so for many-column batches (recommenders: user,
-    item, label, ...) packing collapses k fixed costs into one.  The
+    cost (per-call runtime overhead), so for many-column batches
+    (recommenders: user, item, label, ...) packing collapses k fixed
+    costs into one.  The
     pack itself is a single host memcpy at DRAM bandwidth.
     """
     sh = sharding or data_sharding(mesh)
@@ -182,13 +182,14 @@ def device_prefetch(batches: Iterator[Dict[str, np.ndarray]], mesh: Mesh, *,
     """Overlap H2D transfer with compute: keep `depth` batches in flight,
     staged by a background thread.
 
-    ``device_put`` is nominally async, but on tunneled/remote devices the
-    call itself blocks for the full transfer — staged on the consumer
-    thread, every step would pay transfer + compute SERIALLY.  A worker
-    thread turns the transfer into true double-buffering: it fills a
-    bounded queue (depth = HBM staging bound) while the main thread
-    dispatches compute.  numpy gather + device_put release the GIL for the
-    copy, so the threads genuinely overlap.
+    On the TPU ``device_put`` itself is asynchronous and its transfer
+    overlaps compute from any thread (checked on the v5e, PR 21: the call
+    returns in under a millisecond, and a 256 MB put beside a 0.38 s
+    program costs nothing extra).  What the worker thread overlaps with
+    the main thread's dispatch is the HOST side of staging — the numpy
+    gather, the pack and the dispatch of the next batches — by filling a
+    bounded queue (depth = HBM staging bound); numpy gather + device_put
+    release the GIL for the copy, so the threads genuinely overlap.
 
     On the CPU backend the transfer is a host memcpy — there is nothing
     to overlap — and a ``device_put`` issued from a second thread can

@@ -545,8 +545,8 @@ class InferenceModel:
         with self._sem:
             out = self._compiled()(
                 self._variables, *padded)
-        # start the D2H transfer now: on tunneled/remote devices the fetch
-        # round-trip dominates, so it must overlap the next batch's compute
+        # start the D2H transfer now, so the fetch overlaps the next
+        # batch's compute instead of following it
         jax.tree.map(lambda x: x.copy_to_host_async(), out)
         return lambda: jax.tree.map(lambda x: np.asarray(x)[:n], out)
 

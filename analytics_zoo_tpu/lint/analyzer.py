@@ -71,8 +71,7 @@ DEFAULT_HOT_PATHS: Tuple[str, ...] = (
     "learn/inference_model.py",
 )
 
-_JIT_CALLS = {"jax.jit", "jit", "pjit", "jax.pjit", "nn.jit", "shard_map",
-              "jax.experimental.shard_map.shard_map"}
+_JIT_CALLS = {"jax.jit", "jit", "pjit", "jax.pjit", "nn.jit", "shard_map"}
 _PARTIAL_CALLS = {"partial", "functools.partial"}
 _DEVICE_GET = {"jax.device_get", "device_get"}
 _NP_CONVERT = {"np.asarray", "np.array", "numpy.asarray", "numpy.array",

@@ -19,7 +19,6 @@ TPU-first re-design, not a translation:
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -53,8 +52,9 @@ BERT_MOE_PARTITION_RULES = _MOE_RULES + BERT_PARTITION_RULES
 def flash_ok(use_flash: Optional[bool], seq_len: int) -> bool:
     """Fused-kernel dispatch policy — ONE home for the measured numbers.
 
-    use_flash=None means auto; the kill-switch env var covers Mosaic
-    lowering failures on future TPU generations without code changes.
+    use_flash=None means auto: the kernel on a TPU from seq 512, XLA
+    attention otherwise.  A Mosaic lowering failure is a failure — there
+    is no switch that quietly gives way to XLA attention.
     Measured on v5e (BERT-base fine-tune through fit, bf16): XLA wins at
     seq 128 (+44%) and 256 (+15%); the Pallas kernel wins from seq 512
     (+20%), where attention turns HBM-bound and fusion pays.  At seq 2048
@@ -62,9 +62,6 @@ def flash_ok(use_flash: Optional[bool], seq_len: int) -> bool:
     whose full-attention logits OOM."""
     if use_flash is not None:
         return use_flash
-    if os.environ.get("ZOO_DISABLE_FLASH", "").lower() not in (
-            "", "0", "false"):
-        return False
     return jax.default_backend() == "tpu" and seq_len >= 512
 
 

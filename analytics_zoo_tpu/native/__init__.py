@@ -4,9 +4,11 @@ The reference's native layer is JNI-bound C++ (SURVEY.md §2.3: OpenVINO
 `libzoo_inference`-style .so, memkind/PMEM FeatureSet tier, OpenCV ops —
 ref: zoo/pipeline/inference/, zoo feature/pmem/).  pybind11 is not in this
 image, so the rebuild binds via a pure C ABI + ctypes.  The shared object is
-compiled from source on first use with g++ (cached next to the source,
-keyed on source mtime), mirroring how the reference ships `make-dist.sh`
-built artifacts.
+compiled from source on first use with g++ and cached next to the source
+under a name that carries the source's content hash — a copied tree has
+meaningless mtimes, so only the content says which binary belongs to
+which ``dataplane.cpp`` — mirroring how the reference ships
+`make-dist.sh` built artifacts.
 
 Exposed wrappers:
   RingBuffer           bounded byte queue; blocking push/pop release the GIL
@@ -19,6 +21,8 @@ Exposed wrappers:
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import struct
 import subprocess
@@ -29,7 +33,6 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "dataplane.cpp")
-_SO = os.path.join(_HERE, "libzoo_dataplane.so")
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -39,12 +42,20 @@ class NativeUnavailable(RuntimeError):
     """Raised when the .so cannot be built (no g++) — callers fall back."""
 
 
-def _build_so() -> str:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
+def _so_path() -> str:
+    """``libzoo_dataplane.<sha256[:16] of dataplane.cpp>.so``."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libzoo_dataplane.{digest}.so")
+
+
+def _build_so(so: str) -> str:
+    """Build ``so`` (a :func:`_so_path` name) unless it already exists."""
+    if os.path.exists(so):
+        return so
     # PID-unique tmp + atomic replace: concurrent first-use builds (multiple
     # worker processes, shared FS) must not corrupt each other's output.
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so}.{os.getpid()}.tmp"
     base = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
             _SRC, "-o", tmp]
     # image decode needs system libjpeg/libpng; retry without if absent so
@@ -54,8 +65,16 @@ def _build_so() -> str:
     for cmd in attempts:
         try:
             subprocess.run(cmd, check=True, capture_output=True, text=True)
-            os.replace(tmp, _SO)
-            return _SO
+            os.replace(tmp, so)
+            # binaries of earlier source revisions are dead weight
+            for old in glob.glob(os.path.join(_HERE,
+                                              "libzoo_dataplane*.so")):
+                if old != so:
+                    try:
+                        os.remove(old)
+                    except OSError:
+                        pass
+            return so
         except FileNotFoundError as e:
             raise NativeUnavailable(f"g++ not found: {e}") from e
         except subprocess.CalledProcessError as e:
@@ -65,10 +84,13 @@ def _build_so() -> str:
 
 def load_lib() -> ctypes.CDLL:
     global _lib
+    if _lib is not None:
+        return _lib
+    so = _so_path()             # hashes the source: not under the lock
     with _lib_lock:
         if _lib is not None:
             return _lib
-        lib = ctypes.CDLL(_build_so())
+        lib = ctypes.CDLL(_build_so(so))
         c = ctypes.c_void_p, ctypes.c_long, ctypes.c_int, ctypes.c_size_t
         P, L, I, S = c
         lib.zrb_create.restype = P

@@ -15,9 +15,10 @@ written on the last k step.  Causal runs skip fully-masked blocks.
 
 Interface matches the model stack: q, k, v are [B, T, H, D]; optional
 ``kv_mask`` [B, Tk] bool (True = attend) covers padding; ``causal`` adds the
-autoregressive mask.  On non-TPU backends the kernels run in Pallas
+autoregressive mask.  On the CPU backend the kernels run in Pallas
 interpret mode, so the same code path is unit-testable on the CPU mesh
-(SURVEY.md §4 single-box test doctrine).
+(SURVEY.md §4 single-box test doctrine); any other non-TPU backend
+raises.
 
 Layout notes (Mosaic): per-row stats (max / logsumexp / delta) are kept as
 [rows, 1] columns end-to-end — including the HBM residual, shaped
@@ -36,13 +37,22 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from analytics_zoo_tpu.parallel.mesh import shard_map as _shard_map
-
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/where NaN-free
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled (Mosaic) on a TPU, Pallas interpret mode on an explicit
+    CPU platform — the tier-1 reference.  Any other backend is an error:
+    a host that was meant to find the chip and did not must not run the
+    kernels interpreted and look healthy."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas attention kernels run compiled on 'tpu' or interpreted "
+        f"on 'cpu'; the default backend is {backend!r}")
 
 
 def _pad_to(x, mult, axis):
@@ -393,7 +403,7 @@ def sharded_flash_attention(q, k, v, mesh, kv_mask=None, *,
 
     if kv_mask is None:
         kv_mask = jnp.ones(q.shape[:1] + k.shape[1:2], bool)
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
         out_specs=qkv_spec, check_vma=False,
@@ -613,11 +623,15 @@ def _paged_fused_kernel(tables_ref, pos_ref, *refs, scale, bs, G, S,
     def _accumulate():
         q = q_ref[0, 0]                                # [SGp, D]
         k = k_ref[0, 0]                                # [bs, D]
+        if quant:
+            # one operand dtype into the MXU; |k| <= 127 is exact in
+            # bf16 and f32 alike, so the cast changes no product
+            k = k.astype(q.dtype)
         s = scale * jax.lax.dot_general(               # [SGp, bs] f32
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if quant:
-            s = s * sk_ref[0].astype(jnp.float32)      # [1, bs] bcast
+            s = s * sk_ref[0, 0].astype(jnp.float32)   # [1, bs] bcast
         rows = acc_ref.shape[0]
         lpos = j * bs + jax.lax.broadcasted_iota(
             jnp.int32, (rows, bs), 1)
@@ -636,7 +650,7 @@ def _paged_fused_kernel(tables_ref, pos_ref, *refs, scale, bs, G, S,
         if quant:
             # fold the v scales into p's columns: (p * sv) @ v_int8
             # == p @ (v_int8 * sv[:, None]) without a [bs, D] dequant
-            p = p * sv_ref[0].astype(jnp.float32)
+            p = p * sv_ref[0, 0].astype(jnp.float32)
             v = v_ref[0, 0].astype(jnp.float32)
         else:
             v = v_ref[0, 0]
@@ -681,10 +695,16 @@ def _paged_attention_fused(q, pool_k, pool_v, tables, pos, interpret):
     ]
     operands = [qf, kd, vd]
     if quant:
-        sspec = pl.BlockSpec((1, 1, bs),
-                             lambda b, h, j, t, p: (t[b, j], h, 0))
+        # the [N, KH, bs] scales ride as [N, KH, 1, bs]: a (1, 1, bs)
+        # block of the 3-D array would put a 1 against KH in the
+        # second-minor (sublane) slot, which Mosaic refuses unless
+        # KH == 1; with the unit axis the tile's last two dims (1, bs)
+        # equal the array's and any KH is legal
+        sspec = pl.BlockSpec((1, 1, 1, bs),
+                             lambda b, h, j, t, p: (t[b, j], h, 0, 0))
         in_specs += [sspec, sspec]
-        operands += [pool_k.scale, pool_v.scale]
+        operands += [pool_k.scale[:, :, None, :],
+                     pool_v.scale[:, :, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, KH, M),
@@ -759,7 +779,7 @@ def _paged_attention_fused_tp(q, pool_k, pool_v, tables, pos, mesh,
             return _paged_attention_fused(qs, kd, vd, t, p, interpret)
         in_specs = (q_spec, pool_spec, pool_spec, tab_spec, pos_spec)
         operands = (q, pool_k, pool_v, tables, pos)
-    return _shard_map(local, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                       out_specs=q_spec, check_vma=False)(*operands)
 
 
@@ -809,7 +829,8 @@ def paged_attention(q, pool_k, pool_v, tables, pos, *,
       mode) against it.
 
     ``interpret`` (fused only): run the kernel in Pallas interpret mode;
-    defaults to True off-TPU, like :func:`flash_attention`.
+    defaults to compiled on TPU and interpreted on CPU, like
+    :func:`flash_attention` (any other backend raises).
 
     ``mesh`` (fused only): run the kernel per-chip under
     :func:`shard_map` — the tp-sharded-pool read path

@@ -72,35 +72,18 @@ def make_mesh(
                    key=lambda n: CANONICAL_AXES.index(n)
                    if n in CANONICAL_AXES else len(CANONICAL_AXES))
     shape = tuple(sizes[n] for n in names)
-    # jax>=0.9 defaults make_mesh to Explicit axis types, which changes
+    # jax.make_mesh defaults to Explicit axis types, which changes
     # sharding semantics under jit (shardings become part of array types and
     # ops like x @ x.T error on duplicate axes).  We want classic Auto/pjit
-    # semantics: request it explicitly.  Older jax (< 0.5) has no AxisType
-    # at all — every axis is already Auto there, so omit the kwarg.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {} if axis_type is None else \
-        {"axis_types": (axis_type.Auto,) * len(names)}
+    # semantics: request it explicitly.
+    axis_types = (jax.sharding.AxisType.Auto,) * len(names)
     if devices == list(jax.devices()):
         try:
-            return jax.make_mesh(shape, tuple(names), **kwargs)
+            return jax.make_mesh(shape, tuple(names), axis_types=axis_types)
         except (ValueError, RuntimeError):
             pass  # fall through to manual reshape (e.g. odd device subsets)
     arr = np.asarray(devices).reshape(shape)
-    return Mesh(arr, tuple(names), **kwargs)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at top
-    level with a ``check_vma`` kwarg; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` with the same check spelled
-    ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return Mesh(arr, tuple(names), axis_types=axis_types)
 
 
 def single_device_mesh(axis: str = "dp") -> Mesh:

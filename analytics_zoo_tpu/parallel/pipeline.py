@@ -47,7 +47,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from analytics_zoo_tpu.parallel.mesh import shard_map as _shard_map
 from analytics_zoo_tpu.parallel.partition import PartitionRules
 
 StageFn = Callable[[Any, jax.Array], jax.Array]
@@ -149,7 +148,7 @@ def pipeline_apply(stage_fn: StageFn, stacked_params: Any, x: jax.Array,
         out = lax.psum(jnp.where(idx == S - 1, out_buf, 0.0), pp_axis)
         return out.reshape(xl.shape).astype(xl.dtype)
 
-    return _shard_map(ranked, mesh=mesh, in_specs=(pspec, xspec),
+    return jax.shard_map(ranked, mesh=mesh, in_specs=(pspec, xspec),
                          out_specs=xspec)(stacked_params, x)
 
 
@@ -506,7 +505,7 @@ def pipeline_value_and_grad(stage_fn: StageFn, loss_fn, stacked_params,
         grads = jax.tree.map(lambda g: g[None], grads)
         return loss, grads, dx.astype(xl.dtype)
 
-    loss, grads, dx = _shard_map(
+    loss, grads, dx = jax.shard_map(
         ranked, mesh=mesh, in_specs=(pspec, xspec, lspec),
         out_specs=(P(), pspec, xspec))(stacked_params, x, labels)
     return loss, grads, dx
@@ -624,7 +623,7 @@ def pipeline_apply_interleaved(stage_fn: StageFn, stacked_params,
             out = lax.psum(jnp.where(idx == S - 1, out_buf, 0.0), pp_axis)
             return out.reshape(xl.shape).astype(xl.dtype)
 
-        return _shard_map(ranked, mesh=mesh, in_specs=(pspec, xspec),
+        return jax.shard_map(ranked, mesh=mesh, in_specs=(pspec, xspec),
                              out_specs=xspec)(params, xx)
 
     def fwd(params, xx):
@@ -661,7 +660,7 @@ def pipeline_apply_interleaved(stage_fn: StageFn, stacked_params,
 
         # cotangents match apply's inputs: the CHUNKED tree (autodiff of
         # the outer _chunk_params reshape maps them back to [L, ...])
-        return _shard_map(
+        return jax.shard_map(
             ranked, mesh=mesh, in_specs=(pspec, xspec, xspec),
             out_specs=(pspec, xspec))(params, xx, gy)
 
@@ -709,7 +708,7 @@ def _value_and_grad_interleaved(stage_fn, loss_fn, stacked_params, x,
         grads = jax.tree.map(lambda g: g[:, None], grads)
         return loss, grads, dx.astype(xl.dtype)
 
-    loss, grads, dx = _shard_map(
+    loss, grads, dx = jax.shard_map(
         ranked, mesh=mesh, in_specs=(pspec, xspec, lspec),
         out_specs=(P(), pspec, xspec))(p_resh, x, labels)
     grads = jax.tree.map(lambda g, a: g.reshape(a.shape), grads,
@@ -783,7 +782,7 @@ def pipeline_apply_1f1b(stage_fn: StageFn, stacked_params, x: jax.Array,
                           pp_axis).reshape(xl.shape)
             return grads, dx.astype(xl.dtype)
 
-        return _shard_map(
+        return jax.shard_map(
             ranked, mesh=mesh, in_specs=(pspec, xspec, xspec),
             out_specs=(pspec, xspec))(params, xx, gy)
 

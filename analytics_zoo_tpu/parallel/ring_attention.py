@@ -23,15 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from analytics_zoo_tpu.parallel.mesh import shard_map
-
-
-def _axis_size(axis_name: str) -> int:
-    """``lax.axis_size`` is newer jax; on 0.4.x ``psum(1, axis)`` is the
-    idiom and returns a static Python int under the shard_map trace."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
 
 
 def _chunk_attn(q, k, v, *, scale, mask):
@@ -80,7 +71,7 @@ def ring_attention(q, k, v, kv_mask=None, *, axis_name: str = "sp",
     (True = attend) rotating around the ring with K/V.  Returns
     [B, T_local, H, D].
     """
-    sp = _axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, T, H, D = q.shape
     scale = scale if scale is not None else 1.0 / jnp.sqrt(D).astype(
@@ -164,7 +155,7 @@ def ulysses_attention(q, k, v, kv_mask=None, *, axis_name: str = "sp",
     Must be called inside shard_map with `axis_name` bound; per-device
     shapes q/k/v: [B, T_local, H, D]; kv_mask: [B, T_local] bool.
     """
-    sp = _axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     if sp == 1:
         return full_attention(q, k, v, kv_mask, causal=causal)
     H = q.shape[2]
@@ -221,9 +212,9 @@ def ring_self_attention(q, k, v, mesh: Mesh, kv_mask=None, *,
         ulysses_attention if strategy == "ulysses" else ring_attention,
         axis_name=seq, causal=causal)
     if kv_mask is None:
-        mapped = shard_map(lambda q, k, v: fn(q, k, v), mesh=mesh,
+        mapped = jax.shard_map(lambda q, k, v: fn(q, k, v), mesh=mesh,
                            in_specs=(spec, spec, spec), out_specs=spec)
         return mapped(q, k, v)
-    mapped = shard_map(fn, mesh=mesh,
+    mapped = jax.shard_map(fn, mesh=mesh,
                        in_specs=(spec, spec, spec, mspec), out_specs=spec)
     return mapped(q, k, v, kv_mask)

@@ -158,6 +158,33 @@ class _Slot:
     n_pub: int = 0
 
 
+class _WeightedJit:
+    """A jitted engine program whose leading operands are model weights.
+
+    The weights ride as ARGUMENTS.  A program that closes over them has
+    every parameter lowered into its HLO as a constant: at 1.5 B
+    parameters that is ~3 GB per program variant to embed, hash for the
+    compile cache, compile, and hold in HBM a second time — the engine
+    builds several such programs.  Call sites keep the weight-free
+    signature (``donate_argnums`` count from the first non-weight
+    operand), and ``_cache_size`` keeps ``lint.trace_guard`` counting
+    compiles by walking the engine's attributes."""
+
+    def __init__(self, fn: Callable, weights: tuple,
+                 donate_argnums: Tuple[int, ...] = (), **jit_kwargs):
+        self._weights = weights
+        self._jit = jax.jit(
+            fn, donate_argnums=tuple(i + len(weights)
+                                     for i in donate_argnums),
+            **jit_kwargs)
+
+    def __call__(self, *args, **kwargs):
+        return self._jit(*self._weights, *args, **kwargs)
+
+    def _cache_size(self) -> int:
+        return self._jit._cache_size()
+
+
 class ContinuousEngine:
     """Slot-arena generation engine over one ``TransformerLM``.
 
@@ -414,6 +441,9 @@ class ContinuousEngine:
         self.kv_dtype = "int8" if self._kv_int8 else _kv_label(cdtype)
         self.mesh = mesh
         # ---- mesh: weights shard FIRST, for EVERY engine mode ----------
+        # The mesh is WHERE THE ENGINE LIVES: weights, KV storage and
+        # every program run on its devices — a one-device mesh is how a
+        # replica is pinned to its own chip (serving/server.py).
         # arena, paged, chunked, and speculative engines all ride the
         # same Megatron-layout rules; the per-mode KV storage below only
         # decides how the cache itself is laid out.  _kv_tp records
@@ -424,7 +454,7 @@ class ContinuousEngine:
         tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
         self._tp = tp
         self._kv_tp = self._dkv_tp = False
-        if tp > 1:
+        if mesh is not None:
             from analytics_zoo_tpu.models.lm import LM_PARTITION_RULES
             from analytics_zoo_tpu.parallel.partition import state_sharding
 
@@ -457,6 +487,15 @@ class ContinuousEngine:
                              draft_model.num_heads)
                 self._dkv_tp = dH % tp == 0 and \
                     self._kv_kernels_tp_sharded(dshardings)
+        # the engine's own device(s): every memory_stats read below is
+        # of these, whichever chip the engine was given
+        if mesh is not None:
+            self._devices = list(mesh.devices.flat)
+        else:
+            leaf = jax.tree.leaves(variables)[0]
+            self._devices = (sorted(leaf.devices(), key=lambda d: d.id)
+                             if isinstance(leaf, jax.Array)
+                             else [jax.devices()[0]])
         # ---- paged mode (block-pool cache, serving/paged_cache.py) -----
         self.paged = bool(paged)
         self._preemptions = 0
@@ -508,27 +547,23 @@ class ContinuousEngine:
             self._per_block_bytes = per_block
             self._draft_per_block_bytes = draft_per_block
             if n_blocks is None:
-                lim = 0
-                if hbm_fraction is not None:
-                    try:
-                        stats = jax.devices()[0].memory_stats() or {}
-                        lim = int(stats.get("bytes_limit", 0))
-                    except Exception:
-                        lim = 0
-                if lim:
+                hbm = self._hbm_stats() if hbm_fraction is not None \
+                    else None
+                if hbm is not None:
                     # with a draft the byte budget covers BOTH tenants:
                     # the common block count splits it proportionally
                     # to per-block cost (the draft's slice is small)
                     n_blocks = max(M + 1, split_block_budget(
-                        int(lim * float(hbm_fraction)),
+                        int(hbm["bytes_limit"] * float(hbm_fraction)),
                         (per_block, draft_per_block)
                         if draft_model is not None else (per_block,)))
                 else:
                     if hbm_fraction is not None:
-                        logger.warning(
-                            "hbm_fraction=%s ignored: device exposes no "
-                            "memory_stats (CPU backend?); sizing the "
-                            "pool arena-equivalent (S*M+1 blocks)",
+                        logger.info(
+                            "hbm_fraction=%s does not apply on the CPU "
+                            "backend (no device memory to take a "
+                            "fraction of); the CPU sizing rule is "
+                            "arena-equivalent, S*M+1 blocks",
                             hbm_fraction)
                     # arena-equivalent capacity: every slot can run to
                     # full length — paged still wins whenever real
@@ -578,7 +613,7 @@ class ContinuousEngine:
             # and the jitted decode/chunk/verify programs reach the
             # pool through XLA's sharding propagation.
             pool_sh = scale_sh = None
-            if tp > 1:
+            if mesh is not None:
                 from jax.sharding import NamedSharding
                 from jax.sharding import PartitionSpec as P
                 hax = "tp" if self._kv_tp else None
@@ -623,7 +658,7 @@ class ContinuousEngine:
                     kv_dtype=_kv_label(cdtype),
                     bytes_per_block=draft_per_block)
                 dpool_sh = None
-                if tp > 1:
+                if mesh is not None:
                     from jax.sharding import NamedSharding
                     from jax.sharding import PartitionSpec as P
                     dpool_sh = NamedSharding(
@@ -661,22 +696,18 @@ class ContinuousEngine:
             # compiles once, then caches)
             self._resize_step = max(self._bs, n_blocks // 8)
             ceiling = n_blocks
-            try:
-                stats = jax.devices()[0].memory_stats() or {}
-                lim = int(stats.get("bytes_limit", 0))
-                used = int(stats.get("bytes_in_use", 0))
-            except Exception:
-                lim = used = 0
+            hbm = self._hbm_stats()
             per = self._per_block_bytes + self._draft_per_block_bytes
-            if lim > used and per > 0:
+            if hbm is not None:
                 # leave 20% of the probed headroom for activations /
                 # compile scratch — the elastic pool must never be the
                 # reason a forward OOMs
-                ceiling = max(ceiling, n_blocks
-                              + (int((lim - used) * 0.8) // per))
+                free = max(0, hbm["bytes_limit"] - hbm["bytes_in_use"])
+                ceiling = max(ceiling, n_blocks + (int(free * 0.8) // per))
             else:
-                # no memory_stats (CPU backend): cap at arena-equivalent
-                # capacity — every slot can run to full length
+                # CPU backend (no device memory to probe): cap at
+                # arena-equivalent capacity — every slot can run to
+                # full length
                 ceiling = max(ceiling, S * self._M + 1)
             self._pool_ceiling = int(ceiling)
         # kv-bytes-per-token: all-layer, both-tenant HBM cost of ONE
@@ -751,7 +782,7 @@ class ContinuousEngine:
             self._read_buckets = tuple(rb)
         if self.paged:
             self._ck = self._cv = None  # pool replaces the slot arena
-        elif tp > 1:
+        elif mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             # the arena must MATCH what the kv projections emit under
@@ -795,7 +826,7 @@ class ContinuousEngine:
         # the pool's placement (tp-sharded kv heads, or the replicated
         # KH % tp hatch) — kmesh/kv_tp are compile-time constants too.
         kern = self.kernel
-        kmesh = self.mesh if kern == "fused" else None
+        kmesh = self._kernel_mesh()
         kv_tp = self._kv_tp
 
         def pick_next(logits, pos, done, temps, seeds, topps,
@@ -825,11 +856,10 @@ class ContinuousEngine:
                 done = done | (nxt == eos_id)
             return nxt, done
 
-        def step_fn(ck, cv, tok, pos, done, temps, seeds, topps,
-                    n_ticks, use_sample, use_topp):
+        def step_fn(variables, ck, cv, tok, pos, done, temps, seeds,
+                    topps, n_ticks, use_sample, use_topp):
             """Advance every slot ``n_ticks`` tokens in ONE device call
-            (a lax.scan) — each extra tick saves a host round-trip,
-            which dominates per-token cost on tunneled devices.  A slot
+            (a lax.scan) — each extra tick saves a host round-trip.  A slot
             that hits EOS mid-chunk freezes exactly like generate()'s
             frozen tail: it keeps stepping, fed eos.  Returns tokens
             [n_ticks, S] in emission order."""
@@ -848,8 +878,9 @@ class ContinuousEngine:
                 one, (tok, pos, done, ck, cv), None, length=n_ticks)
             return toks, tok, pos, done, ck, cv
 
-        def step_fn_paged(pk, pv, tok, pos, done, tables, temps, seeds,
-                          topps, n_ticks, use_sample, use_topp):
+        def step_fn_paged(variables, pk, pv, tok, pos, done, tables,
+                          temps, seeds, topps, n_ticks, use_sample,
+                          use_topp):
             """The paged twin of ``step_fn``: decode through per-slot
             block tables against the shared pool.  Rows holding no
             blocks (free/done slots — their table rows are all SINK)
@@ -885,15 +916,16 @@ class ContinuousEngine:
                 # timeline makes a late one — a retrace — stand out)
                 self.telemetry.jit_build("step", key)
                 fn = step_fn_paged if self.paged else step_fn
-                self._step_cache[key] = jax.jit(
+                self._step_cache[key] = _WeightedJit(
                     partial(fn, n_ticks=n, use_sample=sampled,
                             use_topp=use_topp),
-                    donate_argnums=(0, 1))
+                    (variables,), donate_argnums=(0, 1))
             return self._step_cache[key]
 
         self._get_step = get_step
 
-        def paged_admit_fn(pk, pv, suffixes, slens, tables, pos):
+        def paged_admit_fn(variables, pk, pv, suffixes, slens, tables,
+                           pos):
             """Paged admission prefill: each row's (unshared) prompt
             suffix runs block-causally against pool K/V its table
             already maps — prefix-matched blocks behind ``pos`` read as
@@ -909,10 +941,10 @@ class ContinuousEngine:
                 kernel=kern, mesh=kmesh, kv_sharded=kv_tp,
                 method=TransformerLM.prefill_chunk_paged)
 
-        self._paged_admit = jax.jit(paged_admit_fn,
-                                    donate_argnums=(0, 1))
+        self._paged_admit = _WeightedJit(paged_admit_fn, (variables,),
+                                         donate_argnums=(0, 1))
 
-        def prefill_fn(prompts, plens):
+        def prefill_fn(variables, prompts, plens):
             """Batched joiner prefill: [k, Pb] prompts in ONE forward
             (bursts amortise the admission cost k-fold); returns each
             row's last-real-position logits + stacked K/V."""
@@ -922,7 +954,7 @@ class ContinuousEngine:
                 logits, (plens - 1)[:, None, None], axis=1)[:, 0]
             return last, ks, vs
 
-        self._prefill = jax.jit(prefill_fn)
+        self._prefill = _WeightedJit(prefill_fn, (variables,))
 
         def insert_fn(ck, cv, ks, vs, slot):
             ck = jax.lax.dynamic_update_slice(
@@ -936,8 +968,8 @@ class ContinuousEngine:
         # ---- fused chunked tick (decode + prefill chunks, ONE call) ----
         S_arena = S
 
-        def fused_fn(ck, cv, tok, pos, done, temps, seeds, topps,
-                     ctoks, cpos, clens, cslots, ctemps, cseeds,
+        def fused_fn(variables, ck, cv, tok, pos, done, temps, seeds,
+                     topps, ctoks, cpos, clens, cslots, ctemps, cseeds,
                      ctopps, with_decode, use_sample, use_topp,
                      read_len):
             """One budget-bounded tick: decode EVERY slot once (bitwise
@@ -977,9 +1009,9 @@ class ContinuousEngine:
                 ctopps, use_sample, use_topp)
             return nxt, pos, done, cnxt, ck, cv
 
-        def fused_paged_fn(pk, pv, tok, pos, done, tables, temps,
-                           seeds, topps, ctoks, cpos, clens, ctabs,
-                           ctemps, cseeds, ctopps, with_decode,
+        def fused_paged_fn(variables, pk, pv, tok, pos, done, tables,
+                           temps, seeds, topps, ctoks, cpos, clens,
+                           ctabs, ctemps, cseeds, ctopps, with_decode,
                            use_sample, use_topp):
             """The paged twin: chunks scatter through NARROW per-row
             tables (``ctabs`` [kb, Mb], host-sliced to the fill
@@ -1025,8 +1057,8 @@ class ContinuousEngine:
                     fn = partial(fused_fn, with_decode=with_decode,
                                  use_sample=sampled, use_topp=use_topp,
                                  read_len=read_len)
-                self._fused_cache[key] = jax.jit(fn,
-                                                 donate_argnums=(0, 1))
+                self._fused_cache[key] = _WeightedJit(
+                    fn, (variables,), donate_argnums=(0, 1))
             return self._fused_cache[key]
 
         self._get_fused = get_fused
@@ -1044,7 +1076,7 @@ class ContinuousEngine:
         self._next_prefix_id = 0
 
         def _prefix_admit_for(m, v, want_logits):
-            def fn(ck, cv, pks, pvs, suffixes, suffix_lens, slots):
+            def fn(v, ck, cv, pks, pvs, suffixes, suffix_lens, slots):
                 """Splice a stored prefix [layers, 1, P, H, D] into kb
                 slots and run their suffixes through decode_k against it
                 in ONE forward — a burst naming the same system prompt
@@ -1085,7 +1117,7 @@ class ContinuousEngine:
                     return None, ck, cv
                 return last, ck, cv
 
-            return jax.jit(fn, donate_argnums=(0, 1))
+            return _WeightedJit(fn, (v,), donate_argnums=(0, 1))
 
         self._prefix_admit = _prefix_admit_for(model, variables, True)
         if self.draft_model is not None:
@@ -1249,18 +1281,18 @@ class ContinuousEngine:
         the pointers back (``pos + n_emit``, never a block copy: entries
         past the new pointer are dead and the next round overwrites
         them in-place before anything attends that far)."""
-        draft, dvars = self.draft_model, self._draft_variables
-        model, variables = self.model, self._variables
+        draft, model = self.draft_model, self.model
+        both = (self._variables, self._draft_variables)
         S, L, k = self._S, self._L, self._spec_k
         eos_id = self.eos_id
         kern = self.kernel
-        kmesh = self.mesh if kern == "fused" else None
+        kmesh = self._kernel_mesh()
         kv_tp, dkv_tp = self._kv_tp, self._dkv_tp
         self._dpos = np.zeros(S, np.int32)
 
         if self.paged:
-            def spec_step_paged(pk, pv, dpk, dpv, tok, pos, dpos, done,
-                                tables, dtables):
+            def spec_step_paged(variables, dvars, pk, pv, dpk, dpv, tok,
+                                pos, dpos, done, tables, dtables):
                 # draft: k proposals via k+1 greedy cached feeds through
                 # the DRAFT tenant's tables (the extra feed writes
                 # d_{k-1}'s KV so a full-acceptance round leaves the
@@ -1297,11 +1329,11 @@ class ContinuousEngine:
                 return (t.T, n_emit, new_tok, pos, dpos, done,
                         pk, pv, dpk, dpv)
 
-            self._spec_step_paged = jax.jit(
-                spec_step_paged, donate_argnums=(0, 1, 2, 3))
+            self._spec_step_paged = _WeightedJit(
+                spec_step_paged, both, donate_argnums=(0, 1, 2, 3))
 
-            def draft_paged_admit_fn(dpk, dpv, suffixes, slens, dtables,
-                                     pos):
+            def draft_paged_admit_fn(dvars, dpk, dpv, suffixes, slens,
+                                     dtables, pos):
                 """Draft-tenant admission prefill: the same grid the
                 target's ``_paged_admit`` ran, against the draft pool —
                 logits are discarded (only the target picks tokens)."""
@@ -1311,23 +1343,25 @@ class ContinuousEngine:
                     method=TransformerLM.prefill_chunk_paged)
                 return dpk, dpv
 
-            self._draft_paged_admit = jax.jit(draft_paged_admit_fn,
-                                              donate_argnums=(0, 1))
+            self._draft_paged_admit = _WeightedJit(
+                draft_paged_admit_fn, both[1:], donate_argnums=(0, 1))
         else:
             DH = getattr(draft, "kv_heads", draft.num_heads)
             DD = draft.hidden_size // draft.num_heads
             dkv_sh = None
-            if self._dkv_tp:
+            if self.mesh is not None:
                 from jax.sharding import NamedSharding
                 from jax.sharding import PartitionSpec as P
-                dkv_sh = NamedSharding(self.mesh,
-                                       P(None, None, None, "tp", None))
+                dkv_sh = NamedSharding(
+                    self.mesh, P(None, None, None, "tp", None)
+                    if self._dkv_tp else P())
             self._dck = jnp.zeros((draft.num_layers, S, L, DH, DD),
                                   cdtype, device=dkv_sh)
             self._dcv = jnp.zeros((draft.num_layers, S, L, DH, DD),
                                   cdtype, device=dkv_sh)
 
-            def spec_step(ck, cv, dck, dcv, tok, pos, dpos, done):
+            def spec_step(variables, dvars, ck, cv, dck, dcv, tok, pos,
+                          dpos, done):
                 # draft: k proposals via k+1 greedy cached feeds (the
                 # extra feed writes d_{k-1}'s KV so a full-acceptance
                 # round leaves the draft cache complete)
@@ -1355,15 +1389,16 @@ class ContinuousEngine:
                 return (t.T, n_emit, new_tok, pos, dpos, done,
                         ck, cv, dck, dcv)
 
-            self._spec_step = jax.jit(spec_step,
-                                      donate_argnums=(0, 1, 2, 3))
+            self._spec_step = _WeightedJit(
+                spec_step, both, donate_argnums=(0, 1, 2, 3))
 
-            def draft_prefill_fn(prompts):
+            def draft_prefill_fn(dvars, prompts):
                 _, ks, vs = draft.apply(dvars, prompts,
                                         method=TransformerLM.prefill)
                 return ks, vs
 
-            self._draft_prefill = jax.jit(draft_prefill_fn)
+            self._draft_prefill = _WeightedJit(draft_prefill_fn,
+                                               both[1:])
 
         if not self.chunked:
             return
@@ -1378,8 +1413,8 @@ class ContinuousEngine:
         # grid (verify shapes x chunk shapes) to save zero host syncs —
         # both results are consumed by the same host step.
         if self.paged:
-            def spec_chunk_paged_fn(pk, pv, dpk, dpv, ctoks, cpos,
-                                    clens, ctabs, dctabs):
+            def spec_chunk_paged_fn(variables, dvars, pk, pv, dpk, dpv,
+                                    ctoks, cpos, clens, ctabs, dctabs):
                 clog, pk, pv = model.apply(
                     variables, ctoks, pk, pv, ctabs, cpos, clens,
                     kernel=kern, mesh=kmesh, kv_sharded=kv_tp,
@@ -1394,11 +1429,11 @@ class ContinuousEngine:
                 cnxt = jnp.argmax(clog, -1).astype(jnp.int32)
                 return cnxt, pk, pv, dpk, dpv
 
-            self._spec_chunk_paged = jax.jit(
-                spec_chunk_paged_fn, donate_argnums=(0, 1, 2, 3))
+            self._spec_chunk_paged = _WeightedJit(
+                spec_chunk_paged_fn, both, donate_argnums=(0, 1, 2, 3))
         else:
-            def spec_chunk_fn(ck, cv, dck, dcv, ctoks, cpos, clens,
-                              cslots, read_len):
+            def spec_chunk_fn(variables, dvars, ck, cv, dck, dcv, ctoks,
+                              cpos, clens, cslots, read_len):
                 read_idx = jnp.minimum(cslots, S - 1)
                 rows_k = jnp.take(ck, read_idx, axis=1)[:, :, :read_len]
                 rows_v = jnp.take(cv, read_idx, axis=1)[:, :, :read_len]
@@ -1423,9 +1458,39 @@ class ContinuousEngine:
                 cnxt = jnp.argmax(clog, -1).astype(jnp.int32)
                 return cnxt, ck, cv, dck, dcv
 
-            self._spec_chunk = jax.jit(
-                spec_chunk_fn, static_argnames=("read_len",),
+            self._spec_chunk = _WeightedJit(
+                spec_chunk_fn, both, static_argnames=("read_len",),
                 donate_argnums=(0, 1, 2, 3))
+
+    def _kernel_mesh(self):
+        """The mesh the fused kernel runs under ``shard_map`` on: only a
+        mesh of several devices needs the per-chip wrapper — on one
+        device the kernel is called directly."""
+        if self.kernel == "fused" and self.mesh is not None \
+                and self.mesh.size > 1:
+            return self.mesh
+        return None
+
+    def _hbm_stats(self) -> Optional[Dict[str, int]]:
+        """``bytes_limit`` / ``bytes_in_use`` of the engine's OWN
+        devices, reduced to the tightest chip (least limit, most in
+        use).  ``None`` on the CPU backend, which has no device memory
+        to report; on any other platform a device that reports nothing
+        is an error — sizing a pool "as if" a fraction had been
+        honoured would hide that the chip was never asked."""
+        if self._devices[0].platform == "cpu":
+            return None
+        stats = [d.memory_stats() or {} for d in self._devices]
+        if not all(st.get("bytes_limit") for st in stats):
+            raise RuntimeError(
+                f"{self._devices[0].platform} device(s) "
+                f"{[d.id for d in self._devices]} report no "
+                f"memory_stats()['bytes_limit']; hbm_fraction / "
+                f"elastic_pool need it — pass n_blocks explicitly")
+        return {"bytes_limit": min(int(st["bytes_limit"])
+                                   for st in stats),
+                "bytes_in_use": max(int(st.get("bytes_in_use", 0))
+                                    for st in stats)}
 
     @staticmethod
     def _kv_kernels_tp_sharded(shardings) -> bool:
@@ -2367,7 +2432,11 @@ class ContinuousEngine:
         idx = jnp.asarray(blocks, jnp.int32)
 
         def scatter(d, s):
-            out = d.at[:, idx].set(jnp.asarray(s, d.dtype))
+            # the chain arrives on the SOURCE replica's chip(s): move
+            # it onto this engine's own before the scatter (the
+            # gathered [layers, n, KH, bs, D] rows take the pool's spec)
+            s = jax.device_put(jnp.asarray(s, d.dtype), d.sharding)
+            out = d.at[:, idx].set(s)
             return jax.device_put(out, d.sharding)
 
         self._pk = jax.tree_util.tree_map(scatter, self._pk,
@@ -3286,8 +3355,7 @@ class ContinuousEngine:
         generate()'s frozen tail).  Returns the number of active
         slots afterwards (0 = idle; the caller decides how to wait).
         Higher ``ticks_per_step`` trades admission latency granularity
-        for fewer host round-trips — the dominant per-token cost on
-        tunneled devices."""
+        for fewer host round-trips."""
         if self.n_active == 0 and not self._waiting:
             # idle poll (the serving pump spins on step()): no work to
             # do or measure, and no tick event to spam the ring with

@@ -21,7 +21,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from analytics_zoo_tpu.serving.resp import RespClient
+from analytics_zoo_tpu.serving.resp import RedisError, RespClient
 
 INPUT_STREAM = "serving_stream"
 RESULT_PREFIX = "result:"
@@ -30,6 +30,8 @@ TOKEN_PREFIX = "tok:"     # per-uri token stream (streaming requests):
 #                           the pump publishes generated tokens + a
 #                           terminal marker; stream_events() tails it
 CANCEL_STREAM = "serving_cancel"  # client -> pump live-cancel requests
+_BLOCK_SLICE_S = 5.0      # longest single XREAD BLOCK: far inside
+#                           RespClient's 30 s socket timeout
 IMG_MAGIC = b"IMG!"       # field prefix: raw encoded image (JPEG/PNG bytes)
 #                           decoded server-side — ref: Cluster Serving
 #                           clients enqueued base64 image bytes and the
@@ -170,11 +172,19 @@ class OutputQueue:
                 # broker; remove it so abandoned queries don't leak keys
                 self.client.execute("DEL", sig)
                 return None
+            # Block in slices well inside the connection's socket
+            # timeout.  One BLOCK for the whole wait outlives the socket
+            # the first time a result takes longer than it allows (a
+            # cold compile of a real-width model does), and a recv that
+            # times out mid-reply leaves the late XREAD reply to be read
+            # as the NEXT command's — every later answer on this
+            # connection is then the wrong one.
+            block_s = min(remaining, _BLOCK_SLICE_S)
             try:
                 self.client.execute(
                     "XREAD", "COUNT", 1, "BLOCK",
-                    max(1, int(remaining * 1000)), "STREAMS", sig, "0-0")
-            except Exception:
+                    max(1, int(block_s * 1000)), "STREAMS", sig, "0-0")
+            except RedisError:
                 time.sleep(poll_interval)   # legacy broker: plain polling
             h = self.client.execute("HGETALL", key)
         fields = {h[i].decode(): h[i + 1] for i in range(0, len(h), 2)}

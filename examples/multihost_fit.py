@@ -86,6 +86,9 @@ def main():
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
     workdir = tempfile.mkdtemp(prefix="zoo_multihost_")
+    # the launcher itself stays off JAX (a parent that has initialised a
+    # backend holds the device its workers need); the two workers are a
+    # CPU dry run of the multihost path
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)

@@ -50,6 +50,8 @@ def run_group(cmd, nprocs: int, incarnation: int,
     converts an alive-but-hung incarnation (e.g. a deadlocked
     collective no process dies from) into the restart this supervisor
     exists to provide."""
+    # this supervisor never imports JAX: a parent that has initialised a
+    # backend holds the chip(s) its workers are about to need
     port = _free_port()
     t_start = time.monotonic()
     procs = []

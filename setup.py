@@ -8,6 +8,7 @@ skipped and the library compiles lazily on first use instead
 (native.load_lib); pure-Python paths keep working either way.
 """
 
+import hashlib
 import subprocess
 import sys
 from pathlib import Path
@@ -21,9 +22,12 @@ class BuildWithNative(build_py):
         super().run()
         src = Path(__file__).parent / "analytics_zoo_tpu" / "native" / \
             "dataplane.cpp"
+        # the loader (native/__init__.py) looks for the binary under the
+        # source's content hash, so a pre-built one must carry it too
+        digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
         for base in [Path(self.build_lib), Path(__file__).parent]:
             out = base / "analytics_zoo_tpu" / "native" / \
-                "libzoo_dataplane.so"
+                f"libzoo_dataplane.{digest}.so"
             if not out.parent.exists():
                 continue
             cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
