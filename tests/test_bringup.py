@@ -122,6 +122,18 @@ def test_chip_smoke_without_tpu_prints_no_result():
     assert "FAILED before any leg" in p.stderr
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys():
+    # whoever runs the smoke as a check reads its last stdout line and
+    # refuses any other key ("claim", "legs", ... ride on the line before)
+    import chip_smoke
+
+    line = chip_smoke.result_line(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
 @pytest.mark.slow
 def test_chip_smoke_tiny_is_a_labelled_cpu_dry_run():
     p = _run(["chip_smoke.py", "--tiny"], JAX_PLATFORMS="cpu")
@@ -130,10 +142,13 @@ def test_chip_smoke_tiny_is_a_labelled_cpu_dry_run():
     assert lines and all(l.startswith("[CPU DRY RUN] ") for l in lines)
     for needle in ("/s", "per_sec", "per sec", "tok/s", "samples/s"):
         assert needle not in p.stdout, needle
-    summary = json.loads(lines[-1][len("[CPU DRY RUN] "):])
+    summary = json.loads(lines[-2][len("[CPU DRY RUN] "):])
     assert summary["ok"] and summary["mode"] == "cpu-dry-run"
     assert summary["claim"] is None
     assert set(summary["legs"]) == {"serve", "kernel", "train"}
+    # the last line is the result line: two keys, and the same device
+    result = json.loads(lines[-1][len("[CPU DRY RUN] "):])
+    assert result == {"ok": True, "device": summary["device"]}
 
 
 # ---- no fallback that hides the device -----------------------------------
