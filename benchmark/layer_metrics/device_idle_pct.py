@@ -1,0 +1,9 @@
+"""Share of the traced span in which no operation ran on the device:
+1 - union of device-op intervals / traced span (harness/trace_reduce.py)."""
+
+
+def read(run, params):
+    tr = run.get("trace")
+    if not tr or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
