@@ -256,6 +256,7 @@ def _fwd_call(q, k, v, mask, *, scale, causal, bq, bk, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="zoo_flash_attn_fwd",
         **_params(interpret, 1),
     )(q, k, v, mask)
 
@@ -283,6 +284,7 @@ def _bwd_call(q, k, v, mask, o, lse, do, *, scale, causal, bq, bk,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="zoo_flash_attn_bwd_dq",
         **_params(interpret, 1),
     )(q, k, v, mask, do, lse, delta)
     dk, dv = pl.pallas_call(
@@ -310,6 +312,7 @@ def _bwd_call(q, k, v, mask, o, lse, do, *, scale, causal, bq, bk,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        name="zoo_flash_attn_bwd_dkv",
         **_params(interpret, 1),
     )(k, v, mask, q, do, lse, delta)
     return dq, dk, dv
@@ -719,6 +722,7 @@ def _paged_attention_fused(q, pool_k, pool_v, tables, pos, interpret):
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, SGp, D), jnp.float32),
+        name="zoo_paged_attn_decode",
         **_params(interpret, 1),
     )(tables.astype(jnp.int32), pos.astype(jnp.int32), *operands)
     out = out[:, :, :SG, :].reshape(B, KH, S, G, D)

@@ -298,6 +298,9 @@ class ContinuousEngine:
         # jitted program, so it can neither sync the device nor retrace.
         self.telemetry = telemetry if telemetry is not None \
             else Telemetry()
+        # the cycle's lap clock (telemetry.LapClock): step() and its
+        # tick paths name every phase they pass through
+        self._lap = self.telemetry.clock.lap
         # ---- flight recorder (serving/flight.py) -----------------------
         # always-on bounded ring of per-tick state snapshots — the
         # incident lookback a diagnostic bundle ships.  One plain dict
@@ -1991,14 +1994,17 @@ class ContinuousEngine:
                     for i, req in enumerate(reqs):
                         padded[i, :len(req.prompt)] = req.prompt
                         plens[i] = len(req.prompt)
+                    self._lap("admit")
                     pre = self._prefill(jnp.asarray(padded, jnp.int32),
                                         jnp.asarray(plens, jnp.int32))
                     if self.draft_model is not None:
                         pre = pre + self._draft_prefill(
                             jnp.asarray(padded, jnp.int32))
+                    self._lap("dispatch")
                     # ONE host fetch of the bucket's first-token logits;
                     # per-request picks below then stay on numpy
                     pre = (np.asarray(pre[0]),) + tuple(pre[1:])
+                    self._lap("device_wait")
                 except Exception as e:
                     logger.exception(
                         "prefill failed for %d request(s), bucket %d",
@@ -2015,14 +2021,19 @@ class ContinuousEngine:
                         self._req_error(req.uri, req.on_error, e)
         return admitted
 
-    def _req_error(self, uri, on_error, exc):
+    def _req_error(self, uri, on_error, exc, phase: str = "admit"):
+        """``phase`` is the cycle phase the caller is in (all but the
+        handoff's are admission paths): the callback's own time is
+        taken out of it and booked to ``publish``."""
         self.telemetry.req_errored(uri, f"{type(exc).__name__}: {exc}")
         if on_error is None:
             return
+        self._lap(phase)
         try:
             on_error(uri, exc)
         except Exception:
             logger.exception("on_error callback failed for %r", uri)
+        self._lap("publish")
 
     def _suffix_width(self, n: int, P: int) -> int:
         """Padded width for a prefix request's suffix: a shared prompt
@@ -2068,6 +2079,7 @@ class ContinuousEngine:
             lens[i] = len(req.prompt)
         real = [self._free.popleft() for _ in range(n)]
         slots = real + [self._S] * (kb - n)
+        self._lap("admit")
         try:
             last, self._ck, self._cv = self._prefix_admit(
                 self._ck, self._cv, pks, pvs,
@@ -2083,7 +2095,9 @@ class ContinuousEngine:
         except Exception:
             self._free.extend(real)
             raise
+        self._lap("dispatch")
         last = np.asarray(last)     # one D2H for the whole group
+        self._lap("device_wait")
         admitted = 0
         for i, req in enumerate(reqs):
             try:
@@ -2767,6 +2781,7 @@ class ContinuousEngine:
             tabs[i, :len(blocks)] = blocks
             if dblocks is not None:
                 dtabs[i, :len(dblocks)] = dblocks
+        self._lap("admit")
         last, self._pk, self._pv = self._paged_admit(
             self._pk, self._pv, jnp.asarray(padded, jnp.int32),
             jnp.asarray(lens, jnp.int32), jnp.asarray(tabs, jnp.int32),
@@ -2780,7 +2795,9 @@ class ContinuousEngine:
                 jnp.asarray(lens, jnp.int32),
                 jnp.asarray(dtabs, jnp.int32),
                 jnp.asarray(pos, jnp.int32))
+        self._lap("dispatch")
         last = np.asarray(last)     # one D2H for the whole group
+        self._lap("device_wait")
         admitted = 0
         for i, (req, full, hashes, n_match, blocks,
                 dblocks) in enumerate(plans):
@@ -3206,7 +3223,7 @@ class ContinuousEngine:
             self._dpos[slot] = plen
         self._done[slot] = False
         self.telemetry.req_admitted(uri, slot, priority=priority)
-        self._record_token(slot, int(first))
+        self._record_token(slot, int(first), "admit")
 
     def _splice_one(self, pre, i: int, req) -> None:
         """Insert one prefetched joiner into a free slot; the slot goes
@@ -3255,7 +3272,7 @@ class ContinuousEngine:
         # admission only (baselined).
         return int(jax.random.categorical(key, scaled))
 
-    def _handoff_slot(self, slot: int, st: _Slot) -> None:
+    def _handoff_slot(self, slot: int, st: _Slot, phase: str) -> None:
         """Export a just-prefilled row for adoption by another engine
         (the prefill half of a prefill/decode handoff).  Runs on the
         pump thread at first-token time: snapshot the block chain +
@@ -3298,14 +3315,20 @@ class ContinuousEngine:
         # this engine's part of the request is over — the destination
         # runs its own full enqueue->admit->finish telemetry lifecycle
         self.telemetry.req_finished(st.uri, slot, len(st.tokens))
+        self._lap(phase)
         try:
             st.req.handoff_cb(state)
         except Exception as e:
             logger.exception("handoff callback failed for %r", st.uri)
-            self._req_error(st.uri, st.on_error, e)
+            self._req_error(st.uri, st.on_error, e, "publish")
+        self._lap("publish")
 
-    def _record_token(self, slot: int, token: int):
-        """Append one generated token; finish + free the slot when done."""
+    def _record_token(self, slot: int, token: int, phase: str = "book"):
+        """Append one generated token; finish + free the slot when done.
+        ``phase`` is the cycle phase the caller is in (``admit`` for a
+        monolithic admission's first token): the time inside a fired
+        ``on_done`` / handoff callback is taken out of it and booked
+        to ``publish``."""
         st = self._slots[slot]
         st.tokens.append(token)
         self.telemetry.req_token(st.uri, slot)
@@ -3324,7 +3347,7 @@ class ContinuousEngine:
                     and st.req.handoff_cb is not None):
                 # prefill role: the first token is this engine's LAST —
                 # export the row instead of decoding it here
-                self._handoff_slot(slot, st)
+                self._handoff_slot(slot, st, phase)
             return
         out = np.full(st.max_new,
                       self.eos_id if self.eos_id is not None else 0,
@@ -3339,11 +3362,13 @@ class ContinuousEngine:
             self._release_slot_blocks(slot)
         self.telemetry.req_finished(st.uri, slot, len(st.tokens))
         if st.on_done is not None:
+            self._lap(phase)
             try:
                 st.on_done(st.uri, out)
             except Exception:
                 logger.exception("continuous-batching on_done callback "
                                  "failed for %r", st.uri)
+            self._lap("publish")
 
     def step(self) -> int:
         """One engine iteration: admit joiners, then advance every
@@ -3356,19 +3381,27 @@ class ContinuousEngine:
         slots afterwards (0 = idle; the caller decides how to wait).
         Higher ``ticks_per_step`` trades admission latency granularity
         for fewer host round-trips."""
+        clock = self.telemetry.clock
         if self.n_active == 0 and not self._waiting:
             # idle poll (the serving pump spins on step()): no work to
-            # do or measure, and no tick event to spam the ring with
+            # do or measure, and no tick event to spam the ring with;
+            # the pass's laps are summed into the open cycle
+            clock.lap(clock.rest)
+            clock.fold()
             return 0
         if self._fault is not None:
             self._fault_tick()
-        t0 = time.monotonic()
+        # the laps at the step's two ends are the very clock readings
+        # that give ts and dur: a cycle's laps sum to the time from the
+        # end of the last step to the end of this one, with no remainder
+        t0 = clock.lap(clock.rest)
         n = self._step_impl()
-        dur = time.monotonic() - t0
+        dur = clock.lap("admit") - t0   # every tick path ends in _admit()
+        phases, folded = clock.take()
         samples = self._tick_samples(n)
-        self.telemetry.tick(t0, dur, samples)
+        self.telemetry.tick(t0, dur, samples, phases, folded)
         if self.flight is not None:
-            self._flight_record(t0, dur, samples)
+            self._flight_record(t0, dur, samples, phases)
         return n
 
     def _fault_tick(self) -> None:
@@ -3416,7 +3449,7 @@ class ContinuousEngine:
         return samples
 
     def _flight_record(self, ts: float, dur: float,
-                       samples: dict) -> None:
+                       samples: dict, phases) -> None:
         """Append one tick snapshot to the flight ring: the telemetry
         samples plus resident row sets, tick kind, and the per-tick
         DELTAS of every cumulative counter an incident reader wants on
@@ -3435,6 +3468,11 @@ class ContinuousEngine:
         rec["seq"] = self.flight.next_seq()
         rec["ts"] = round(ts, 6)
         rec["dur_ms"] = round(dur * 1e3, 3)
+        # the cycle this tick closes, [phase, wall_ms, cpu_ms] in the
+        # order the laps were taken: the wall_ms sum to (ts + dur_ms)
+        # minus the previous record's (ts + dur_ms)
+        rec["phases"] = [[name, round(wall * 1e3, 4), round(cpu * 1e3, 4)]
+                         for name, wall, cpu in phases]
         rec["kind"] = self._tick_kind
         # which read path / storage mode this tick ran on — a bundle
         # reader's first question when a regression bisects to config
@@ -3565,7 +3603,9 @@ class ContinuousEngine:
 
     def _step_impl(self) -> int:
         self._tick_kind = "decode"
+        lap = self._lap
         self._admit()
+        lap("admit")
         active = [i for i, s in enumerate(self._slots) if s is not None]
         if not active:
             return 0
@@ -3590,6 +3630,7 @@ class ContinuousEngine:
                 # verify writes; may preempt
                 active = self._ensure_blocks(active)
                 if not active:
+                    lap("plan")
                     self._admit()   # preemptions freed blocks
                     return self.n_active
             return self._spec_tick(active)
@@ -3604,6 +3645,7 @@ class ContinuousEngine:
             # grow block tables for the coming chunk; may preempt
             active = self._ensure_blocks(active)
             if not active:
+                lap("plan")
                 self._admit()   # preemptions freed blocks: retry now
                 return self.n_active
         self._peak_resident = max(self._peak_resident, len(active))
@@ -3626,6 +3668,7 @@ class ContinuousEngine:
             # pos + spec_k coverage _ensure_blocks grants this engine
             n_eff = 1
         step = self._get_step(n_eff, sampled, use_topp)
+        lap("plan")
         if self.paged:
             toks, tok, pos, done, self._pk, self._pv = step(
                 self._pk, self._pv, jnp.asarray(self._tok, jnp.int32),
@@ -3643,17 +3686,20 @@ class ContinuousEngine:
                 jnp.asarray(temps, jnp.float32),
                 jnp.asarray(seeds, jnp.uint32),
                 jnp.asarray(topps, jnp.float32))
+        lap("dispatch")
         toks = np.asarray(toks)                     # [n_eff, S]
         # np.asarray of a jax array is a read-only view; _admit writes
         # per-slot entries, so take mutable copies
         self._tok = np.array(tok)
         self._pos = np.array(pos)
         self._done = np.array(done)
+        lap("device_wait")
         for i in active:
             for j in range(n_eff):
                 if self._slots[i] is None:
                     break       # finished mid-chunk; the rest is frozen
                 self._record_token(i, int(toks[j, i]))
+        lap("book")
         self._admit()       # freed slots recycle on the SAME iteration
         return self.n_active
 
@@ -3733,7 +3779,9 @@ class ContinuousEngine:
                            if self._slots[i] is not None]
             chunks = [(i, c) for i, c in chunks
                       if self._slots[i] is not None]
+        lap = self._lap
         if not decode_rows and not chunks:
+            lap("plan")
             self._admit()       # preemptions may have freed blocks
             return self.n_active
         self._peak_resident = max(self._peak_resident, len(active))
@@ -3772,13 +3820,13 @@ class ContinuousEngine:
             cseeds[j] = st.rng_seed or 0
             ctopps[j] = st.top_p
         need = int((cpos + clens).max())
-        t_fused = time.monotonic()
         if self.paged:
             Mb = self._table_width(-(-need // self._bs))
             ctabs = np.full((kb, Mb), SINK_BLOCK, np.int32)
             for j, (i, _) in enumerate(chunks):
                 ctabs[j] = self._tables[i, :Mb]
             fused = self._get_fused(with_decode, sampled, use_topp)
+            t_fused = lap("plan")
             nxt, pos2, done2, cnxt, self._pk, self._pv = fused(
                 self._pk, self._pv,
                 jnp.asarray(self._tok, jnp.int32),
@@ -3800,6 +3848,7 @@ class ContinuousEngine:
                             if b >= need)
             fused = self._get_fused(with_decode, sampled, use_topp,
                                     read_len)
+            t_fused = lap("plan")
             nxt, pos2, done2, cnxt, self._ck, self._cv = fused(
                 self._ck, self._cv,
                 jnp.asarray(self._tok, jnp.int32),
@@ -3815,12 +3864,13 @@ class ContinuousEngine:
                 jnp.asarray(ctemps, jnp.float32),
                 jnp.asarray(cseeds, jnp.uint32),
                 jnp.asarray(ctopps, jnp.float32))
+        lap("dispatch")
         # one host sync for decode picks + chunk first-token picks
         nxt, pos2, done2, cnxt = jax.device_get(
             (nxt, pos2, done2, cnxt))
         # all of a tick's chunks land in the one fused call above, so
         # they share its span (per-chunk device timing doesn't exist)
-        dur_fused = time.monotonic() - t_fused
+        dur_fused = lap("device_wait") - t_fused
         for i, clen in chunks:
             self.telemetry.events.span(
                 "prefill_chunk", t_fused, dur_fused, i,
@@ -3851,6 +3901,7 @@ class ContinuousEngine:
         for i in decode_rows:
             if self._slots[i] is not None:
                 self._record_token(i, int(nxt[i]))
+        lap("book")
         self._admit()       # freed slots recycle on the SAME iteration
         return self.n_active
 
@@ -3864,6 +3915,8 @@ class ContinuousEngine:
         use_topp = any(self._slots[i].top_p > 0.0 for i in decode_rows)
         temps, seeds, topps = self._sampling_vectors(decode_rows)
         step = self._get_step(1, sampled, use_topp)
+        lap = self._lap
+        lap("plan")
         if self.paged:
             toks, tok, pos, done, self._pk, self._pv = step(
                 self._pk, self._pv, jnp.asarray(self._tok, jnp.int32),
@@ -3881,14 +3934,17 @@ class ContinuousEngine:
                 jnp.asarray(temps, jnp.float32),
                 jnp.asarray(seeds, jnp.uint32),
                 jnp.asarray(topps, jnp.float32))
+        lap("dispatch")
         toks = np.asarray(toks)
         self._tok = np.array(tok)
         self._pos = np.array(pos)
         self._done = np.array(done)
+        lap("device_wait")
         self._reanchor_prefill()
         for i in decode_rows:
             if self._slots[i] is not None:
                 self._record_token(i, int(toks[0, i]))
+        lap("book")
         self._admit()
         return self.n_active
 
@@ -4048,6 +4104,8 @@ class ContinuousEngine:
         or past the fill frontier, where their own chunks (and, after
         the flip, their first verify) overwrite them before anything
         attends that far."""
+        lap = self._lap
+        lap("plan")
         if self.paged:
             (toks, n_emit, tok, pos, dpos, done, self._pk, self._pv,
              self._dpk, self._dpv) = self._spec_step_paged(
@@ -4066,12 +4124,14 @@ class ContinuousEngine:
                 jnp.asarray(self._pos, jnp.int32),
                 jnp.asarray(self._dpos, jnp.int32),
                 jnp.asarray(self._done, jnp.bool_))
+        lap("dispatch")
         toks = np.asarray(toks)                 # [k+1, S]
         n_emit = np.asarray(n_emit)
         self._tok = np.array(tok)
         self._pos = np.array(pos)
         self._dpos = np.array(dpos)
         self._done = np.array(done)
+        lap("device_wait")
         self._spec_rounds = getattr(self, "_spec_rounds", 0) + 1
         self._spec_emitted = getattr(self, "_spec_emitted", 0) + int(
             n_emit[rows].sum())
@@ -4086,6 +4146,7 @@ class ContinuousEngine:
                 if self._slots[i] is None:
                     break       # finished mid-round; the rest is frozen
                 self._record_token(i, int(toks[j, i]))
+        lap("book")
 
     def _spec_chunked_tick(self, active) -> int:
         """Chunked tick with a draft model: ONE token budget covers
@@ -4120,6 +4181,7 @@ class ContinuousEngine:
             chunks = [(i, c) for i, c in chunks
                       if self._slots[i] is not None]
         if not decode_rows and not chunks:
+            self._lap("plan")
             self._admit()       # preemptions may have freed blocks
             return self.n_active
         self._peak_resident = max(self._peak_resident, len(active))
@@ -4135,6 +4197,7 @@ class ContinuousEngine:
         if chunks:
             self._spec_chunks(chunks)
         self._reanchor_prefill()
+        self._lap("book")
         self._admit()       # freed slots recycle on the SAME iteration
         return self.n_active
 
@@ -4159,7 +4222,7 @@ class ContinuousEngine:
             clens[j] = clen
             cslots[j] = i
         need = int((cpos + clens).max())
-        t_chunk = time.monotonic()
+        lap = self._lap
         if self.paged:
             Mb = self._table_width(-(-need // self._bs))
             ctabs = np.full((kb, Mb), SINK_BLOCK, np.int32)
@@ -4167,6 +4230,7 @@ class ContinuousEngine:
             for j, (i, _) in enumerate(chunks):
                 ctabs[j] = self._tables[i, :Mb]
                 dctabs[j] = self._dtables[i, :Mb]
+            t_chunk = lap("plan")
             (cnxt, self._pk, self._pv, self._dpk,
              self._dpv) = self._spec_chunk_paged(
                 self._pk, self._pv, self._dpk, self._dpv,
@@ -4178,6 +4242,7 @@ class ContinuousEngine:
         else:
             read_len = next(b for b in self._read_buckets
                             if b >= need)
+            t_chunk = lap("plan")
             (cnxt, self._ck, self._cv, self._dck,
              self._dcv) = self._spec_chunk(
                 self._ck, self._cv, self._dck, self._dcv,
@@ -4186,8 +4251,9 @@ class ContinuousEngine:
                 jnp.asarray(clens, jnp.int32),
                 jnp.asarray(cslots, jnp.int32),
                 read_len=read_len)
+        lap("dispatch")
         cnxt = np.asarray(cnxt)     # one host sync for first-token picks
-        dur_chunk = time.monotonic() - t_chunk
+        dur_chunk = lap("device_wait") - t_chunk
         for i, clen in chunks:
             self.telemetry.events.span(
                 "prefill_chunk", t_chunk, dur_chunk, i,

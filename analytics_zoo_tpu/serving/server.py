@@ -1219,6 +1219,13 @@ class ClusterServing:
                 emitter.error(u, f"admission failed: {exc!r}"[:200])
             self._finish_entries(client, [eid])
 
+        # the engine cycle's lap clock (telemetry.LapClock): this thread
+        # drives the engine from here on, and every stretch of the loop
+        # below is booked to a named phase — the engine's step() names
+        # its own and closes `submit` as it starts
+        clock = engine.telemetry.clock
+        clock.drive("submit")
+        lap = clock.lap
         try:
             while not self._stop.is_set():
                 now = time.monotonic()
@@ -1245,6 +1252,7 @@ class ClusterServing:
                 if replica == 0 and now >= next_prune:
                     next_prune = now + _prune_cadence()
                     self._prune_abandoned(client, now)
+                lap("house")
                 if routed:
                     self._drain_routed_cancels(client, replica, emitter,
                                                streaming,
@@ -1252,6 +1260,7 @@ class ClusterServing:
                 else:
                     self._drain_cancels(client, emitter, streaming,
                                         cancelled_pending)
+                lap("cancel")
                 busy = engine.n_active > 0 or engine.n_waiting > 0
                 if routed:
                     requests, ids = self._pop_routed(
@@ -1267,6 +1276,9 @@ class ClusterServing:
                             break
                         time.sleep(0.05)
                         continue
+                # with the engine empty the claim is the wait for a
+                # request, not work the cycle is held up by
+                lap("claim" if busy else "idle_wait")
                 for r, eid in zip(requests, ids):
                     t0 = time.perf_counter()
                     try:
@@ -1381,6 +1393,7 @@ class ClusterServing:
                     time.sleep(0.2)
                 else:
                     self._diag_poll(engine, replica)
+                    lap("observe")
                     if elastic and time.monotonic() >= next_resize:
                         # throttled elastic-pool control step (pump
                         # thread — the arenas are donated through the
@@ -1407,7 +1420,9 @@ class ClusterServing:
                         except Exception:
                             logger.exception(
                                 "brownout controller step failed")
+                    lap("control")
                 self._flush_emitter(client, emitter)
+                lap("flush")
         except Exception:
             # an exception escaping the pump loop used to die silently
             # in the thread, leaving a zombie entry in the router's

@@ -16,6 +16,9 @@ surfaces:
   counter samples (one ``deque.append`` per event, bounded memory),
   exported as Chrome trace-event JSON (``to_chrome``) loadable in
   Perfetto / ``chrome://tracing``.
+- :class:`LapClock` — the pump thread's lap clock: every millisecond
+  of the engine's cycle (end of step N-1 to end of step N) booked to a
+  named phase, wall and CPU, with no remainder.
 - :class:`Telemetry` — the per-engine facade: request-lifecycle hooks
   (enqueued → admitted → first token → finished/preempted/errored)
   feed TTFT / inter-token-gap / queue-wait histograms and lifecycle
@@ -45,8 +48,8 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "WindowHistogram", "MetricsRegistry",
-           "EventLog", "Telemetry", "render_prometheus",
-           "validate_chrome_trace"]
+           "EventLog", "LapClock", "PHASES", "Telemetry",
+           "render_prometheus", "validate_chrome_trace"]
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 
@@ -402,6 +405,88 @@ def validate_chrome_trace(obj: Any) -> None:
     json.dumps(obj)     # must be serializable as-is
 
 
+# ---- lap clock (the engine cycle's phases) ----------------------------
+
+#: The phases of one engine cycle, in the order they occur from the end
+#: of step N-1 to the end of step N (docs/observability.md has, for
+#: each, the first and last statement it covers).  ``claim`` reads
+#: ``idle_wait`` while the engine is empty; ``outside`` is everything
+#: between two steps of an engine that no pump drives.
+PHASES = ("observe", "control", "flush", "house", "cancel", "claim",
+          "idle_wait", "submit", "outside", "admit", "plan", "dispatch",
+          "device_wait", "book", "publish")
+
+
+class LapClock:
+    """A lap clock for the ONE thread that drives an engine (the pump
+    thread: its loop and ``engine.step()`` run there).  ``lap(name)``
+    reads ``time.monotonic()`` and ``time.thread_time()`` once each and
+    books the wall and the CPU time since the previous lap to ``name``.
+    Laps are contiguous, so a cycle is accounted for with no remainder:
+    a wait for the GIL or a socket lands in the phase that suffered it,
+    as wall without CPU.  No lock, no context manager, one tuple
+    appended per lap; there is no switch.
+
+    The open cycle's laps are an ordered list of ``(name, wall_s,
+    cpu_s)``; a name repeats when its phase ran twice (the two
+    ``_admit()`` calls of a step).  ``fold()`` sums the list per phase
+    so that it stays bounded while the engine idles; ``take()`` closes
+    the cycle."""
+
+    __slots__ = ("rest", "_t", "_c", "_laps", "_folded")
+
+    def __init__(self):
+        # the phase that the step's opening lap closes: whatever ran
+        # since the last lap without a name of its own
+        self.rest = "outside"
+        self._t = time.monotonic()
+        self._c = time.thread_time()
+        self._laps: List[Tuple[str, float, float]] = []
+        self._folded = 0        # leading entries that are sums
+
+    def drive(self, rest: str) -> None:
+        """The calling thread takes the engine over (a pump starting
+        its loop): CPU time is per thread, so its baseline is read
+        anew, and ``rest`` names what this driver does last before a
+        step.  The wall time since the last lap goes to ``outside``."""
+        t = time.monotonic()
+        self._laps.append(("outside", t - self._t, 0.0))
+        self._t, self._c, self.rest = t, time.thread_time(), rest
+
+    def lap(self, name: str) -> float:
+        """Book the time since the previous lap to ``name``; returns
+        the wall reading, so a caller that needs the same instant (the
+        step's ``ts`` and ``dur``) does not read the clock twice."""
+        t = time.monotonic()
+        c = time.thread_time()
+        self._laps.append((name, t - self._t, c - self._c))
+        self._t, self._c = t, c
+        return t
+
+    def fold(self) -> None:
+        """A pass of the driver ended in no tick: sum the open cycle's
+        laps per phase (first occurrence keeps its place)."""
+        acc: Dict[str, List[float]] = {}
+        for name, wall, cpu in self._laps:
+            a = acc.get(name)
+            if a is None:
+                acc[name] = [wall, cpu]
+            else:
+                a[0] += wall
+                a[1] += cpu
+        self._laps = [(n, a[0], a[1]) for n, a in acc.items()]
+        self._folded = len(self._laps)
+
+    def take(self) -> Tuple[List[Tuple[str, float, float]], int]:
+        """Close the cycle at the last lap: its laps, and how many of
+        the leading ones are per-phase sums of folded passes (the rest
+        are exact, so their boundaries follow by cumulative sum back
+        from the cycle's end)."""
+        out = (self._laps, self._folded)
+        self._laps, self._folded = [], 0
+        return out
+
+
 # ---- per-request lifecycle facade -------------------------------------
 
 class _Clock:
@@ -441,6 +526,7 @@ class Telemetry:
                  window: int = 8192, prefix: str = "zoo_engine_"):
         self.metrics = MetricsRegistry()
         self.events = EventLog(events_capacity)
+        self.clock = LapClock()
         self.keep_request_stamps = False
         self._stamps: Dict[str, dict] = {}
         self._clocks: Dict[str, _Clock] = {}
@@ -516,6 +602,21 @@ class Telemetry:
         self.h_tick = m.histogram(
             p + "tick_seconds", "engine step wall time",
             window=window)
+        # the cycle's phases (LapClock): cumulative seconds per phase,
+        # wall and CPU — rate() of one over the sum of all is that
+        # phase's share of the cycle, device_wait's being the host-side
+        # reading of utilisation; wall minus CPU is time the pump
+        # thread was blocked (a socket, the GIL)
+        self.c_phase_wall = {
+            ph: m.counter(
+                p + f"phase_seconds_total_{ph}",
+                f"wall seconds of the engine cycle spent in phase {ph}")
+            for ph in PHASES}
+        self.c_phase_cpu = {
+            ph: m.counter(
+                p + f"phase_cpu_seconds_total_{ph}",
+                f"CPU seconds the pump thread ran in phase {ph}")
+            for ph in PHASES}
         self.h_spec_accept = m.histogram(
             p + "spec_accept_len",
             "accepted draft tokens per row per verify round (0..k)",
@@ -756,18 +857,36 @@ class Telemetry:
     # -- engine loop -------------------------------------------------
 
     def tick(self, start: float, dur: float,
-             samples: Dict[str, float]) -> None:
+             samples: Dict[str, float],
+             phases: Sequence[Tuple[str, float, float]] = (),
+             folded: int = 0) -> None:
         """One engine step: a span on the engine-loop track, a tick
         wall-time histogram sample, and a Perfetto counter track of
         the per-tick gauges (queue depth, row mix, free blocks, ...).
         Every value arrives as a host int/float the engine already
-        computed — recording one costs two deque appends."""
+        computed — recording one costs two deque appends.
+
+        ``phases`` is the cycle that this step closes (``LapClock.
+        take()``, ending at ``start + dur``): each phase adds to its
+        cumulative-seconds counters and, unless it is one of the
+        ``folded`` leading sums, becomes a span on the engine-loop
+        track — the in-step phases nest under the tick's span, the
+        pump's lie between two ticks."""
         self.c_ticks.inc()
         self.h_tick.record(dur)
         self.events.span("tick", start, dur, EventLog.TID_ENGINE,
                          samples or None)
         if samples:
             self.events.counter_sample("engine", samples, start)
+        for name, wall, cpu in phases:
+            self.c_phase_wall[name].inc(wall)
+            self.c_phase_cpu[name].inc(cpu)
+        exact = phases[folded:]
+        t = start + dur - sum(wall for _, wall, _ in exact)
+        for name, wall, cpu in exact:
+            self.events.span(name, t, wall, EventLog.TID_ENGINE,
+                             {"cpu_ms": round(cpu * 1e3, 3)})
+            t += wall
 
     def spec_round(self, proposed: int, accepted: int,
                    accept_lens) -> None:
