@@ -304,11 +304,24 @@ def _apply_rope(x, pos, base: float):
     return out.astype(x.dtype)
 
 
-def _stack_kv(xs):
-    """``jnp.stack`` over per-layer KV pools that also works for the
-    quantized pools (``ops.flash_attention.QuantKV`` pytrees): every
-    leaf (data, scale) is stacked along a new leading layers axis."""
-    return jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *xs)
+def _flat_pools(pools):
+    """View stacked KV pools ``[layers, N, KH, bs, D]`` (any pytree of
+    them: arrays, or ``ops.flash_attention.QuantKV`` data + scales) as
+    ONE block arena ``[layers*N, KH, bs, D]`` each; returns (flat, N).
+    Only leading dimensions merge, so this is a bitcast of the donated
+    buffer, under a mesh too (the pool shards on KH).  Layer ``i`` reads and
+    writes through ``tables + i*N``: its blocks are ``i*N .. i*N+N-1``,
+    its sink ``i*N``.  Nothing in a step program may slice a layer out
+    or stack layers back: either makes the compiler copy the pool."""
+    N = jax.tree_util.tree_leaves(pools)[0].shape[1]
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), pools), N
+
+
+def _stacked_pools(flat, n_layers):
+    """Inverse view of :func:`_flat_pools`: ``[layers, N, ...]``."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((n_layers, -1) + a.shape[1:]), flat)
 
 
 class DecoderAttention(nn.Module):
@@ -968,16 +981,15 @@ class TransformerLM(nn.Module):
         if self.pos_embed is not None:
             x = x + self.pos_embed(pos)[:, None]
         x = x.astype(self.dtype)
-        ks, vs = [], []
+        (pk, pv), N = _flat_pools((pools_k, pools_v))
         for i, layer in enumerate(self.layers):
-            x, pk, pv = layer.decode_paged(x, pools_k[i], pools_v[i],
-                                           tables, pos, kernel=kernel,
+            x, pk, pv = layer.decode_paged(x, pk, pv, tables + i * N,
+                                           pos, kernel=kernel,
                                            mesh=mesh,
                                            kv_sharded=kv_sharded)
-            ks.append(pk)
-            vs.append(pv)
         logits = self._logits(self.ln_f(x))[:, 0]
-        return logits, _stack_kv(ks), _stack_kv(vs)
+        pk, pv = _stacked_pools((pk, pv), len(self.layers))
+        return logits, pk, pv
 
     def verify_step_paged(self, toks, pools_k, pools_v, tables, pos,
                           kernel="gather", mesh=None, kv_sharded=True):
@@ -1018,15 +1030,14 @@ class TransformerLM(nn.Module):
             p = pos[:, None] + jnp.arange(S)[None, :]
             x = x + self.pos_embed(p)
         x = x.astype(self.dtype)
-        ks, vs = [], []
+        (pk, pv), N = _flat_pools((pools_k, pools_v))
         for i, layer in enumerate(self.layers):
-            x, pk, pv = layer.decode_paged(x, pools_k[i], pools_v[i],
-                                           tables, pos, limit=limit,
+            x, pk, pv = layer.decode_paged(x, pk, pv, tables + i * N,
+                                           pos, limit=limit,
                                            kernel=kernel, mesh=mesh,
                                            kv_sharded=kv_sharded)
-            ks.append(pk)
-            vs.append(pv)
-        return self.ln_f(x), _stack_kv(ks), _stack_kv(vs)
+        pk, pv = _stacked_pools((pk, pv), len(self.layers))
+        return self.ln_f(x), pk, pv
 
     def prefill_chunk(self, toks, caches_k, caches_v, pos, lens):
         """One CHUNKED-PREFILL step against the slot-arena cache: run a

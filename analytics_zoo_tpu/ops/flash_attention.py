@@ -441,8 +441,9 @@ class QuantKV:
 
     Registered as a pytree so it threads OPAQUELY through jit / scan /
     donate_argnums / ``flax.apply`` exactly like the plain array pool it
-    replaces; ``__getitem__`` mirrors the per-layer ``pools[i]``
-    indexing the model's decode loop does.
+    replaces; ``__getitem__`` indexes data and scales alike (a layer's
+    pool out of the stacked one — for tests and tools: the step programs
+    never slice a layer out, see ``models/lm.py:_flat_pools``).
     """
 
     __slots__ = ("data", "scale")
@@ -553,30 +554,31 @@ def paged_kv_update(pool_k, pool_v, tables, pos, new_k, new_v,
     — and corrupt real K/V.  Reads are unaffected; attention masking is
     :func:`paged_attention`'s job.
     """
-    if isinstance(pool_k, QuantKV):
-        N, KH, bs, D = pool_k.data.shape
-        S = new_k.shape[1]
-        phys, off = _paged_scatter_index(tables, pos, S, bs, N, limit)
-        qk, sk = quantize_kv(new_k, pool_k.scale.dtype)
-        qv, sv = quantize_kv(new_v, pool_v.scale.dtype)
-        # advanced indices (phys, off) straddle the KH slice, so the
-        # indexed dims lead the result: [B, S, KH, D] — new_k's own
-        # layout, no transpose needed.  Same for the [B, S, KH] scales.
-        pk = QuantKV(
-            pool_k.data.at[phys, :, off].set(qk, mode="drop"),
-            pool_k.scale.at[phys, :, off].set(sk, mode="drop"))
-        pv = QuantKV(
-            pool_v.data.at[phys, :, off].set(qv, mode="drop"),
-            pool_v.scale.at[phys, :, off].set(sv, mode="drop"))
-        return pk, pv
-    N, KH, bs, D = pool_k.shape
+    quant = isinstance(pool_k, QuantKV)
+    N, KH, bs, D = (pool_k.data if quant else pool_k).shape
     S = new_k.shape[1]
     phys, off = _paged_scatter_index(tables, pos, S, bs, N, limit)
-    pk = pool_k.at[phys, :, off].set(new_k.astype(pool_k.dtype),
-                                     mode="drop")
-    pv = pool_v.at[phys, :, off].set(new_v.astype(pool_v.dtype),
-                                     mode="drop")
-    return pk, pv
+    # every INDEXED dimension (block, kv head, offset) leads and the
+    # window is the trailing [D] row: XLA then scatters into the pool
+    # in the layout it already has, in place on a donated buffer.
+    # ``pool.at[phys, :, off]`` (kv heads inside the window, between
+    # the two indexed dims) made the TPU compiler transpose the whole
+    # operand around every write.  KH stays an axis of its own, so a
+    # pool sharded over ``tp`` on KH partitions the same way.  The
+    # indexed dims lead the result: [B, S, KH, D], new_k's own layout
+    # ([B, S, KH] for the scales).
+    idx = (phys[:, :, None], jnp.arange(KH)[None, None, :],
+           off[:, :, None])
+
+    def put(pool, rows):
+        return pool.at[idx].set(rows.astype(pool.dtype), mode="drop")
+
+    if quant:
+        qk, sk = quantize_kv(new_k, pool_k.scale.dtype)
+        qv, sv = quantize_kv(new_v, pool_v.scale.dtype)
+        return (QuantKV(put(pool_k.data, qk), put(pool_k.scale, sk)),
+                QuantKV(put(pool_v.data, qv), put(pool_v.scale, sv)))
+    return put(pool_k, new_k), put(pool_v, new_v)
 
 
 # ---------------------------------------------------------------------------
@@ -698,16 +700,22 @@ def _paged_attention_fused(q, pool_k, pool_v, tables, pos, interpret):
     ]
     operands = [qf, kd, vd]
     if quant:
-        # the [N, KH, bs] scales ride as [N, KH, 1, bs]: a (1, 1, bs)
-        # block of the 3-D array would put a 1 against KH in the
-        # second-minor (sublane) slot, which Mosaic refuses unless
-        # KH == 1; with the unit axis the tile's last two dims (1, bs)
-        # equal the array's and any KH is legal
+        # the scales of the blocks the tables name, gathered here
+        # ([B*M, KH, bs]: some KB) and indexed by (b, j), not by
+        # t[b, j]: handing the kernel the whole [N, KH, bs] array made
+        # XLA re-lay ALL of it out, padded to Mosaic's tiles, before
+        # every call (the scatter that writes the scales keeps them
+        # in a layout of its own).  They ride as [.., KH, 1, bs]: a
+        # (1, 1, bs) block of the 3-D array would put a 1
+        # against KH in the second-minor (sublane) slot, which Mosaic
+        # refuses unless KH == 1; with the unit axis the tile's last two
+        # dims (1, bs) equal the array's and any KH is legal
         sspec = pl.BlockSpec((1, 1, 1, bs),
-                             lambda b, h, j, t, p: (t[b, j], h, 0, 0))
+                             lambda b, h, j, t, p: (b * M + j, h, 0, 0))
         in_specs += [sspec, sspec]
-        operands += [pool_k.scale[:, :, None, :],
-                     pool_v.scale[:, :, None, :]]
+        rows = tables.reshape(-1)
+        operands += [jnp.take(pool_k.scale, rows, axis=0)[:, :, None, :],
+                     jnp.take(pool_v.scale, rows, axis=0)[:, :, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, KH, M),

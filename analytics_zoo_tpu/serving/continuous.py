@@ -181,6 +181,12 @@ class _WeightedJit:
     def __call__(self, *args, **kwargs):
         return self._jit(*self._weights, *args, **kwargs)
 
+    def lower(self, *args, **kwargs):
+        """``jax.jit(...).lower`` with the weights in front: inspect the
+        compiled program (``.compile().memory_analysis()``) without
+        running it; operands may be ``jax.ShapeDtypeStruct``s."""
+        return self._jit.lower(*self._weights, *args, **kwargs)
+
     def _cache_size(self) -> int:
         return self._jit._cache_size()
 
@@ -1032,8 +1038,15 @@ class ContinuousEngine:
                 pos = jnp.minimum(pos + 1, Lmax - 1)
             else:
                 nxt = tok
+            # the chunk's first WRITE to the pool needs no value that
+            # the decode rows' last READ of it produces, so nothing
+            # orders the two and a compiler may keep a copy of the
+            # pool for the read (XLA:CPU does).  Ordering them by a
+            # value does: token ids are >= 0, so this adds 0.
+            wpos = (cpos + jnp.minimum(jnp.min(nxt), 0) if with_decode
+                    else cpos)
             clog, pk, pv = model.apply(
-                variables, ctoks, pk, pv, ctabs, cpos, clens,
+                variables, ctoks, pk, pv, ctabs, wpos, clens,
                 kernel=kern, mesh=kmesh, kv_sharded=kv_tp,
                 method=TransformerLM.prefill_chunk_paged)
             cnxt, _ = pick_next(
@@ -4047,16 +4060,23 @@ class ContinuousEngine:
                     for wd in (False, True):
                         if self.paged:
                             fn = self._get_fused(wd, sampled, use_topp)
-                            fn(_zeros_like(self._pk),
-                               _zeros_like(self._pv),
-                               tok, pos, done,
-                               jnp.full((S, self._M), SINK_BLOCK,
-                                        jnp.int32),
-                               temps, seeds, topps, ctoks, cpos,
-                               clens,
-                               jnp.full((kb, width), SINK_BLOCK,
-                                        jnp.int32),
-                               *czeros)
+                            # wait for the call: the next one allocates
+                            # its zeroed pools when it is dispatched,
+                            # and two calls in flight hold THREE pools.
+                            # Warm-up, not a serving loop: the sync is
+                            # the point
+                            # tpulint: disable-next-line=TZ001
+                            jax.block_until_ready(fn(
+                                _zeros_like(self._pk),
+                                _zeros_like(self._pv),
+                                tok, pos, done,
+                                jnp.full((S, self._M), SINK_BLOCK,
+                                         jnp.int32),
+                                temps, seeds, topps, ctoks, cpos,
+                                clens,
+                                jnp.full((kb, width), SINK_BLOCK,
+                                         jnp.int32),
+                                *czeros))
                         else:
                             fn = self._get_fused(wd, sampled,
                                                  use_topp, width)
@@ -4084,6 +4104,66 @@ class ContinuousEngine:
                     tok, pos, pos, done)
             count += 1
         return count
+
+    def paged_step_memory(self, program: str = "decode"
+                          ) -> Dict[str, int]:
+        """What the compiler reserves for one paged step program,
+        beside the pool, per device: ``pool_bytes`` (ONE of the two
+        pools; under a mesh one device's shard of it), ``temp_bytes``
+        and ``alias_bytes`` from ``memory_analysis()`` of the program
+        compiled for this engine's devices on abstract operands
+        (nothing runs, engine state is untouched).
+
+        ``program``: ``"decode"`` (the one-tick step), ``"chunk"`` (a
+        chunk tick without decode rows) or ``"fused"`` (decode + chunk),
+        the chunk half at one row of the widest chunk bucket and the
+        full table width.  The in-place contract of the
+        paged step (docs/serving_memory.md) reads here: both donated
+        pools aliased to the outputs (``alias_bytes >= 2 *
+        pool_bytes``) and temporaries far under one LAYER's slice of a
+        pool — a slice or a stack of layers inside the program, or a
+        write the compiler cannot do in place, shows as pool-sized
+        temporaries.  ``tests/test_paged_inplace.py`` and
+        ``chip_smoke.py`` hold it."""
+        if not self.paged or self.draft_model is not None:
+            raise ValueError("paged_step_memory reads the plain paged "
+                             "step programs (paged=True, no draft)")
+        if program not in ("decode", "chunk", "fused"):
+            raise ValueError(f"program must be 'decode', 'chunk' or "
+                             f"'fused', got {program!r}")
+        if program != "decode" and not self.chunked:
+            raise ValueError(f"{program!r} needs chunked=True")
+
+        spec = jax.ShapeDtypeStruct
+
+        def like(a):
+            return spec(a.shape, a.dtype, sharding=a.sharding)
+
+        S, kb = self._S, 1
+        pk = jax.tree_util.tree_map(like, self._pk)
+        pv = jax.tree_util.tree_map(like, self._pv)
+        rows = (spec((S,), jnp.int32), spec((S,), jnp.int32),
+                spec((S,), jnp.bool_), spec((S, self._M), jnp.int32),
+                spec((S,), jnp.float32), spec((S,), jnp.uint32),
+                spec((S,), jnp.float32))
+        if program == "decode":
+            lowered = self._get_step(1, False).lower(pk, pv, *rows)
+        else:
+            Cb = self._chunk_buckets[-1]
+            lowered = self._get_fused(
+                program == "fused", False, False).lower(
+                pk, pv, *rows, spec((kb, Cb), jnp.int32),
+                spec((kb,), jnp.int32), spec((kb,), jnp.int32),
+                spec((kb, self._M), jnp.int32),
+                spec((kb,), jnp.float32), spec((kb,), jnp.uint32),
+                spec((kb,), jnp.float32))
+        mem = lowered.compile().memory_analysis()
+        return {
+            "pool_bytes": sum(a.addressable_shards[0].data.nbytes
+                              for a in
+                              jax.tree_util.tree_leaves(self._pk)),
+            "temp_bytes": int(mem.temp_size_in_bytes),
+            "alias_bytes": int(mem.alias_size_in_bytes)}
 
     def _spec_tick(self, active) -> int:
         """One speculative round for the whole batch: every resident

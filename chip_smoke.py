@@ -252,6 +252,7 @@ def _serve_once(name, im, cfg_kwargs, prompts, fresh_prompts, max_new,
         metrics = _get(fe.port, "/metrics")
         if "zoo_engine_" not in metrics:
             raise AssertionError(f"{name}: /metrics has no engine family")
+        step_memory = _step_memory(name, serving.engines[0], platform)
         placed = []
         for eng in serving.engines:
             kv = (eng._pk, eng._pv) if eng.paged else (eng._ck, eng._cv)
@@ -264,7 +265,42 @@ def _serve_once(name, im, cfg_kwargs, prompts, fresh_prompts, max_new,
         return {"outs": outs, "engine_devices": placed,
                 "kv_bytes": int(report["arena_bytes"]),
                 "n_blocks": report.get("n_blocks"),
+                "step_memory": step_memory,
                 "requests": len(prompts) + len(fresh_prompts)}
+
+
+def _step_memory(name, eng, platform) -> dict:
+    """The paged step's in-place contract, read off the programs as
+    compiled for this device: the decode program and one chunk program
+    hold temporaries far under the pool they update (they held 1.3-3 x
+    the pool while the step sliced layers out of it).  Fails at half a
+    pool on the chip; the CPU dry run only prints (its compiler widens
+    bf16 around a scatter and interprets the kernel, temporaries and
+    all: tests/test_paged_inplace.py holds the contract there, in
+    float32)."""
+    if not (eng.paged and eng.chunked):
+        return {}
+    out = {}
+    for program in ("decode", "chunk"):
+        mem = eng.paged_step_memory(program)
+        say(f"serve[{name}]: {program} program temporaries "
+            f"{mem['temp_bytes'] / 2**20:.1f} MiB beside a pool of "
+            f"{mem['pool_bytes'] / 2**20:.1f} MiB per device (K; V is "
+            f"the same), {mem['alias_bytes'] / 2**20:.1f} MiB aliased "
+            f"in place")
+        out[program] = mem
+        if platform != "tpu":
+            continue
+        if mem["alias_bytes"] < 2 * mem["pool_bytes"]:
+            raise AssertionError(
+                f"{name}: the {program} program does not return the "
+                f"donated pools in place: {mem}")
+        if mem["temp_bytes"] >= mem["pool_bytes"] / 2:
+            raise AssertionError(
+                f"{name}: the {program} program's temporaries reach "
+                f"half a pool, so something copies the pool inside a "
+                f"step: {mem}")
+    return out
 
 
 def _prompts(rng, lengths, vocab):
@@ -297,8 +333,6 @@ def leg_serve(tiny: bool, platform: str) -> dict:
                            prompt_buckets=buckets)
     paged = dict(engine_slots=slots, engine_paged=True,
                  engine_chunked=True, engine_kernel="fused",
-                 # a modest fraction: the step programs hold temporaries
-                 # of 1.3-3x the pool beside it (PERF.md, open questions)
                  engine_hbm_fraction=HBM_FRACTION,
                  # one chunk covers a whole bucket: fewer chunk shapes
                  engine_tick_token_budget=buckets[-1] + slots)
