@@ -8,13 +8,17 @@ one per-layer metric is a file of its own:
     workloads/<cell>.json              the cell's own numbers (fixed rate, limits)
     layer_metrics/<metric>.json        which reader, its parameters
     layer_metrics/<reader>.py          ``read(run, params)`` -> number or None
+    families/<family>/                 what knows the architecture that the
+                                       configuration's ``family`` names
 """
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
+import re
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -69,3 +73,38 @@ def layer_metric(name: str):
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return spec, mod.read
+
+
+def families() -> list:
+    """The families present: the directories of ``families/``."""
+    root = os.path.join(BENCH_DIR, "families")
+    return sorted(d for d in os.listdir(root)
+                  if re.fullmatch(r"[A-Za-z0-9][A-Za-z0-9_]*", d)
+                  and os.path.isdir(os.path.join(root, d)))
+
+
+class Family:
+    """``families/<name>/``: its ``leaves``, ``reference``, ``counts`` and
+    ``model`` modules, each imported when asked for (``model`` alone
+    imports the package, and only harness/server.py asks for it)."""
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __getattr__(self, part: str):
+        if part not in ("leaves", "reference", "counts", "model"):
+            raise AttributeError(part)
+        return importlib.import_module(f"families.{self.name}.{part}")
+
+
+def family(cfg: dict) -> Family:
+    """The family a configuration names.  There is no default: a file
+    without the key, or naming a family that is absent, is an error."""
+    name, present = cfg.get("family"), families()
+    if name not in present:
+        raise SystemExit(
+            f"configuration {cfg.get('name')!r} "
+            + (f"names the family {name!r}, which is not under "
+               f"benchmark/families/" if name else "has no 'family' key")
+            + f" (present: {present})")
+    return Family(name)

@@ -5,13 +5,11 @@ It imports nothing of the program and takes nothing the program made: its
 weights come from harness/weights.py, from the seed, layer by layer, so a
 model whose float32 copy would not fit the chip still runs.
 
-Llama-family block as the configuration's source describes it: RMSNorm
-(x * rsqrt(mean x^2 + eps) * scale), Q/K/V projections (with bias where the
-configuration has ``attention_bias``), rotary embedding in the rotate-half
-convention with base ``rope_theta``, grouped-query causal attention scaled
-by 1/sqrt(head_dim), output projection, residual; RMSNorm, SwiGLU
-(down(silu(gate x) * up x)), residual; final RMSNorm; head = embedding
-transposed when ``tie_word_embeddings`` else its own matrix.
+What a layer and the head ARE the configuration's family says
+(families/<family>/reference.py: ``embed``, ``layer``, ``logits``); here
+are the helpers a family may build them from (``_q``, ``_mm``, ``_rms``,
+``_rope``) and everything that is the same for every family: the sample's
+padding, the loop over the layers, the control, the gap.
 
 ``quant="fp8"`` is the control of "How correct is decided": the same
 forward with both operands of every matmul rounded to float8_e4m3 (per-
@@ -27,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import cells
 from . import weights as W
 
 
@@ -61,56 +60,27 @@ def _rope(x, base):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _layer(cfg_items, quant, w, x):
-    """One decoder layer on one sequence x [T, E] float32."""
-    cfg = dict(cfg_items)
-    d = W.dims(cfg)
-    eps, base = cfg["rms_norm_eps"], cfg["rope_theta"]
-    w = {k: v.astype(jnp.float32) for k, v in w.items()}
-    h = _rms(x, w["ln_attn"], eps)
-    q = _mm("te,ehd->thd", h, w["wq"], quant)
-    k = _mm("te,ehd->thd", h, w["wk"], quant)
-    v = _mm("te,ehd->thd", h, w["wv"], quant)
-    if d["bias"]:
-        q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
-    q, k = _rope(q, base), _rope(k, base)
-    G = d["H"] // d["KH"]
-    k, v = jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
-    s = _mm("thd,shd->hts", q, k, quant) / jnp.sqrt(jnp.float32(d["D"]))
-    T = x.shape[0]
-    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = _mm("hts,shd->thd", p, v, quant)
-    x = x + _mm("thd,hde->te", o, w["wo"], quant)
-    h = _rms(x, w["ln_ffn"], eps)
-    g = jax.nn.silu(_mm("te,ef->tf", h, w["w_gate"], quant))
-    u = _mm("te,ef->tf", h, w["w_up"], quant)
-    return x + _mm("tf,fe->te", g * u, w["w_down"], quant)
+@functools.lru_cache(maxsize=None)
+def _layer_fn(frozen: str, kind, quant):
+    """One layer of kind ``kind``, jitted once per (configuration, kind,
+    precision): a pattern of many layers compiles one program a kind."""
+    cfg = W.thaw(frozen)
+    return jax.jit(functools.partial(
+        cells.family(cfg).reference.layer, cfg, kind, quant))
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fn(cfg_items, quant):
-    return jax.jit(functools.partial(_layer, cfg_items, quant))
+def _gap_fn(frozen: str, with_control: bool):
+    cfg = W.thaw(frozen)
+    logits = cells.family(cfg).reference.logits
 
-
-def _logits(cfg_items, quant, top, x, rows):
-    cfg = dict(cfg_items)
-    h = _rms(x[rows], top["ln_f"].astype(jnp.float32),
-             cfg["rms_norm_eps"])
-    if cfg["tie_word_embeddings"]:
-        return _mm("pe,ve->pv", h, top["embed"].astype(jnp.float32), quant)
-    return _mm("pe,ev->pv", h, top["head"].astype(jnp.float32), quant)
-
-
-@functools.lru_cache(maxsize=None)
-def _gap_fn(cfg_items, with_control: bool):
     def gaps(top, x, xc, rows, served):
-        ref = _logits(cfg_items, None, top, x, rows)
+        ref = logits(cfg, None, top, x, rows)
         best = ref.max(-1)
         take = lambda tok: jnp.take_along_axis(ref, tok[:, None], 1)[:, 0]
         out = {"served": best - take(served)}
         if with_control:
-            ctl = _logits(cfg_items, "fp8", top, xc, rows)
+            ctl = logits(cfg, "fp8", top, xc, rows)
             out["control"] = best - take(jnp.argmax(ctl, -1))
         return out
     return jax.jit(gaps)
@@ -127,24 +97,23 @@ def served_gaps(cfg: dict, seed: int, samples: list, *, t_pad: int,
     Every sequence is padded to ``t_pad`` and every row list to ``p_pad``
     (one compiled shape per cell); causal attention makes the padding
     invisible to the positions read."""
-    items = W._items(cfg)
+    fam, frozen = cells.family(cfg), W.freeze(cfg)
     top = W.top(cfg, seed)
-    embed = top["embed"].astype(jnp.float32)
     toks = np.zeros((len(samples), t_pad), np.int32)
     for i, (prompt, served) in enumerate(samples):
         seq = list(prompt) + list(served)
         if len(seq) > t_pad or len(served) > p_pad:
             raise ValueError("sample longer than the cell's padded shape")
         toks[i, :len(seq)] = seq
-    xs = [embed[toks[i]] for i in range(len(samples))]
+    xs = list(fam.reference.embed(cfg, top, jnp.asarray(toks)))
     xcs = list(xs) if control else None
-    for li in range(cfg["num_hidden_layers"]):
-        w = W.layer(cfg, seed, li)
-        xs = [_layer_fn(items, None)(w, x) for x in xs]
+    for li in range(fam.leaves.n_layers(cfg)):
+        w, kind = W.layer(cfg, seed, li), fam.leaves.kind(cfg, li)
+        xs = [_layer_fn(frozen, kind, None)(w, x) for x in xs]
         if control:
-            xcs = [_layer_fn(items, "fp8")(w, x) for x in xcs]
+            xcs = [_layer_fn(frozen, kind, "fp8")(w, x) for x in xcs]
     out = []
-    fn = _gap_fn(items, control)
+    fn = _gap_fn(frozen, control)
     for i, (prompt, served) in enumerate(samples):
         n = len(served)
         rows = np.zeros(p_pad, np.int32)

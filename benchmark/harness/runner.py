@@ -137,8 +137,9 @@ class Runner:
 
         tr = self.traffic
         rate = rate_rps or self.workload.get("rate_rps")
-        rows = schedule.make(tr, seed=self.seed, seconds=seconds,
-                             vocab=self.cfg["vocab_size"], rate_rps=rate)
+        rows = schedule.make(
+            tr, seed=self.seed, seconds=seconds, rate_rps=rate,
+            vocab=cells.family(self.cfg).leaves.vocab(self.cfg))
         sched = os.path.join(self.tmp, "schedule.jsonl")
         recs = os.path.join(self.tmp, "records.jsonl")
         schedule.write(rows, sched)

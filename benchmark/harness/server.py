@@ -1,10 +1,10 @@
 """The system under test, started the way a user starts it.
 
-The ONLY module of the benchmark that imports the package.  It maps a
-configuration file onto ``TransformerLM`` the way
-``net/hf_net.py:_from_llama_family`` maps a Hugging Face ``config.json``,
-hands the served model the benchmark's own seeded weights
-(harness/weights.py), and starts ``InferenceModel.load_flax_generator`` ->
+With each family's ``model.py``, the only modules of the benchmark that
+import the package.  The configuration's family builds the model object and
+places the benchmark's own seeded weights (harness/weights.py) in its tree
+(families/<family>/model.py); this module checks the tree against
+``model.init`` and starts ``InferenceModel.load_flax_generator`` ->
 ``ClusterServing(embedded_broker=True)`` -> ``HttpFrontend``.
 """
 
@@ -16,55 +16,18 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from . import cells
 from . import weights as W
 
 
-def build_model(cfg: dict):
-    from analytics_zoo_tpu.models import TransformerLM
-
-    if cfg.get("hidden_act", "silu") != "silu" or cfg.get("rope_scaling"):
-        raise ValueError("configuration outside the llama family as "
-                         "TransformerLM builds it")
-    return TransformerLM(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        intermediate_size=cfg["intermediate_size"],
-        max_position=cfg["max_position_embeddings"], dropout=0.0,
-        dtype=jnp.bfloat16, pos_encoding="rope",
-        rope_base=float(cfg["rope_theta"]), norm="rmsnorm", mlp="swiglu",
-        use_bias=False, qkv_bias=bool(cfg.get("attention_bias", False)),
-        tied_head=bool(cfg["tie_word_embeddings"]),
-        ln_eps=float(cfg["rms_norm_eps"]))
-
-
 def build_variables(model, cfg: dict, seed: int) -> dict:
-    """The benchmark's seeded bf16 leaves in the served model's own tree;
-    the tree and every shape are checked against ``model.init``."""
-    top = W.top(cfg, seed)
-    params = {"embed": {"embedding": top["embed"]},
-              "ln_f": {"scale": top["ln_f"]}}
-    if "head" in top:
-        params["lm_head"] = {"kernel": top["head"]}
-    for i in range(cfg["num_hidden_layers"]):
-        w = W.layer(cfg, seed, i)
-        attn = {"query": {"kernel": w["wq"]}, "key": {"kernel": w["wk"]},
-                "value": {"kernel": w["wv"]},
-                "attn_out": {"kernel": w["wo"]}}
-        if "bq" in w:
-            attn["query"]["bias"] = w["bq"]
-            attn["key"]["bias"] = w["bk"]
-            attn["value"]["bias"] = w["bv"]
-        params[f"layer_{i}"] = {
-            "ln_attn": {"scale": w["ln_attn"]}, "attention": attn,
-            "ln_ffn": {"scale": w["ln_ffn"]},
-            "ffn_gate": {"kernel": w["w_gate"]},
-            "ffn_up": {"kernel": w["w_up"]},
-            "ffn_down": {"kernel": w["w_down"]}}
+    """The benchmark's seeded bf16 leaves, placed by the configuration's
+    family in the served model's own tree; the tree and every shape are
+    checked against ``model.init``."""
+    params = cells.family(cfg).model.place(
+        cfg, W.top(cfg, seed), lambda i: W.layer(cfg, seed, i))
     variables = {"params": params}
     want = jax.eval_shape(model.init, jax.random.key(0),
                           np.zeros((1, 8), np.int32))
@@ -87,7 +50,9 @@ class Stack:
                                                HttpFrontend, ServingConfig)
 
         gen, eng = cfg["generator"], dict(cfg["engine"])
-        self.model = build_model(cfg)
+        fam = cells.family(cfg)
+        self.model = fam.model.build(cfg)
+        self.vocab = fam.leaves.vocab(cfg)
         variables = build_variables(self.model, cfg, seed)
         jax.block_until_ready(variables)
         im = InferenceModel(batch_buckets=(1, eng["engine_slots"]))
@@ -117,10 +82,9 @@ class Stack:
         n = self.engine.precompile_chunked(max_chunk_rows=max_chunk_rows)
         self.grid_s = time.monotonic() - t0
         rng = np.random.default_rng(0)
-        vocab = self.model.vocab_size
         for plen, max_new in lengths:
             post_generate(self.port, rng.integers(
-                1, vocab, plen).tolist(), max_new)
+                1, self.vocab, plen).tolist(), max_new)
         return n
 
     def stop(self) -> None:

@@ -89,8 +89,16 @@ def test_no_code_branches_on_a_cell_or_configuration_name():
 
 
 def test_configurations_differ_only_in_their_files():
-    a, b = (cells.load_json(os.path.join(cells.ROOT, c["file"]))
-            for c in B["configs"][:2])
-    assert set(a["engine"]) == set(b["engine"])
-    assert set(a["generator"]) == set(b["generator"])
-    assert json.dumps(a) != json.dumps(b)
+    """Every pair of one family: the same engine and generator knobs, the
+    same source keys, other values."""
+    import itertools
+
+    cfgs = [cells.load_json(os.path.join(cells.ROOT, c["file"]))
+            for c in B["configs"]]
+    pairs = [(a, b) for a, b in itertools.combinations(cfgs, 2)
+             if a["family"] == b["family"]]
+    assert pairs
+    for a, b in pairs:
+        assert set(a["engine"]) == set(b["engine"])
+        assert set(a["generator"]) == set(b["generator"])
+        assert json.dumps(a) != json.dumps(b)
