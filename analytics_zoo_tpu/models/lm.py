@@ -111,7 +111,7 @@ def beam_search(model: TransformerLM, variables, prompt,
                          f"{model.max_position}")
     V = model.vocab_size
     H = model.kv_heads                  # GQA: cache stores KV heads only
-    D = model.hidden_size // model.num_heads
+    D = model.head_size
     cdtype = jnp.dtype(model.dtype)
     ragged = prompt_len is not None
     plen = (jnp.full((B,), Pn, jnp.int32) if not ragged
@@ -352,6 +352,21 @@ class DecoderAttention(nn.Module):
     # qwen2-style split: biased q/k/v with a bias-free o_proj/mlp
     # (None follows use_bias)
     qkv_bias: Optional[bool] = None
+    # a head width of its own (None: hidden_size // num_heads), so that
+    # num_heads * head_dim need not be the hidden size
+    head_dim: Optional[int] = None
+    # RMSNorm over the head width of every q and k head, learned scale,
+    # before the rotary (the Qwen3 convention)
+    qk_norm: bool = False
+    ln_eps: float = 1e-6
+    # learned sparse attention (indexer_topk > 0): an indexer of
+    # indexer_heads query heads of indexer_head_dim and ONE index key a
+    # token scores every earlier position, and a query attends its
+    # indexer_topk best (ops.sparse_attention.index_scores).  The index
+    # key is cached beside K and V (``decode_paged_sparse``)
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    indexer_topk: int = 0
 
     def setup(self):
         H = self.num_heads
@@ -359,8 +374,25 @@ class DecoderAttention(nn.Module):
         if H % KH:
             raise ValueError(
                 f"num_heads {H} must be a multiple of num_kv_heads {KH}")
-        D = self.hidden_size // H
+        D = self.head_dim or self.hidden_size // H
         self._h, self._kh, self._d = H, KH, D
+        if self.qk_norm:
+            self.q_norm = nn.RMSNorm(dtype=jnp.float32, name="q_norm",
+                                     epsilon=self.ln_eps)
+            self.k_norm = nn.RMSNorm(dtype=jnp.float32, name="k_norm",
+                                     epsilon=self.ln_eps)
+        if self.indexer_topk:
+            IH, ID = self.indexer_heads, self.indexer_head_dim
+            self.idx_query = nn.DenseGeneral((IH, ID), dtype=self.dtype,
+                                             use_bias=False,
+                                             name="idx_query")
+            self.idx_key = nn.Dense(ID, dtype=self.dtype, use_bias=False,
+                                    name="idx_key")
+            self.idx_key_norm = nn.LayerNorm(dtype=jnp.float32,
+                                             epsilon=self.ln_eps,
+                                             name="idx_key_norm")
+            self.idx_weight = nn.Dense(IH, dtype=self.dtype,
+                                       use_bias=False, name="idx_weight")
         qkvb = self.use_bias if self.qkv_bias is None else self.qkv_bias
         self.query = nn.DenseGeneral((H, D), dtype=self.dtype,
                                      use_bias=qkvb,
@@ -387,14 +419,25 @@ class DecoderAttention(nn.Module):
         ``return_kv=True`` also returns this layer's K/V projections
         ``[B, T, KV_H, D]`` (KV-arena prefill for continuous batching)."""
         q, k, v = self.query(x), self.key(x), self.value(x)
+        if self.qk_norm:
+            q, k = self._normed_qk(q, k)
         if self.pos_encoding == "rope":
             t_pos = jnp.arange(x.shape[1])
             q = _apply_rope(q, t_pos, self.rope_base)
             k = _apply_rope(k, t_pos, self.rope_base)
-        o = attention_dispatch(q, self._expand_kv(k), self._expand_kv(v),
-                               None, causal=True, mesh=self.mesh,
-                               use_flash=self.use_flash,
-                               sp_strategy=self.sp_strategy)
+        if self.indexer_topk:
+            from analytics_zoo_tpu.ops.sparse_attention import \
+                sparse_attention
+
+            qi, ki, w = self._index_qkw(x, jnp.arange(x.shape[1]))
+            o = sparse_attention(q, k, v, qi, w, ki,
+                                 self.indexer_topk).astype(self.dtype)
+        else:
+            o = attention_dispatch(q, self._expand_kv(k),
+                                   self._expand_kv(v),
+                                   None, causal=True, mesh=self.mesh,
+                                   use_flash=self.use_flash,
+                                   sp_strategy=self.sp_strategy)
         out = self.attn_out(o)
         return (out, k, v) if return_kv else out
 
@@ -544,6 +587,58 @@ class DecoderAttention(nn.Module):
                             kv_sharded=kv_sharded)
         return self.attn_out(o.astype(self.dtype)), pool_k, pool_v
 
+    def _normed_qk(self, q, k):
+        return (self.q_norm(q).astype(self.dtype),
+                self.k_norm(k).astype(self.dtype))
+
+    def _index_qkw(self, x, pos):
+        """The indexer's side of S tokens: queries ``[B, S, IH, ID]`` and
+        the one index key a token ``[B, S, ID]`` (LayerNorm, then the
+        rotary over the whole width, like q and k), and the head weights
+        ``[B, S, IH]`` in float32."""
+        qi = self.idx_query(x)
+        ki = self.idx_key_norm(self.idx_key(x)).astype(self.dtype)
+        if self.pos_encoding == "rope":
+            qi = _apply_rope(qi, pos, self.rope_base)
+            ki = _apply_rope(ki[:, :, None, :], pos,
+                             self.rope_base)[:, :, 0]
+        return qi, ki, self.idx_weight(x).astype(jnp.float32)
+
+    def decode_paged_sparse(self, xs, pool_k, pool_v, pool_i, tables,
+                            pos, limit=None):
+        """:meth:`decode_paged` for a layer with an indexer: the same
+        contract, with token-major K/V pools ``[N, 1, bs, KH*D]`` and the
+        index-key arena ``pool_i`` ``[N, 1, bs, ID]``
+        written and read through the same tables, and the attention over
+        each query's selected positions only
+        (ops.sparse_attention.paged_sparse_attention).  Returns (ys,
+        pool_k, pool_v, pool_i, n_read [B] int32)."""
+        from analytics_zoo_tpu.ops.flash_attention import paged_kv_update
+        from analytics_zoo_tpu.ops.sparse_attention import (
+            paged_index_update, paged_sparse_attention)
+
+        q = self.query(xs)                              # [B, S, H, D]
+        ks = self.key(xs)                               # [B, S, KH, D]
+        vs = self.value(xs)
+        if self.qk_norm:
+            q, ks = self._normed_qk(q, ks)
+        p = pos[:, None] + jnp.arange(xs.shape[1])[None, :]
+        if self.pos_encoding == "rope":
+            q = _apply_rope(q, p, self.rope_base)
+            ks = _apply_rope(ks, p, self.rope_base)
+        qi, ki, w = self._index_qkw(xs, p)
+        # token-major pools [N, 1, bs, KH*D]: a token's KV heads lie side
+        # by side, written as ONE head (paged_sparse_attention says why)
+        row = lambda t: t.reshape(t.shape[:2] + (1, -1))
+        pool_k, pool_v = paged_kv_update(pool_k, pool_v, tables, pos,
+                                         row(ks), row(vs), limit=limit)
+        pool_i = paged_index_update(pool_i, tables, pos, ki, limit=limit)
+        o, n_read = paged_sparse_attention(
+            q, pool_k, pool_v, pool_i, tables, pos, qi, w,
+            self.indexer_topk)
+        return (self.attn_out(o.astype(self.dtype)), pool_k, pool_v,
+                pool_i, n_read)
+
 
 class DecoderLayer(nn.Module):
     """Pre-LN causal decoder block (pre-LN trains stably at depth without
@@ -573,6 +668,15 @@ class DecoderLayer(nn.Module):
     mlp: str = "gelu"
     use_bias: bool = True
     qkv_bias: Optional[bool] = None
+    # see TransformerLM
+    head_dim: Optional[int] = None
+    qk_norm: bool = False
+    experts: int = 0
+    experts_per_token: int = 8
+    expert_width: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    indexer_topk: int = 0
 
     def setup(self):
         self.ln_attn = _make_norm(self.norm, self.ln_eps, "ln_attn")
@@ -583,10 +687,20 @@ class DecoderLayer(nn.Module):
             sp_strategy=self.sp_strategy,
             pos_encoding=self.pos_encoding, rope_base=self.rope_base,
             use_bias=self.use_bias, qkv_bias=self.qkv_bias,
+            head_dim=self.head_dim, qk_norm=self.qk_norm,
+            ln_eps=self.ln_eps, indexer_heads=self.indexer_heads,
+            indexer_head_dim=self.indexer_head_dim,
+            indexer_topk=self.indexer_topk,
             name="attention")
         self.ln_ffn = _make_norm(self.norm, self.ln_eps,
                                  "ln_ffn")
-        if self.num_experts > 0:
+        if self.experts > 0:
+            from analytics_zoo_tpu.models.moe import DroplessMoE
+
+            self.moe = DroplessMoE(self.experts, self.expert_width,
+                                   top_k=self.experts_per_token,
+                                   dtype=self.dtype, name="moe")
+        elif self.num_experts > 0:
             from analytics_zoo_tpu.models.moe import MoEMLP
 
             self.moe = MoEMLP(self.num_experts, self.intermediate_size,
@@ -609,7 +723,10 @@ class DecoderLayer(nn.Module):
         self.drop = nn.Dropout(self.dropout)
 
     def _mlp(self, x, train):
-        if self.num_experts > 0:
+        if self.experts > 0:
+            # dropless: no capacity, so no coupling to the batch (moe.py)
+            h = self.moe(x)[0]
+        elif self.num_experts > 0:
             # Per-token routing runs for both the [B, T, E] training
             # forward and the [B, 1, E] cached decode step.  NOTE: the
             # capacity pool differs (B*T tokens jointly vs B per decode
@@ -655,6 +772,25 @@ class DecoderLayer(nn.Module):
         xs = xs + a
         xs = xs + self._mlp(self.ln_ffn(xs).astype(self.dtype), False)
         return xs, pk, pv
+
+    def decode_paged_sparse(self, xs, pool_k, pool_v, pool_i, tables, pos,
+                            limit=None, count=None):
+        """:meth:`decode_paged` of a layer with an indexer
+        (``DecoderAttention.decode_paged_sparse``).  Also returns what the
+        tick's counters read: ``n_read [B]``, the positions whose K/V the
+        attention read, and ``load [X]``, the assignments every expert got
+        from the tokens where ``count`` ``[B, S]`` is true (a single zero
+        for a layer without experts)."""
+        a, pk, pv, pi, n_read = self.attention.decode_paged_sparse(
+            self.ln_attn(xs).astype(self.dtype), pool_k, pool_v, pool_i,
+            tables, pos, limit=limit)
+        xs = xs + a
+        h = self.ln_ffn(xs).astype(self.dtype)
+        if self.experts > 0:
+            y, load = self.moe(h, count)
+        else:
+            y, load = self._mlp(h, False), jnp.zeros((1,), jnp.int32)
+        return xs + y, pk, pv, pi, n_read, load
 
     def forward_kv(self, x, train: bool = False):
         """``__call__`` that also returns this layer's K/V ``[B, T, H,
@@ -774,6 +910,26 @@ class TransformerLM(nn.Module):
     # qwen2-style: biased q/k/v despite bias-free o_proj/mlp
     qkv_bias: Optional[bool] = None
     tied_head: bool = True
+    # ---- sparse-expert, sparse-attention decoders ----------------------
+    # all default to the behaviour above.  ``head_dim``: a head width of
+    # its own (None: hidden_size // num_heads).  ``qk_norm``: RMSNorm over
+    # the head width of every q and k head.  ``experts`` > 0: every layer's
+    # MLP is ``models.moe.DroplessMoE`` (``experts_per_token`` of
+    # ``experts`` SiLU-gated experts of ``expert_width``; softmax, top-k,
+    # renormalise; nothing dropped).  ``indexer_topk`` > 0: learned sparse
+    # attention — ``indexer_heads`` index heads of ``indexer_head_dim``
+    # score every earlier position and a query attends its
+    # ``indexer_topk`` best; the index key is cached beside K and V, and
+    # the paged engine reaches such a model through the ``*_sparse``
+    # methods below (serving/continuous.py; docs/serving.md).
+    head_dim: Optional[int] = None
+    qk_norm: bool = False
+    experts: int = 0
+    experts_per_token: int = 8
+    expert_width: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    indexer_topk: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -781,11 +937,40 @@ class TransformerLM(nn.Module):
         allocation site — generate/beam/engine — sizes with this)."""
         return self.num_kv_heads or self.num_heads
 
+    @property
+    def head_size(self) -> int:
+        """Width of one attention head: ``head_dim`` where the model has
+        one of its own, else ``hidden_size // num_heads``.  What every
+        cache is sized by."""
+        return self.head_dim or self.hidden_size // self.num_heads
+
+    def paged_cache_geometry(self) -> dict:
+        """What this model caches per token and layer in the paged
+        engine, as ``{name: (heads, width)}`` of each block arena
+        ``[layers, N, heads, block_size, width]``: K and V of ``kv_heads x
+        head_size``; a model with an indexer keeps K and V TOKEN-major
+        (one head of ``kv_heads * head_size``: its read gathers whole
+        token rows) and adds the index key."""
+        if not self.indexer_topk:
+            return {"k": (self.kv_heads, self.head_size),
+                    "v": (self.kv_heads, self.head_size)}
+        row = (1, self.kv_heads * self.head_size)
+        return {"k": row, "v": row, "index": (1, self.indexer_head_dim)}
+
     def setup(self):
         if self.pos_encoding not in ("learned", "rope"):
             raise ValueError(
                 f"pos_encoding must be 'learned' or 'rope', got "
                 f"{self.pos_encoding!r}")
+        if (self.experts or self.indexer_topk or self.qk_norm
+                or self.head_dim) and self.pp_stages > 0:
+            raise ValueError(
+                "head_dim / qk_norm / experts / indexer_topk are not "
+                "built into pipelined trunks (pp_stages > 0)")
+        if self.experts and self.moe_experts:
+            raise ValueError("experts (dropless) and moe_experts "
+                             "(capacity-bounded) are two expert layers: "
+                             "set one")
         self.embed = nn.Embed(self.vocab_size, self.hidden_size,
                               name="embed")
         # rope rotates q/k inside attention: no absolute position table
@@ -858,6 +1043,13 @@ class TransformerLM(nn.Module):
                       ln_eps=self.ln_eps,
                       norm=self.norm, mlp=self.mlp,
                       use_bias=self.use_bias, qkv_bias=self.qkv_bias,
+                      head_dim=self.head_dim, qk_norm=self.qk_norm,
+                      experts=self.experts,
+                      experts_per_token=self.experts_per_token,
+                      expert_width=self.expert_width,
+                      indexer_heads=self.indexer_heads,
+                      indexer_head_dim=self.indexer_head_dim,
+                      indexer_topk=self.indexer_topk,
                       name=f"layer_{i}")
             for i in range(self.num_layers)]
 
@@ -903,6 +1095,7 @@ class TransformerLM(nn.Module):
                 "cached decode is not pipelined; convert the params with "
                 "models.lm.unstack_pp_params and generate on a "
                 "pp_stages=0 TransformerLM of the same dimensions")
+        self._no_arena_indexer()
         x = self.embed(tok)[:, None]
         if self.pos_embed is not None:
             x = x + (self.pos_embed(pos)[None, None]
@@ -941,6 +1134,7 @@ class TransformerLM(nn.Module):
             raise NotImplementedError(
                 "verify_step is not pipelined (same restriction as "
                 "decode_step); convert with models.lm.unstack_pp_params")
+        self._no_arena_indexer()
         B, S = toks.shape
         x = self.embed(toks)
         if self.pos_embed is not None:
@@ -1083,6 +1277,75 @@ class TransformerLM(nn.Module):
                                      axis=1)
         return self._logits(last_h)[:, 0], pk, pv
 
+    def _no_arena_indexer(self):
+        if self.indexer_topk:
+            raise NotImplementedError(
+                "a model with an indexer (indexer_topk > 0) caches an "
+                "index key beside K and V, which only the paged cache "
+                "holds: serve it with paged=True (the *_paged_sparse "
+                "methods); the slot-arena decode, generate() and "
+                "beam_search() have no place for it")
+
+    # ---- a model with an indexer against the paged cache ---------------
+    # Siblings of the three paged methods above, reached only where
+    # ``indexer_topk`` is set: the key side of the cache is an
+    # ``ops.sparse_attention.IndexedKeys`` (the K pool and the index-key
+    # pool), and each returns, after the pools, what the tick's counters
+    # read: ``n_read [B]`` (positions whose K/V the attention read for the
+    # row, the most over the layers) and ``load [layers, X]`` (assignments
+    # every expert got in every layer from the tokens of ``count``).
+
+    def _paged_sparse_trunk(self, x, pools_k, pools_v, tables, pos,
+                            limit, count):
+        from analytics_zoo_tpu.ops.sparse_attention import IndexedKeys
+
+        (pk, pv, pi), N = _flat_pools(
+            (pools_k.k, pools_v, pools_k.index))
+        n_read, loads = None, []
+        for i, layer in enumerate(self.layers):
+            x, pk, pv, pi, nr, load = layer.decode_paged_sparse(
+                x, pk, pv, pi, tables + i * N, pos, limit=limit,
+                count=count)
+            n_read = nr if n_read is None else jnp.maximum(n_read, nr)
+            loads.append(load)
+        pk, pv, pi = _stacked_pools((pk, pv, pi), len(self.layers))
+        return (self.ln_f(x), IndexedKeys(pk, pi), pv, n_read,
+                jnp.stack(loads))
+
+    def decode_step_paged_sparse(self, tok, pools_k, pools_v, tables, pos,
+                                 count=None):
+        """:meth:`decode_step_paged` of a model with an indexer.
+        ``count`` ``[B]`` bool: the rows whose tokens the expert load
+        counts (None: all).  Returns (logits [B, V], pools_k, pools_v,
+        n_read, load)."""
+        x = self.embed(tok)[:, None]
+        if self.pos_embed is not None:
+            x = x + self.pos_embed(pos)[:, None]
+        h, pk, pv, n_read, load = self._paged_sparse_trunk(
+            x.astype(self.dtype), pools_k, pools_v, tables, pos, None,
+            None if count is None else count[:, None])
+        return self._logits(h)[:, 0], pk, pv, n_read, load
+
+    def prefill_chunk_paged_sparse(self, toks, pools_k, pools_v, tables,
+                                   pos, lens, real=None):
+        """:meth:`prefill_chunk_paged` of a model with an indexer: the
+        chunk's K/V and index keys scatter through the tables, limited to
+        ``pos + lens``; padding columns, and the rows where ``real``
+        ``[B]`` is false, are not counted in the expert load.  Returns
+        (last-real-position logits [B, V], pools_k, pools_v, n_read,
+        load)."""
+        B, S = toks.shape
+        x = self.embed(toks)
+        if self.pos_embed is not None:
+            x = x + self.pos_embed(pos[:, None] + jnp.arange(S)[None, :])
+        h, pk, pv, n_read, load = self._paged_sparse_trunk(
+            x.astype(self.dtype), pools_k, pools_v, tables, pos,
+            pos + lens,
+            (jnp.arange(S)[None, :] < lens[:, None])
+            & (True if real is None else real[:, None]))
+        last_h = jnp.take_along_axis(h, (lens - 1)[:, None, None], axis=1)
+        return self._logits(last_h)[:, 0], pk, pv, n_read, load
+
     def prefill(self, tokens):
         """Causal forward that ALSO returns every layer's K/V: ``(logits
         [B, T, V], ks [n_layers, B, T, H, D], vs)``.  One MXU-friendly
@@ -1092,6 +1355,7 @@ class TransformerLM(nn.Module):
             raise NotImplementedError(
                 "prefill is not pipelined (same restriction as "
                 "decode_step); serve a pp_stages=0 restore instead")
+        self._no_arena_indexer()
         B, T = tokens.shape
         if T > self.max_position:
             raise ValueError(
@@ -1141,7 +1405,7 @@ def _gen_state(model, prompt, max_new_tokens, prompt_len):
     plen = (jnp.full((B,), Pn, jnp.int32) if prompt_len is None
             else jnp.clip(jnp.asarray(prompt_len, jnp.int32), 1, Pn))
     H = model.kv_heads                  # GQA: cache stores KV heads only
-    D = model.hidden_size // model.num_heads
+    D = model.head_size
     ck = jnp.zeros((model.num_layers, B, L, H, D),
                    jnp.dtype(model.dtype))
     return L, plen, ck, jnp.zeros_like(ck)

@@ -182,6 +182,71 @@ class MoEMLP(nn.Module):
         return with_sharding_constraint(t, P("ep", None, tp))
 
 
+class DroplessMoE(nn.Module):
+    """Token-choice top-k experts with NO capacity: every token reaches
+    every expert it chose, so a row's output does not depend on its
+    batchmates (``MoEMLP`` above drops tokens over capacity and stays for
+    what trains with it).
+
+    Router logits and softmax over ALL experts in float32, ``lax.top_k``,
+    the chosen gates renormalised to sum to 1 (softmax, then top-k, then
+    renormalise).  The ``N * top_k`` assignments are sorted by expert and
+    the three SiLU-gated, bias-free projections run as grouped matmuls
+    over the contiguous groups (``jax.lax.ragged_dot``: on the TPU one
+    kernel that multiplies each assignment through its own expert's
+    matrix and touches no expert that got no token), then un-sorted and
+    combined with the gates in float32.
+
+    Parameters: ``router [E, X]``, ``w_gate``/``w_up`` ``[X, E, F]``,
+    ``w_down`` ``[X, F, E]``.
+    """
+
+    num_experts: int
+    expert_width: int
+    top_k: int = 8
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, count=None):
+        """x ``[..., E]`` -> ``(y [..., E], load [X] int32)``: ``load[e]``
+        is how many assignments expert e got from the tokens where
+        ``count`` (bool ``x.shape[:-1]``; None counts all) is true —
+        padding is computed like any token but not counted."""
+        E = x.shape[-1]
+        X, F, K = self.num_experts, self.expert_width, self.top_k
+        if not 1 <= K <= X:
+            raise ValueError(f"top_k={K} must be in [1, {X}]")
+        init = nn.initializers.lecun_normal()
+        router = self.param("router", init, (E, X), jnp.float32)
+        w_gate = self.param("w_gate", init, (X, E, F), jnp.float32)
+        w_up = self.param("w_up", init, (X, E, F), jnp.float32)
+        w_down = self.param("w_down", init, (X, F, E), jnp.float32)
+        xt = x.reshape(-1, E)
+        N = xt.shape[0]
+        probs = jax.nn.softmax(
+            jnp.dot(xt.astype(jnp.float32), router.astype(jnp.float32)),
+            axis=-1)                                        # [N, X] f32
+        gates, chosen = jax.lax.top_k(probs, K)             # [N, K]
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        expert = chosen.reshape(-1)                         # [N*K]
+        order = jnp.argsort(expert)          # stable: by expert, then token
+        sizes = jnp.zeros((X,), jnp.int32).at[expert].add(1)
+        xs = xt.astype(self.dtype)[order // K]              # [N*K, E]
+        rd = lambda a, w: jax.lax.ragged_dot(
+            a, w.astype(self.dtype), sizes,
+            preferred_element_type=jnp.float32)
+        h = jax.nn.silu(rd(xs, w_gate)) * rd(xs, w_up)
+        ys = rd(h.astype(self.dtype), w_down)               # [N*K, E] f32
+        ya = ys[jnp.argsort(order)].reshape(N, K, E)        # un-sorted
+        y = jnp.einsum("nk,nke->ne", gates, ya)
+        if count is None:
+            load = sizes
+        else:
+            load = jnp.zeros((X,), jnp.int32).at[expert].add(
+                jnp.repeat(count.reshape(-1), K).astype(jnp.int32))
+        return y.reshape(x.shape).astype(x.dtype), load
+
+
 class MoETransformerLayer(nn.Module):
     """Post-LN encoder block with an MoE FFN (attention as in
     models/transformer.py).  Residual connections mean capacity-dropped

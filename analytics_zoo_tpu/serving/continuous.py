@@ -48,12 +48,14 @@ from analytics_zoo_tpu.models.lm import (TransformerLM,
 from analytics_zoo_tpu.models.speculative import accept_proposals
 from analytics_zoo_tpu.ops.flash_attention import (KV_SCALE_DTYPE,
                                                    QuantKV)
+from analytics_zoo_tpu.ops.sparse_attention import IndexedKeys
 from analytics_zoo_tpu.serving.frontdoor import (PRIORITIES, QosPolicy,
                                                  WeightedWaitQueue)
 from analytics_zoo_tpu.serving import policy as scheduler_policy
 from analytics_zoo_tpu.serving.paged_cache import (BlockPool,
                                                    SINK_BLOCK,
                                                    block_bytes,
+                                                   index_block_bytes,
                                                    split_block_budget)
 from analytics_zoo_tpu.serving.flight import FlightRecorder
 from analytics_zoo_tpu.serving.kv_store import (HostKVStore, TIER_HBM,
@@ -61,6 +63,12 @@ from analytics_zoo_tpu.serving.kv_store import (HostKVStore, TIER_HBM,
 from analytics_zoo_tpu.serving.telemetry import Telemetry
 
 logger = logging.getLogger("analytics_zoo_tpu")
+
+
+# the flight record's fields of a model with a sparse-attention indexer,
+# in the order the step program returns them (docs/observability.md)
+DSA_COUNTERS = ("dsa_ctx_tokens", "dsa_read_tokens", "moe_assignments",
+                "moe_max_load")
 
 
 def _zeros_like(x):
@@ -378,7 +386,19 @@ class ContinuousEngine:
         # bfloat16 arena under an f32 model: 2x more slots; attention
         # reads upcast via the einsums' f32 accumulation).
         H = getattr(model, "kv_heads", model.num_heads)
-        D = model.hidden_size // model.num_heads
+        # the model says how wide a head is: a model with a head_dim of
+        # its own has num_heads * head_size != hidden_size
+        D = getattr(model, "head_size", None) \
+            or model.hidden_size // model.num_heads
+        # ---- a model with a sparse-attention indexer -------------------
+        # caches an index key beside K and V (models/lm.py); the engine
+        # reaches it through sibling step programs and holds its key-side
+        # pools as ONE pytree (IndexedKeys), so everything below that
+        # donates, zeroes, resizes or measures "the K pool" covers the
+        # index keys too.  A model without one takes none of these
+        # branches: its programs are what they were.
+        self._dsa = bool(getattr(model, "indexer_topk", 0))
+        self._dsa_counts = dict.fromkeys(DSA_COUNTERS, 0)
         # validate cache_dtype EAGERLY with a serving-level message — a
         # bad value must not surface as a bare jnp.dtype TypeError deep
         # inside arena allocation
@@ -442,6 +462,25 @@ class ContinuousEngine:
                 "lockstep and re-admitting only the target tenant's "
                 "chain would desynchronize them — serve the host tier "
                 "on non-speculative replicas")
+        if self._dsa:
+            unsupported = [what for what, on in (
+                ("paged=False (the slot arena has no index-key cache)",
+                 not paged),
+                ("a tp mesh (the index-key pool has one head to shard)",
+                 mesh is not None and int(mesh.shape.get("tp", 1)) > 1),
+                ("kv_dtype='int8'", kv_dtype == "int8"),
+                ("a draft model", draft_model is not None),
+                ("the host tier (kv_host_store_bytes / "
+                 "prefix_directory)",
+                 kv_host_store_bytes > 0 or prefix_directory is not None),
+            ) if on]
+            if unsupported:
+                raise ValueError(
+                    "a model with a sparse-attention indexer "
+                    "(indexer_topk > 0) is served by the paged engine on "
+                    "one chip with a bf16/float cache; not supported "
+                    "with it: " + "; ".join(unsupported)
+                    + " (docs/serving.md)")
         self.kernel = kernel
         if kv_dtype == "bf16":
             # explicit storage request wins over cache_dtype/model dtype
@@ -546,11 +585,17 @@ class ContinuousEngine:
             else:
                 per_block = 2 * model.num_layers * bs * H * D \
                     * cdtype.itemsize
+            if self._dsa:
+                # one block id holds K, V and the index keys: the budget
+                # is divided over them by bytes a token
+                per_block += index_block_bytes(
+                    model.num_layers, bs, model.indexer_head_dim,
+                    cdtype.itemsize)
             draft_per_block = 0
             if draft_model is not None:
                 DHp = getattr(draft_model, "kv_heads",
                               draft_model.num_heads)
-                DDp = draft_model.hidden_size // draft_model.num_heads
+                DDp = draft_model.head_size
                 draft_per_block = 2 * draft_model.num_layers * bs \
                     * DHp * DDp * cdtype.itemsize
             self._per_block_bytes = per_block
@@ -638,6 +683,15 @@ class ContinuousEngine:
                     jnp.zeros(shape, jnp.int8, device=pool_sh),
                     jnp.ones(shape[:-1], KV_SCALE_DTYPE,
                              device=scale_sh))
+            elif self._dsa:
+                # the arenas the model says it caches, [layers, N, heads,
+                # bs, width] each: K and V token-major, and the index keys
+                geo = model.paged_cache_geometry()
+                arena = lambda name: jnp.zeros(
+                    (model.num_layers, n_blocks, geo[name][0], bs,
+                     geo[name][1]), cdtype, device=pool_sh)
+                self._pk = IndexedKeys(arena("k"), arena("index"))
+                self._pv = arena("v")
             else:
                 self._pk = jnp.zeros(shape, cdtype, device=pool_sh)
                 self._pv = jnp.zeros(shape, cdtype, device=pool_sh)
@@ -731,7 +785,7 @@ class ContinuousEngine:
             if draft_model is not None:
                 dH = getattr(draft_model, "kv_heads",
                              draft_model.num_heads)
-                dD = draft_model.hidden_size // draft_model.num_heads
+                dD = draft_model.head_size
                 bpt += 2 * draft_model.num_layers * dH * dD \
                     * cdtype.itemsize
             self._kv_bytes_per_token = bpt
@@ -911,6 +965,50 @@ class ContinuousEngine:
                 one, (tok, pos, done, pk, pv), None, length=n_ticks)
             return toks, tok, pos, done, pk, pv
 
+        def dsa_stats(ctx, n_read, load):
+            """What a tick of a model with an indexer says of itself, as
+            four int32: Σ over the decode rows of the positions in
+            context and of the positions whose K/V the attention read,
+            the assignments the experts got, and the most any one expert
+            got in one layer (``load`` [layers, X])."""
+            return jnp.stack([jnp.sum(ctx), jnp.sum(n_read),
+                              jnp.sum(load[0]), jnp.max(load)]
+                             ).astype(jnp.int32)
+
+        def dsa_live(done, tables):
+            """The rows that decode this tick: not frozen (a finished or
+            PREFILLING row is) and holding blocks (an empty slot's table
+            is all sink)."""
+            return ~done & jnp.any(tables != SINK_BLOCK, axis=1)
+
+        def step_fn_paged_dsa(variables, pk, pv, tok, pos, done, tables,
+                              temps, seeds, topps, n_ticks, use_sample,
+                              use_topp):
+            """``step_fn_paged`` of a model with an indexer: ``pk`` is an
+            ``IndexedKeys``, and the tick's four counters come back after
+            ``done`` (summed over the scan's ticks; the maximum load is
+            the largest of them).  Rows that do not decode (``dsa_live``)
+            count for nothing."""
+
+            def one(carry, _):
+                tok, pos, done, pk, pv = carry
+                live = dsa_live(done, tables)
+                logits, pk, pv, n_read, load = model.apply(
+                    variables, tok, pk, pv, tables, pos, live,
+                    method=TransformerLM.decode_step_paged_sparse)
+                stats = dsa_stats(jnp.where(live, pos + 1, 0),
+                                  jnp.where(live, n_read, 0), load)
+                nxt, done = pick_next(logits, pos, done, temps, seeds,
+                                      topps, use_sample, use_topp)
+                pos = jnp.minimum(pos + 1, Lmax - 1)
+                return (nxt, pos, done, pk, pv), (nxt, stats)
+
+            (tok, pos, done, pk, pv), (toks, stats) = jax.lax.scan(
+                one, (tok, pos, done, pk, pv), None, length=n_ticks)
+            stats = jnp.concatenate([jnp.sum(stats[:, :3], axis=0),
+                                     jnp.max(stats[:, 3:], axis=0)])
+            return toks, tok, pos, done, stats, pk, pv
+
         # one compiled program per (n_ticks, sampled) pair — n_ticks is
         # bounded by ticks_per_step, so the cache stays small
         self._step_cache: Dict[Tuple[int, bool, bool],
@@ -925,6 +1023,8 @@ class ContinuousEngine:
                 # timeline makes a late one — a retrace — stand out)
                 self.telemetry.jit_build("step", key)
                 fn = step_fn_paged if self.paged else step_fn
+                if self._dsa:
+                    fn = step_fn_paged_dsa
                 self._step_cache[key] = _WeightedJit(
                     partial(fn, n_ticks=n, use_sample=sampled,
                             use_topp=use_topp),
@@ -950,8 +1050,17 @@ class ContinuousEngine:
                 kernel=kern, mesh=kmesh, kv_sharded=kv_tp,
                 method=TransformerLM.prefill_chunk_paged)
 
-        self._paged_admit = _WeightedJit(paged_admit_fn, (variables,),
-                                         donate_argnums=(0, 1))
+        def paged_admit_dsa_fn(variables, pk, pv, suffixes, slens,
+                                tables, pos):
+            """``paged_admit_fn`` of a model with an indexer."""
+            last, pk, pv, _, _ = model.apply(
+                variables, suffixes, pk, pv, tables, pos, slens,
+                method=TransformerLM.prefill_chunk_paged_sparse)
+            return last, pk, pv
+
+        self._paged_admit = _WeightedJit(
+            paged_admit_dsa_fn if self._dsa else paged_admit_fn,
+            (variables,), donate_argnums=(0, 1))
 
         def prefill_fn(variables, prompts, plens):
             """Batched joiner prefill: [k, Pb] prompts in ONE forward
@@ -1055,6 +1164,43 @@ class ContinuousEngine:
                 ctopps, use_sample, use_topp)
             return nxt, pos, done, cnxt, pk, pv
 
+        def fused_paged_dsa_fn(variables, pk, pv, tok, pos, done, tables,
+                               temps, seeds, topps, ctoks, cpos, clens,
+                               ctabs, ctemps, cseeds, ctopps, with_decode,
+                               use_sample, use_topp):
+            """``fused_paged_fn`` of a model with an indexer: the same
+            tick through the ``*_paged_sparse`` methods, ``pk`` an
+            ``IndexedKeys``, and the tick's four counters returned after
+            ``cnxt``.  The decode rows that are live and the chunk rows
+            that are real (a padding row's table is all sink) are what
+            the counters count."""
+            ctx = n_read = jnp.zeros((1,), jnp.int32)
+            load = 0
+            if with_decode:
+                live = dsa_live(done, tables)
+                logits, pk, pv, n_read, load = model.apply(
+                    variables, tok, pk, pv, tables, pos, live,
+                    method=TransformerLM.decode_step_paged_sparse)
+                ctx = jnp.where(live, pos + 1, 0)
+                n_read = jnp.where(live, n_read, 0)
+                nxt, done = pick_next(logits, pos, done, temps, seeds,
+                                      topps, use_sample, use_topp)
+                pos = jnp.minimum(pos + 1, Lmax - 1)
+            else:
+                nxt = tok
+            wpos = (cpos + jnp.minimum(jnp.min(nxt), 0) if with_decode
+                    else cpos)          # orders the writes: fused_paged_fn
+            real = jnp.any(ctabs != SINK_BLOCK, axis=1)
+            clog, pk, pv, _, cload = model.apply(
+                variables, ctoks, pk, pv, ctabs, wpos, clens, real,
+                method=TransformerLM.prefill_chunk_paged_sparse)
+            cnxt, _ = pick_next(
+                clog, cpos + clens - 1,
+                jnp.zeros(clens.shape, jnp.bool_), ctemps, cseeds,
+                ctopps, use_sample, use_topp)
+            return (nxt, pos, done, cnxt,
+                    dsa_stats(ctx, n_read, load + cload), pk, pv)
+
         # one program per (with_decode, sampled, topp, read_len) —
         # read_len only varies on the arena path (O(log L) buckets)
         self._fused_cache: Dict[Tuple[bool, bool, bool, int],
@@ -1066,7 +1212,8 @@ class ContinuousEngine:
             if key not in self._fused_cache:
                 self.telemetry.jit_build("fused", key)
                 if self.paged:
-                    fn = partial(fused_paged_fn,
+                    fn = partial(fused_paged_dsa_fn if self._dsa
+                                 else fused_paged_fn,
                                  with_decode=with_decode,
                                  use_sample=sampled, use_topp=use_topp)
                 else:
@@ -1363,7 +1510,7 @@ class ContinuousEngine:
                 draft_paged_admit_fn, both[1:], donate_argnums=(0, 1))
         else:
             DH = getattr(draft, "kv_heads", draft.num_heads)
-            DD = draft.hidden_size // draft.num_heads
+            DD = draft.head_size
             dkv_sh = None
             if self.mesh is not None:
                 from jax.sharding import NamedSharding
@@ -1537,7 +1684,7 @@ class ContinuousEngine:
             # pool layout is [layers, N, KH, bs, D] (head-major for
             # the fused kernel); int8 pools are QuantKV, so bill from
             # the init-time ledger rather than re-deriving off dtypes
-            H = self._pk.shape[2]
+            H = getattr(self.model, "kv_heads", self.model.num_heads)
             per_block = self._per_block_bytes
             per_slot_max = per_block * self._M
             arena_equiv = (per_block // self._bs) * self._L * self._S
@@ -1546,7 +1693,8 @@ class ContinuousEngine:
                 "slots": self._S,
                 "cache_len": self._L,
                 "kv_heads": H,
-                "cache_dtype": str(self._pk.dtype),
+                "cache_dtype": str(
+                    jax.tree_util.tree_leaves(self._pk)[0].dtype),
                 "kv_dtype": self.kv_dtype,
                 "kernel": self.kernel,
                 "kv_bytes_per_token": self._kv_bytes_per_token,
@@ -3440,6 +3588,14 @@ class ContinuousEngine:
             from .fault import InjectedFault
             raise InjectedFault(msg)
 
+    def _note_dsa(self, counts) -> None:
+        """Book the four counters one device call of a model with an
+        indexer returned (``dsa_stats``) to the tick's flight record."""
+        c = self._dsa_counts
+        for name, v in zip(DSA_COUNTERS[:3], counts[:3]):
+            c[name] += int(v)
+        c[DSA_COUNTERS[3]] = max(c[DSA_COUNTERS[3]], int(counts[3]))
+
     def _tick_samples(self, n_active: int) -> dict:
         """Post-tick residency mix + queue/pool pressure, as plain host
         ints — the per-tick sample row of the ISSUE's event-log spec."""
@@ -3542,6 +3698,12 @@ class ContinuousEngine:
             self._alloc_fail_streak = \
                 self._alloc_fail_streak + 1 if fails else 0
             rec["alloc_fail_streak"] = self._alloc_fail_streak
+        if self._dsa:
+            # what the selection read and how the experts were loaded,
+            # from the step program itself (docs/observability.md); the
+            # record of any other model has none of these
+            rec.update(self._dsa_counts)
+            self._dsa_counts = dict.fromkeys(DSA_COUNTERS, 0)
         if self._qos is not None:
             rec["qos_depths"] = {f"{c}/{t}" if t else c: n
                                  for (c, t), n in
@@ -3683,7 +3845,9 @@ class ContinuousEngine:
         step = self._get_step(n_eff, sampled, use_topp)
         lap("plan")
         if self.paged:
-            toks, tok, pos, done, self._pk, self._pv = step(
+            # (*dsa: the four counters of a model with an indexer, and
+            # nothing for any other model)
+            toks, tok, pos, done, *dsa, self._pk, self._pv = step(
                 self._pk, self._pv, jnp.asarray(self._tok, jnp.int32),
                 jnp.asarray(self._pos, jnp.int32),
                 jnp.asarray(self._done, jnp.bool_),
@@ -3706,6 +3870,8 @@ class ContinuousEngine:
         self._tok = np.array(tok)
         self._pos = np.array(pos)
         self._done = np.array(done)
+        if self._dsa:
+            self._note_dsa(np.asarray(dsa[0]))
         lap("device_wait")
         for i in active:
             for j in range(n_eff):
@@ -3840,7 +4006,7 @@ class ContinuousEngine:
                 ctabs[j] = self._tables[i, :Mb]
             fused = self._get_fused(with_decode, sampled, use_topp)
             t_fused = lap("plan")
-            nxt, pos2, done2, cnxt, self._pk, self._pv = fused(
+            nxt, pos2, done2, cnxt, *dsa, self._pk, self._pv = fused(
                 self._pk, self._pv,
                 jnp.asarray(self._tok, jnp.int32),
                 jnp.asarray(self._pos, jnp.int32),
@@ -3879,8 +4045,13 @@ class ContinuousEngine:
                 jnp.asarray(ctopps, jnp.float32))
         lap("dispatch")
         # one host sync for decode picks + chunk first-token picks
-        nxt, pos2, done2, cnxt = jax.device_get(
-            (nxt, pos2, done2, cnxt))
+        if self._dsa:
+            nxt, pos2, done2, cnxt, counts = jax.device_get(
+                (nxt, pos2, done2, cnxt, dsa[0]))
+            self._note_dsa(counts)
+        else:
+            nxt, pos2, done2, cnxt = jax.device_get(
+                (nxt, pos2, done2, cnxt))
         # all of a tick's chunks land in the one fused call above, so
         # they share its span (per-chunk device timing doesn't exist)
         dur_fused = lap("device_wait") - t_fused
@@ -3931,7 +4102,9 @@ class ContinuousEngine:
         lap = self._lap
         lap("plan")
         if self.paged:
-            toks, tok, pos, done, self._pk, self._pv = step(
+            # (*dsa: the four counters of a model with an indexer, and
+            # nothing for any other model)
+            toks, tok, pos, done, *dsa, self._pk, self._pv = step(
                 self._pk, self._pv, jnp.asarray(self._tok, jnp.int32),
                 jnp.asarray(self._pos, jnp.int32),
                 jnp.asarray(self._done, jnp.bool_),
@@ -3952,6 +4125,8 @@ class ContinuousEngine:
         self._tok = np.array(tok)
         self._pos = np.array(pos)
         self._done = np.array(done)
+        if self._dsa:
+            self._note_dsa(np.asarray(dsa[0]))
         lap("device_wait")
         self._reanchor_prefill()
         for i in decode_rows:

@@ -118,6 +118,19 @@ def block_bytes(n_layers: int, block_size: int, kv_heads: int,
     return 2 * int(n_layers) * int(block_size) * int(kv_heads) * row
 
 
+def index_block_bytes(n_layers: int, block_size: int, index_dim: int,
+                      itemsize: int = 2) -> int:
+    """HBM bytes the INDEX KEYS of one physical block cost across all
+    layers, for a model with a sparse-attention indexer: one key of
+    ``index_dim`` a token and layer, beside the block's K and V
+    (:func:`block_bytes`) and on the same block id — allocated, freed,
+    preempted, shared and evicted with it, so the engine bills the sum as
+    the block's cost and ``hbm_fraction`` divides over both by bytes a
+    token (2048 : 128 a layer at 4 KV heads of 128 and an index key of
+    64)."""
+    return int(n_layers) * int(block_size) * int(index_dim) * int(itemsize)
+
+
 def split_block_budget(budget_bytes: int,
                        per_block_costs: Sequence[int]) -> int:
     """The COMMON block count every tenant can hold inside one HBM
