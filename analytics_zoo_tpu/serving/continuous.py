@@ -315,6 +315,11 @@ class ContinuousEngine:
         # the cycle's lap clock (telemetry.LapClock): step() and its
         # tick paths name every phase they pass through
         self._lap = self.telemetry.clock.lap
+        # called once per device call, right after it is enqueued and
+        # before the host waits for its result (_dispatched): the
+        # serving pump hangs its token flush here, so that the broker's
+        # fan-out runs while the device works.  None without a pump.
+        self.after_dispatch: Optional[Callable[[], None]] = None
         # ---- flight recorder (serving/flight.py) -----------------------
         # always-on bounded ring of per-tick state snapshots — the
         # incident lookback a diagnostic bundle ships.  One plain dict
@@ -336,7 +341,8 @@ class ContinuousEngine:
                              "spec_accepted": 0, "pool_resizes": 0,
                              "handoffs_out": 0, "handoffs_in": 0,
                              "kv_spills": 0, "kv_readmits": 0,
-                             "deadline_sheds": 0}
+                             "deadline_sheds": 0, "flush_events": 0,
+                             "flush_overlapped": 0}
         # ---- overload brownout + deadline admission (policy.py) --------
         # per-tick engine state the broker's plan_brownout controller
         # pushes via set_brownout(); 0/off by default, and every gate
@@ -2161,7 +2167,7 @@ class ContinuousEngine:
                     if self.draft_model is not None:
                         pre = pre + self._draft_prefill(
                             jnp.asarray(padded, jnp.int32))
-                    self._lap("dispatch")
+                    self._dispatched()
                     # ONE host fetch of the bucket's first-token logits;
                     # per-request picks below then stay on numpy
                     pre = (np.asarray(pre[0]),) + tuple(pre[1:])
@@ -2181,6 +2187,19 @@ class ContinuousEngine:
                         logger.exception("splice failed for %r", req.uri)
                         self._req_error(req.uri, req.on_error, e)
         return admitted
+
+    def _dispatched(self) -> None:
+        """A device call has just been enqueued: close the ``dispatch``
+        lap and run ``after_dispatch``.  Every tick path and admission
+        goes through here between its jitted call and the fetch of its
+        result.  The pools are already donated to the call, so nothing
+        the hook does may raise into the step."""
+        self._lap("dispatch")
+        if self.after_dispatch is not None:
+            try:
+                self.after_dispatch()
+            except Exception:
+                logger.exception("after_dispatch hook failed")
 
     def _req_error(self, uri, on_error, exc, phase: str = "admit"):
         """``phase`` is the cycle phase the caller is in (all but the
@@ -2256,7 +2275,7 @@ class ContinuousEngine:
         except Exception:
             self._free.extend(real)
             raise
-        self._lap("dispatch")
+        self._dispatched()
         last = np.asarray(last)     # one D2H for the whole group
         self._lap("device_wait")
         admitted = 0
@@ -2956,7 +2975,7 @@ class ContinuousEngine:
                 jnp.asarray(lens, jnp.int32),
                 jnp.asarray(dtabs, jnp.int32),
                 jnp.asarray(pos, jnp.int32))
-        self._lap("dispatch")
+        self._dispatched()
         last = np.asarray(last)     # one D2H for the whole group
         self._lap("device_wait")
         admitted = 0
@@ -3698,6 +3717,14 @@ class ContinuousEngine:
             self._alloc_fail_streak = \
                 self._alloc_fail_streak + 1 if fails else 0
             rec["alloc_fail_streak"] = self._alloc_fail_streak
+        if self.after_dispatch is not None:
+            # a pump drives this engine: the token-stream events it sent
+            # in this cycle, and those of them sent under a device call
+            rec["flush_events"] = delta(
+                "flush_events", self.telemetry.c_flush_events.value)
+            rec["flush_events_overlapped"] = delta(
+                "flush_overlapped",
+                self.telemetry.c_flush_overlapped.value)
         if self._dsa:
             # what the selection read and how the experts were loaded,
             # from the step program itself (docs/observability.md); the
@@ -3863,7 +3890,7 @@ class ContinuousEngine:
                 jnp.asarray(temps, jnp.float32),
                 jnp.asarray(seeds, jnp.uint32),
                 jnp.asarray(topps, jnp.float32))
-        lap("dispatch")
+        self._dispatched()
         toks = np.asarray(toks)                     # [n_eff, S]
         # np.asarray of a jax array is a read-only view; _admit writes
         # per-slot entries, so take mutable copies
@@ -4043,7 +4070,7 @@ class ContinuousEngine:
                 jnp.asarray(ctemps, jnp.float32),
                 jnp.asarray(cseeds, jnp.uint32),
                 jnp.asarray(ctopps, jnp.float32))
-        lap("dispatch")
+        self._dispatched()
         # one host sync for decode picks + chunk first-token picks
         if self._dsa:
             nxt, pos2, done2, cnxt, counts = jax.device_get(
@@ -4120,7 +4147,7 @@ class ContinuousEngine:
                 jnp.asarray(temps, jnp.float32),
                 jnp.asarray(seeds, jnp.uint32),
                 jnp.asarray(topps, jnp.float32))
-        lap("dispatch")
+        self._dispatched()
         toks = np.asarray(toks)
         self._tok = np.array(tok)
         self._pos = np.array(pos)
@@ -4379,7 +4406,7 @@ class ContinuousEngine:
                 jnp.asarray(self._pos, jnp.int32),
                 jnp.asarray(self._dpos, jnp.int32),
                 jnp.asarray(self._done, jnp.bool_))
-        lap("dispatch")
+        self._dispatched()
         toks = np.asarray(toks)                 # [k+1, S]
         n_emit = np.asarray(n_emit)
         self._tok = np.array(tok)
@@ -4506,7 +4533,7 @@ class ContinuousEngine:
                 jnp.asarray(clens, jnp.int32),
                 jnp.asarray(cslots, jnp.int32),
                 read_len=read_len)
-        lap("dispatch")
+        self._dispatched()
         cnxt = np.asarray(cnxt)     # one host sync for first-token picks
         dur_chunk = lap("device_wait") - t_chunk
         for i, clen in chunks:

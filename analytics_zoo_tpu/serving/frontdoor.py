@@ -53,9 +53,10 @@ class TokenEmitter:
 
     ``emit`` runs inside ``engine.step()`` on the pump thread and does
     two list appends — no Redis I/O, no locks, no device syncs, so the
-    hot decode loop's cost profile is unchanged.  After each ``step()``
-    the pump calls ``drain()`` and publishes everything in one
-    pipeline.  Terminal markers (``finish``/``error``/``cancelled``)
+    hot decode loop's cost profile is unchanged.  Once per step the
+    pump calls ``drain()`` and writes everything in one pipeline —
+    when the NEXT step's device call has been enqueued, or at the end
+    of its pass if none is coming.  Terminal markers (``finish``/``error``/``cancelled``)
     ride the same per-request buffer, so a request's final tokens are
     always published BEFORE its done marker even though ``on_done``
     fires mid-step.

@@ -441,6 +441,11 @@ class RespServer:
 # client
 # ---------------------------------------------------------------------------
 
+def _encode_command(cmd) -> bytes:
+    return encode([a if isinstance(a, (bytes, bytearray))
+                   else str(a).encode() for a in cmd])
+
+
 class RespClient:
     """Tiny RESP2 client (drop-in for redis-py's execute_command subset);
     thread-safe via a per-call lock."""
@@ -450,31 +455,48 @@ class RespClient:
         self.sock = socket.create_connection((host, port), timeout=timeout)
         self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         self.reader = _Reader(self.sock)
-        self.lock = threading.Lock()
+        # re-entrant: pipeline() holds it across its send and collect
+        self.lock = threading.RLock()
+        self._outstanding = 0       # replies of a sent pipeline not yet read
 
     def execute(self, *args):
-        payload = encode([a if isinstance(a, (bytes, bytearray))
-                          else str(a).encode() for a in args])
+        payload = _encode_command(args)
         with self.lock:
+            self._refuse_if_outstanding()
             self.sock.sendall(payload)
             return self.reader.read()
 
-    def pipeline(self, commands):
-        """Send many commands in one write, read all replies (real Redis
-        pipelining — one round-trip for N commands).
+    def _refuse_if_outstanding(self):
+        # replies come back in the order the commands went: a command
+        # written now would be answered with the sent pipeline's replies
+        if self._outstanding:
+            raise RuntimeError(
+                f"{self._outstanding} replies of a sent pipeline are "
+                f"outstanding on this connection: collect() them first")
+
+    def send(self, commands) -> None:
+        """First half of :meth:`pipeline`: write the commands in one
+        ``sendall`` and return without reading a reply.  At most one
+        pipeline is outstanding on a connection — nothing else may be
+        sent on it until :meth:`collect` has read the replies."""
+        payload = b"".join(_encode_command(cmd) for cmd in commands)
+        with self.lock:
+            self._refuse_if_outstanding()
+            self.sock.sendall(payload)
+            self._outstanding = len(commands)
+
+    def collect(self) -> list:
+        """Second half of :meth:`pipeline`: read every reply of the
+        pipeline that :meth:`send` wrote ([] if none is outstanding).
 
         Every reply is consumed even when some are errors — bailing out
         mid-stream would leave unread replies in the buffer and desync
         every later command on this connection.  The first error reply is
         raised after the stream is drained."""
-        payload = b"".join(
-            encode([a if isinstance(a, (bytes, bytearray))
-                    else str(a).encode() for a in cmd])
-            for cmd in commands)
         with self.lock:
-            self.sock.sendall(payload)
+            n, self._outstanding = self._outstanding, 0
             replies, first_err = [], None
-            for _ in commands:
+            for _ in range(n):
                 try:
                     replies.append(self.reader.read())
                 except RedisError as e:   # error reply: keep draining
@@ -483,6 +505,14 @@ class RespClient:
         if first_err is not None:
             raise first_err
         return replies
+
+    def pipeline(self, commands):
+        """Send many commands in one write, read all replies (real Redis
+        pipelining — one round-trip for N commands): :meth:`send` and
+        :meth:`collect` in turn."""
+        with self.lock:
+            self.send(commands)
+            return self.collect()
 
     def close(self):
         try:

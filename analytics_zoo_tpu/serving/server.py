@@ -1119,12 +1119,21 @@ class ClusterServing:
         pump pops its replica's routed queue; a ``kill_pump`` stops the
         claiming but the pump keeps stepping until ITS engine drains,
         so no admitted request is dropped by a graceful kill."""
+        client = tok_client = None
         try:
             client = RespClient(self.config.redis_host,
                                 self.config.redis_port)
+            # the token-stream events have a connection of their own:
+            # cancel, claim, the result pipelines and _finish_entries
+            # are round trips, and would queue behind a tick's XADDs in
+            # the broker's connection thread
+            tok_client = RespClient(self.config.redis_host,
+                                    self.config.redis_port)
         except OSError:
             logger.exception("continuous serving pump could not connect "
                              "to the broker — not started")
+            if client is not None:
+                client.close()
             self._pump_live[replica] = False
             return
         engine = self.engines[replica]
@@ -1145,7 +1154,10 @@ class ClusterServing:
         # streaming state is PUMP-THREAD-ONLY (on_done/on_token fire
         # inside engine.step() on this thread): the emitter buffers
         # per-token events between steps; one pipeline per step ships
-        # them — never a per-token broker round-trip
+        # them — never a per-token broker round-trip — and it is
+        # written when the NEXT step's device call has been enqueued,
+        # so the broker's and the front door's fan-out runs under the
+        # device and not between two steps (flush_under_device below)
         emitter = TokenEmitter(max_events=engine.max_new_tokens + 4)
         streaming: set = set()              # uris with a live tok: stream
         cancelled_pending: set = set()      # cancels that beat admission
@@ -1223,11 +1235,31 @@ class ClusterServing:
         # drives the engine from here on, and every stretch of the loop
         # below is booked to a named phase — the engine's step() names
         # its own and closes `submit` as it starts
-        clock = engine.telemetry.clock
+        tm = engine.telemetry
+        clock = tm.clock
         clock.drive("submit")
         lap = clock.lap
+        dispatched = False      # a device call was enqueued in this pass
+
+        def flush(overlapped: bool = False, wait: bool = False):
+            n = self._flush_emitter(tok_client, emitter, wait=wait)
+            if n:
+                tm.c_flush_events.inc(n)
+                if overlapped:
+                    tm.c_flush_overlapped.inc(n)
+
+        def flush_under_device():
+            # engine.after_dispatch: the step's device call is enqueued
+            # and the pump needs no GIL until its result is there
+            nonlocal dispatched
+            dispatched = True
+            flush(overlapped=True)
+            lap("flush")
+
+        engine.after_dispatch = flush_under_device
         try:
             while not self._stop.is_set():
+                dispatched = False
                 now = time.monotonic()
                 # heartbeat: the supervisor's liveness input.  Stamped
                 # every pass (busy or idle) so a healthy-but-quiet pump
@@ -1421,8 +1453,17 @@ class ClusterServing:
                             logger.exception(
                                 "brownout controller step failed")
                     lap("control")
-                self._flush_emitter(client, emitter)
+                # what the emitter holds now waits for the next device
+                # call only if one is coming: this pass enqueued one and
+                # the engine still has work.  Else (the engine went idle,
+                # a cancelled or error marker with no step behind it, a
+                # step that enqueued nothing) it leaves here, so no
+                # event waits longer than one pass
+                if not (dispatched and (engine.n_active > 0
+                                        or engine.n_waiting > 0)):
+                    flush()
                 lap("flush")
+            flush(wait=True)        # leaving in good order: nothing stays
         except Exception:
             # an exception escaping the pump loop used to die silently
             # in the thread, leaving a zombie entry in the router's
@@ -1438,12 +1479,19 @@ class ClusterServing:
             except Exception:
                 logger.exception("crash bundle dump failed "
                                  "(replica %d)", replica)
+            # the broker has every token of this attempt BEFORE the
+            # supervisor can write a redispatch's `restart` marker on
+            # its own connection: a stale token behind the marker would
+            # pass the consumer's reset index watermark
+            flush(wait=True)
             self._declare_dead(replica, "pump_exception")
         finally:
+            engine.after_dispatch = None
             self._pump_live[replica] = False
             with self._rq_cond:
                 self._rq_cond.notify_all()   # wake the router's sweep
             client.close()
+            tok_client.close()
 
     def _diag_poll(self, engine, replica: int = 0) -> None:
         """One cheap anomaly check per pump iteration: three counter
@@ -1533,13 +1581,26 @@ class ClusterServing:
                       max(1, self.config.diag_max_bundles))
         return path
 
-    def _flush_emitter(self, client: RespClient,
-                       emitter: TokenEmitter) -> None:
+    def _flush_emitter(self, client: RespClient, emitter: TokenEmitter,
+                       wait: bool = False) -> int:
         """Publish every token/terminal event buffered since the last
-        engine step in ONE pipeline — per-step, never per-token."""
+        flush in ONE pipeline — per-step, never per-token — and return
+        how many went.  The pipeline is written and NOT waited for: its
+        replies are read by the next flush that has something to send,
+        a device step later (if the broker has not answered by then,
+        that wait is the back-pressure that bounds the backlog to one
+        pipeline).  ``wait`` reads them at once (the pump leaving)."""
+        def collect():
+            try:
+                client.collect()
+            except Exception:
+                logger.exception("token-stream publish failed")
+
         batch = emitter.drain()
+        if batch or wait:
+            collect()
         if not batch:
-            return
+            return 0
         cmds = []
         for uri, events in batch:
             key = TOKEN_PREFIX + uri
@@ -1554,9 +1615,13 @@ class ClusterServing:
                     cmds.append(("XADD", key, "*", "error",
                                  str(val)[:500]))
         try:
-            client.pipeline(cmds)
+            client.send(cmds)
         except Exception:
             logger.exception("token-stream publish failed")
+            return 0
+        if wait:
+            collect()
+        return len(cmds)
 
     def _drain_cancels(self, client: RespClient, emitter: TokenEmitter,
                        streaming: set, cancelled_pending: set) -> int:

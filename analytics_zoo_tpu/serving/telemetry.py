@@ -617,6 +617,16 @@ class Telemetry:
                 p + f"phase_cpu_seconds_total_{ph}",
                 f"CPU seconds the pump thread ran in phase {ph}")
             for ph in PHASES}
+        # the serving pump's token flush (server._flush_emitter): how
+        # many token-stream events went to the broker, and how many of
+        # them while a device call was in flight (engine.after_dispatch)
+        # and not between two steps
+        self.c_flush_events = m.counter(
+            p + "flush_events_total",
+            "token-stream events the pump sent to the broker")
+        self.c_flush_overlapped = m.counter(
+            p + "flush_events_overlapped_total",
+            "token-stream events sent while a device call was in flight")
         self.h_spec_accept = m.histogram(
             p + "spec_accept_len",
             "accepted draft tokens per row per verify round (0..k)",

@@ -25,10 +25,14 @@ PUMP = ["observe", "control", "flush", "house", "cancel", "claim", "submit"]
 STEP = ["admit", "plan", "dispatch", "device_wait", "book", "publish"]
 # one letter per in-step phase: plan -> dispatch -> device_wait -> book
 # (callbacks taken out of it) between two admissions; a monolithic
-# admission runs its own prefill (dispatch, device_wait) inside admit
+# admission runs its own prefill (dispatch, device_wait) inside admit.
+# Under a pump every device call is followed by the pump's token flush
+# (engine.after_dispatch): dispatch -> flush -> device_wait
 LETTER = dict(admit="a", plan="p", dispatch="d", device_wait="w", book="b",
-              publish="u")
-IN_STEP = re.compile(r"^(a|dw|u)*a(p(dw(b|u)+)?)*(a|dw|u)*a$")
+              publish="u", flush="f")
+_IN_STEP = r"^(a|{dw}|u)*a(p({dw}(b|u)+)?)*(a|{dw}|u)*a$"
+IN_STEP = re.compile(_IN_STEP.format(dw="dw"))
+IN_STEP_PUMPED = re.compile(_IN_STEP.format(dw="dfw"))
 # a lap's two clocks are read one after the other
 SLACK_MS = 0.1 + time.get_clock_info("thread_time").resolution * 1e3
 
@@ -187,6 +191,8 @@ def test_every_cycle_is_accounted_for_with_no_remainder(served):
         first_admit = [p[0] for p in b["phases"]].index("admit")
         assert sum(p[1] for p in b["phases"][first_admit:]) == \
             pytest.approx(b["dur_ms"], abs=0.02)
+        # a pump drives the engine: what its token flush sent this cycle
+        assert 0 <= b["flush_events_overlapped"] <= b["flush_events"]
 
 
 def test_phase_names_are_the_documented_set_in_cycle_order(served):
@@ -200,14 +206,22 @@ def test_phase_names_are_the_documented_set_in_cycle_order(served):
         names = [p[0] for p in t["phases"]]
         k = names.index("admit")
         assert names[:k] == PUMP, names
-        assert IN_STEP.match("".join(LETTER[n] for n in names[k:])), names
+        # every device call is followed by the flush, then the wait
+        assert IN_STEP_PUMPED.match(
+            "".join(LETTER[n] for n in names[k:])), names
     if served["kind"] in ("chunked", "decode-only"):
         # the benchmark's engine: one device call a step, so the first
-        # occurrences are the catalog's order exactly
+        # occurrences are the catalog's order exactly, and the in-step
+        # flush is one lap between dispatch and device_wait
         for t in busy:
             names = [p[0] for p in t["phases"]]
             first = sorted(set(names), key=names.index)
             assert first == [n for n in PUMP + STEP if n in first], names
+            if "dispatch" in names:
+                d = names.index("dispatch")
+                assert names[d:d + 3] == ["dispatch", "flush",
+                                          "device_wait"], names
+                assert names.count("flush") == 2
         assert "publish" in seen and "plan" in seen
     if served["kind"] == "decode-only":
         assert any(t["kind"] == "chunked" and t["chunks"] == 0
@@ -258,7 +272,7 @@ def test_counters_and_spans_carry_the_same_cycle(served):
         {e["name"] for e in spans}
     # the in-step phases nest under their tick's span
     tick = [e for e in spans if e["name"] == "tick"][-1]
-    inside = [e for e in spans if e["name"] in STEP
+    inside = [e for e in spans if e["name"] in STEP + ["flush"]
               and tick["ts"] - 1 <= e["ts"]
               and e["ts"] + e["dur"] <= tick["ts"] + tick["dur"] + 1]
     assert sum(e["dur"] for e in inside) == pytest.approx(tick["dur"],
@@ -290,9 +304,9 @@ def test_a_sleep_is_wall_and_a_busy_loop_is_cpu(lm, monkeypatch):
         flush = ClusterServing._flush_emitter
         plan = scheduler_policy.plan_chunks
 
-        def slow_flush(self, client, emitter):
+        def slow_flush(self, *a, **kw):
             time.sleep(0.02)                # a broker that answers late
-            return flush(self, client, emitter)
+            return flush(self, *a, **kw)
 
         def hot_plan(*a, **kw):
             c0 = time.thread_time()         # a planner with too much to do
@@ -357,6 +371,8 @@ def test_without_the_pump_the_rest_of_the_cycle_is_outside(lm, mode):
         assert sum(p[1] for p in b["phases"]) == pytest.approx(
             cycle_ms, abs=0.02)
     assert any("publish" in [p[0] for p in t["phases"]] for t in ticks)
+    # no pump, no token flush: neither the lap nor the counters
+    assert not any("flush_events" in t for t in ticks)
 
 
 @pytest.mark.parametrize("mode", list(ENGINES))
@@ -369,3 +385,56 @@ def test_greedy_tokens_are_bitwise_equal_without_the_ring(lm, mode):
         np.testing.assert_array_equal(done_on[u], done_off[u])
     # no ring, but the counters still run
     assert off.telemetry.c_phase_wall["device_wait"].value > 0
+
+
+# ---------------------------------------------------------------------------
+# the token flush under the device: where it runs, what it counts
+# ---------------------------------------------------------------------------
+
+def test_streamed_tokens_are_flushed_under_the_next_device_call(lm):
+    """Streaming requests through the pump: every token event and every
+    ``done`` marker is counted once in ``flush_events``; those that left
+    from ``engine.after_dispatch`` (a device call in flight) are the
+    overlapped ones, the rest left at the end of a pass (the engine went
+    idle) — and the in-step flush is booked between ``dispatch`` and
+    ``device_wait`` with the step's identity intact."""
+    from analytics_zoo_tpu.serving import InputQueue, OutputQueue
+
+    serving = _serve(lm, KINDS["chunked"][0], max_new=12)
+    try:
+        eng = serving.engine
+        inq = InputQueue(port=serving.port)
+        outq = OutputQueue(port=serving.port)
+        rng = np.random.default_rng(31)
+        names = [f"s{i}" for i in range(3)]
+        for name, n in zip(names, (5, 9, 14)):
+            inq.enqueue(name, tokens=rng.integers(1, 32, n).astype(np.int32),
+                        stream=np.int32(1))
+        for name in names:
+            evs = [e for e in outq.stream_events(name, timeout=600)
+                   if "ping" not in e]
+            assert evs[-1] == {"done": True}
+            assert [e["index"] for e in evs[:-1]] == list(range(12))
+        time.sleep(0.3)
+        ticks = eng.flight.snapshot()
+        tm = eng.telemetry
+    finally:
+        serving.stop()
+    sent = 3 * 12 + 3               # tokens and done markers
+    assert tm.c_flush_events.value == sent
+    assert sum(t["flush_events"] for t in ticks) <= sent    # + the open cycle
+    over = tm.c_flush_overlapped.value
+    assert sum(t["flush_events_overlapped"] for t in ticks) == over
+    # all but the last tick's events found a device call to leave under
+    assert sent - 12 <= over < sent
+    text = render_prometheus(tm.metrics)
+    assert f"zoo_engine_flush_events_total {sent}" in text
+    assert f"zoo_engine_flush_events_overlapped_total {over}" in text
+    for t in ticks:
+        names_ = [p[0] for p in t["phases"]]
+        if t["flush_events_overlapped"]:
+            d = names_.index("dispatch")
+            assert names_[d + 1] == "flush", names_
+        k = names_.index("admit")
+        assert sum(p[1] for p in t["phases"][k:]) == pytest.approx(
+            t["dur_ms"], abs=0.02)

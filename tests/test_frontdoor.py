@@ -892,3 +892,244 @@ class TestStreamingStack:
         finally:
             fe.stop()
             serving.stop()
+
+
+# ---------------------------------------------------------------------------
+# a tick's tokens leave under the NEXT step's device call: what is
+# delivered, and in what order, is what the pump delivered when it waited
+# for the broker between two steps (server._flush_emitter,
+# engine.after_dispatch)
+# ---------------------------------------------------------------------------
+
+def _plain_stack(max_new=16, slots=3, **cfg_kw):
+    """paged + chunked ClusterServing, no draft, no front end."""
+    model = _tiny_lm()
+    variables = model.init(jax.random.key(0), np.zeros((1, 8), np.int32))
+    im = InferenceModel().load_flax_generator(
+        model, variables, max_new_tokens=max_new, prompt_buckets=(8,))
+    cfg = ServingConfig(**dict(dict(
+        prompt_col="tokens", continuous_batching=True, engine_slots=slots,
+        engine_paged=True, engine_block_size=4, engine_chunked=True,
+        engine_tick_token_budget=16), **cfg_kw))
+    serving = ClusterServing(im, cfg, embedded_broker=True).start()
+    return model, variables, serving
+
+
+def _raw_stream(client, uri):
+    """The token stream as the broker holds it: ('tok', index, token),
+    ('restart', attempt) and terminal markers, in entry order."""
+    out = []
+    for _, flat in client.execute("XRANGE", "tok:" + uri, "-", "+"):
+        f = {flat[i].decode(): flat[i + 1] for i in range(0, len(flat), 2)}
+        if "t" in f:
+            out.append(("tok", int(f["i"]), int(f["t"])))
+        elif "restart" in f:
+            out.append(("restart", int(f["restart"])))
+        else:
+            out.append((next(iter(f)),))
+    return out
+
+
+def _wait_results(client, uris, timeout=120):
+    deadline = time.monotonic() + timeout
+    for u in uris:
+        while not client.execute("HGETALL", "result:" + u):
+            assert time.monotonic() < deadline, f"{u} never landed"
+            time.sleep(0.02)
+
+
+class TestTokensLeaveUnderTheDevice:
+    @pytest.fixture(scope="class")
+    def stack(self):
+        model, variables, serving = _plain_stack()
+        inq = InputQueue(port=serving.port)
+        outq = OutputQueue(port=serving.port)
+        try:
+            yield model, variables, serving, inq, outq
+        finally:
+            inq.close()
+            outq.close()
+            serving.stop()
+
+    def test_concurrent_streams_in_index_order_terminal_last(self, stack):
+        """Five streams over three slots (two wait for a slot): each
+        arrives in index order with ``done`` last, and the greedy tokens
+        are bitwise those of solo generation."""
+        model, variables, serving, inq, outq = stack
+        rng = np.random.default_rng(41)
+        prompts = {f"o{i}": rng.integers(1, 32, 3 + i).astype(np.int32)
+                   for i in range(5)}
+        for u, p in prompts.items():
+            inq.enqueue(u, tokens=p, stream=np.int32(1))
+        c = RespClient("127.0.0.1", serving.port)
+        _wait_results(c, prompts)
+        time.sleep(0.3)
+        for u, p in prompts.items():
+            raw = _raw_stream(c, u)         # nothing deduplicated
+            assert raw[-1] == ("done",), (u, raw)
+            assert [e[1] for e in raw[:-1]] == list(range(16)), (u, raw)
+            ref = np.asarray(generate(model, variables,
+                                      jnp.asarray(p[None]), 16))[0]
+            np.testing.assert_array_equal(
+                np.asarray([e[2] for e in raw[:-1]], np.int32), ref)
+            evs = [e for e in outq.stream_events(u, timeout=60)
+                   if "ping" not in e]
+            assert evs[-1] == {"done": True} and len(evs) == 17
+            np.testing.assert_array_equal(
+                np.asarray(outq.query(u, timeout=60)), ref)
+        c.close()
+        tm = serving.engine.telemetry
+        assert tm.c_flush_events.value >= 5 * 17
+        assert tm.c_flush_overlapped.value >= 5 * 17 // 2
+
+    def test_last_done_leaves_in_the_pass_the_engine_goes_idle(
+            self, stack, monkeypatch):
+        """The last running request's final token and ``done`` do not
+        wait for a next device call: with the idle claim made to take
+        1.5 s, they are in the broker long before the next pass ends."""
+        model, variables, serving, inq, outq = stack
+        read = ClusterServing._read_batch
+
+        def slow_idle_claim(self, client, consumer, block_ms=200):
+            if block_ms >= 200:             # the engine is empty
+                time.sleep(1.5)
+            return read(self, client, consumer, block_ms)
+
+        monkeypatch.setattr(ClusterServing, "_read_batch", slow_idle_claim)
+        p = np.arange(1, 6, dtype=np.int32)
+        inq.enqueue("last", tokens=p, stream=np.int32(1))
+        c = RespClient("127.0.0.1", serving.port)
+        _wait_results(c, ["last"])          # published inside the step
+        t_result = time.monotonic()
+        deadline = t_result + 10
+        while True:
+            raw = _raw_stream(c, "last")
+            if raw and raw[-1] == ("done",):
+                break
+            assert time.monotonic() < deadline, raw
+            time.sleep(0.01)
+        assert time.monotonic() - t_result < 1.0
+        assert [e[1] for e in raw[:-1]] == list(range(16))
+        c.execute("DEL", "tok:last")
+        c.close()
+
+    def test_cancelled_marker_with_no_step_behind_it_arrives(self, stack):
+        """A cancel that beat its request's admission: the marker is put
+        in the emitter at claim time, no device call follows, and it
+        leaves at the end of that pass."""
+        model, variables, serving, inq, outq = stack
+        inq.cancel("early")
+        deadline = time.monotonic() + 30
+        while inq.client.execute("XRANGE", "serving_cancel", "-", "+"):
+            assert time.monotonic() < deadline      # the pump parks it
+            time.sleep(0.02)
+        inq.enqueue("early", tokens=np.arange(1, 5, dtype=np.int32),
+                    stream=np.int32(1))
+        evs = [e for e in outq.stream_events("early", timeout=30)
+               if "ping" not in e]
+        assert evs == [{"cancelled": True}]
+
+    def test_midstream_cancel_ends_the_stream_in_order(self, stack):
+        model, variables, serving, inq, outq = stack
+        inq.enqueue("mid", tokens=np.arange(1, 6, dtype=np.int32),
+                    stream=np.int32(1))
+        saw = []
+        for ev in outq.stream_events("mid", timeout=60):
+            if "ping" in ev:
+                continue
+            saw.append(ev)
+            if len(saw) == 1:
+                inq.cancel("mid")
+        assert saw[-1] in ({"cancelled": True}, {"done": True})
+        assert [e["index"] for e in saw[:-1]] == list(range(len(saw) - 1))
+
+    def test_submit_error_marker_arrives(self, stack):
+        """A streaming request the engine refuses (prompt over the
+        widest bucket): the ``error`` marker has no step behind it."""
+        model, variables, serving, inq, outq = stack
+        inq.enqueue("wide", tokens=np.ones(40, np.int32),
+                    stream=np.int32(1))
+        evs = [e for e in outq.stream_events("wide", timeout=30)
+               if "ping" not in e]
+        assert len(evs) == 1 and "submit failed" in evs[0]["error"]
+
+
+def test_graceful_kill_pump_loses_no_stream_event():
+    """``kill_pump`` while streams are in flight on the killed replica:
+    it drains in place, and every stream is whole — indices in order,
+    ``done`` last, tokens those of solo generation."""
+    model, variables, serving = _plain_stack(slots=2, n_replicas=2)
+    try:
+        inq = InputQueue(port=serving.port)
+        outq = OutputQueue(port=serving.port)
+        rng = np.random.default_rng(43)
+        prompts = {f"k{i}": rng.integers(1, 32, 3 + i % 4).astype(np.int32)
+                   for i in range(6)}
+        for u, p in prompts.items():
+            inq.enqueue(u, tokens=p, stream=np.int32(1))
+        c = RespClient("127.0.0.1", serving.port)
+        deadline = time.monotonic() + 120
+        while not any(len(_raw_stream(c, u)) for u in prompts):
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        serving.kill_pump(1)                # streams are in flight
+        _wait_results(c, prompts)
+        t1 = next(t for t in serving._threads
+                  if t.name == "zoo-serving-cb-1")
+        t1.join(timeout=60)
+        assert not t1.is_alive(), "pump 1 never exited"
+        assert all(n > 0 for n in serving.router_status()["routed"])
+        for u, p in prompts.items():
+            raw = _raw_stream(c, u)
+            assert raw[-1] == ("done",), (u, raw)
+            assert [e[1] for e in raw[:-1]] == list(range(16)), (u, raw)
+            ref = np.asarray(generate(model, variables,
+                                      jnp.asarray(p[None]), 16))[0]
+            np.testing.assert_array_equal(
+                np.asarray([e[2] for e in raw[:-1]], np.int32), ref)
+        c.close()
+    finally:
+        serving.stop()
+
+
+def test_no_stale_token_behind_a_restart_marker():
+    """An injected pump crash mid-generation: the dead pump's tokens
+    are in the broker BEFORE the supervisor writes the redispatch's
+    ``restart`` marker, so what follows the marker is the new attempt
+    alone — indices 0..n-1 in order, then ``done``."""
+    model, variables, serving = _plain_stack(
+        max_new=12, slots=1, n_replicas=2, retry_budget=3,
+        fault_injection=[{"kind": "crash_pump", "replica": 1,
+                          "at_tick": 5}])
+    try:
+        inq = InputQueue(port=serving.port)
+        rng = np.random.default_rng(47)
+        prompts = {f"z{i}": rng.integers(1, 32, 3 + i % 4).astype(np.int32)
+                   for i in range(6)}
+        for u, p in prompts.items():
+            inq.enqueue(u, tokens=p, stream=np.int32(1))
+        c = RespClient("127.0.0.1", serving.port)
+        _wait_results(c, prompts)
+        time.sleep(0.3)
+        status = serving.router_status()
+        assert status["death_reasons"] == [None, "pump_exception"]
+        restarted = 0
+        for u, p in prompts.items():
+            raw = _raw_stream(c, u)
+            assert raw[-1] == ("done",), (u, raw)
+            marks = [j for j, e in enumerate(raw) if e[0] == "restart"]
+            restarted += bool(marks)
+            before = raw[:marks[-1]] if marks else []
+            after = raw[marks[-1] + 1:-1] if marks else raw[:-1]
+            # the dead attempt: a prefix of the indices, in order
+            dead = [e[1] for e in before if e[0] == "tok"]
+            assert dead == list(range(len(dead))), (u, raw)
+            assert [e[1] for e in after] == list(range(12)), (u, raw)
+            ref = np.asarray(generate(model, variables,
+                                      jnp.asarray(p[None]), 12))[0]
+            np.testing.assert_array_equal(
+                np.asarray([e[2] for e in after], np.int32), ref)
+        assert restarted >= 1 and status["redispatched"] >= restarted
+        c.close()
+    finally:
+        serving.stop()

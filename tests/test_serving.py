@@ -67,6 +67,68 @@ class TestRespBroker:
         finally:
             srv.stop()
 
+    def test_split_pipeline_returns_what_pipeline_returned(self):
+        """send() + collect() are the two halves of pipeline(): the
+        same commands give the same replies, on the same connection, and
+        the connection is in step afterwards."""
+        srv = RespServer(port=0).start()
+        try:
+            c = RespClient("127.0.0.1", srv.port)
+
+            def cmds(k):
+                return [("HSET", k, "f", "v"), ("HGETALL", k),
+                        ("SADD", k + "s", "a", "b"), ("PING",),
+                        ("XLEN", k + "x")]
+            whole = c.pipeline(cmds("p"))
+            assert c.send(cmds("q")) is None
+            assert c.collect() == whole == [1, [b"f", b"v"], 2, "PONG", 0]
+            assert c.collect() == []            # nothing outstanding
+            assert c.execute("PING") == "PONG"
+            # the replies wait in the socket for as long as they must
+            c.send([("XADD", "t", "*", "i", str(i)) for i in range(64)])
+            time.sleep(0.2)
+            assert len(c.collect()) == 64
+            assert int(c.execute("XLEN", "t")) == 64
+        finally:
+            srv.stop()
+
+    def test_split_pipeline_error_in_the_middle_is_raised_at_collect(self):
+        """An error reply does not surface at send(); collect() reads
+        EVERY reply and then raises the first error, so the next command
+        reads its own reply."""
+        from analytics_zoo_tpu.serving.resp import RedisError
+        srv = RespServer(port=0).start()
+        try:
+            c = RespClient("127.0.0.1", srv.port)
+            c.send([("HSET", "h", "f", "v"), ("NOSUCH", "x"),
+                    ("XGROUP", "DESTROY", "s", "g"), ("HGETALL", "h")])
+            with pytest.raises(RedisError, match="unknown command NOSUCH"):
+                c.collect()
+            assert c.collect() == []
+            assert c.execute("HGETALL", "h") == [b"f", b"v"]
+        finally:
+            srv.stop()
+
+    @pytest.mark.parametrize("second", ["send", "pipeline", "execute"])
+    def test_one_pipeline_outstanding_on_a_connection(self, second):
+        """Replies come back in the order the commands went, so nothing
+        may be written behind a pipeline whose replies are unread: a
+        second send (or any command) before collect() is refused, and
+        the refusal leaves the outstanding pipeline collectable."""
+        srv = RespServer(port=0).start()
+        try:
+            c = RespClient("127.0.0.1", srv.port)
+            c.send([("HSET", "h", "f", "v"), ("PING",)])
+            with pytest.raises(RuntimeError, match="outstanding"):
+                if second == "execute":
+                    c.execute("PING")
+                else:
+                    getattr(c, second)([("PING",)])
+            assert c.collect() == [1, "PONG"]
+            assert c.pipeline([("PING",)]) == ["PONG"]
+        finally:
+            srv.stop()
+
     def test_xrange_id_bounds(self):
         """XRANGE honours real Redis range semantics — the supervisor's
         redispatch re-reads a dead replica's entries by EXACT id, so a
