@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: test verify examples bench chip-smoke native serve-smoke \
+.PHONY: test verify examples chip-smoke native serve-smoke \
 	chaos-smoke overload-smoke sim-gate lint clean
 
 # full suite on the 8-virtual-device CPU mesh (tests/conftest.py forces it)
@@ -42,12 +42,6 @@ lint:
 	$(PY) -m analytics_zoo_tpu.lint analytics_zoo_tpu/ \
 	    --baseline tpulint_baseline.json
 
-# one-chip benchmark suite (prints the driver-facing JSON line).  Needs
-# the TPU: with no chip, or when any model's child fails, it exits
-# non-zero — nothing is skipped and nothing falls back to the CPU.
-bench:
-	$(PY) bench.py
-
 # the quickest proof that serving and training still start on the chip:
 # Qwen2.5-1.5B-width serving through ClusterServing + HttpFrontend
 # (default, then paged+chunked+fused in bf16 and int8), every Pallas
@@ -58,15 +52,15 @@ bench:
 chip-smoke:
 	$(PY) chip_smoke.py
 
-# serving smoke: the paged KV-cache + chunked-prefill + composed-mode
-# (speculative over blocks/chunks) + telemetry + QoS front-door test
-# files + a 20-request e2e wire-protocol bench leg (which drives the
-# chunked scheduler end to end, runs a SPECULATIVE paged+chunked stack
-# and scrapes /metrics + /healthz and schema-checks the dumped trace
-# live, then the front-door leg: SSE streaming e2e, a mid-stream
-# client disconnect with both KV pools reclaimed, and a 429 +
-# Retry-After off a saturated admission queue), all forced onto host
-# CPU (fast; fits the tier-1 timeout)
+# serving smoke, all on the host CPU: the paged KV-cache, chunked-prefill,
+# telemetry, QoS front-door and router test files; the composed-mode
+# (speculative over blocks and chunks), flight-recorder and fused-kernel
+# files without the marker filter (they keep their live-stack cases in
+# the slow lane); then the end-to-end wire-protocol tests of a live fleet
+# (tests/test_serve_smoke.py: paged + chunked shared-prefix run, the
+# scrape of a speculative stack, anomaly bundle, replicas,
+# disaggregation, host tier, fused kernel under tp=2, chaos, overload).
+# Counts and correctness only: nothing here reads a time.
 serve-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_paged_cache.py \
 	    tests/test_chunked_prefill.py tests/test_telemetry.py \
@@ -82,27 +76,25 @@ serve-smoke:
 	# (slow-marked classes in test_sim.py run unfiltered here, like
 	# test_flight.py above; docs/simulation.md)
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_sim.py -q
-	JAX_PLATFORMS=cpu $(PY) bench_serving.py --smoke
+	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_serve_smoke.py -q
 
-# crash-tolerance chaos leg, standalone (also runs inside serve-smoke's
-# bench_serving --smoke chain): a live 3-replica prefill/decode fleet
-# under deterministic fault injection — one decode pump crashes and one
-# KV handoff is dropped; every request must reach a terminal result
-# with at-least-once `attempts` recorded, and /metrics must show the
-# death, the redispatch, and the handoff ack-timeout recovery
+# crash-tolerance chaos test, standalone (serve-smoke runs it too): a
+# live prefill + two-decode fleet under a fixed fault schedule — one
+# decode pump crashes and one KV handoff is dropped; every request must
+# reach a terminal result with its `attempts` recorded, and /metrics must
+# show the death, the redispatch and the handoff's ack-timeout retry
 # (docs/debugging.md "Crash recovery runbook").
 chaos-smoke:
-	JAX_PLATFORMS=cpu $(PY) bench_serving.py --chaos-smoke
+	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_serve_smoke.py -q -k chaos
 
-# graceful-degradation overload leg, standalone (also runs inside
-# serve-smoke's bench_serving --smoke chain): a live 2-replica fleet
-# under a saturating mixed-class burst with a tiny brownout ladder —
-# the ladder must ascend AND fully unwind on /metrics, expired-deadline
-# requests must shed at admission (before prefill) as terminal
-# deadline_exceeded errors, and every interactive request must finish
+# graceful-degradation overload test, standalone (serve-smoke runs it
+# too): a live 2-replica fleet under a saturating mixed-class burst with
+# a tiny brownout ladder — the ladder must ascend AND fully unwind on
+# /metrics, requests past their deadline must be shed at admission as
+# terminal deadline_exceeded errors, and every other request must finish
 # (docs/serving_qos.md "Overload & brownout").
 overload-smoke:
-	JAX_PLATFORMS=cpu $(PY) bench_serving.py --overload-smoke
+	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_serve_smoke.py -q -k overload
 
 # CI gate for scheduler regressions: run the pinned golden scenario
 # (tests/golden/sim_golden.json) through the offline discrete-event

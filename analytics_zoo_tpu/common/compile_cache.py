@@ -1,8 +1,8 @@
 """Persistent XLA compilation cache — one rule, one home.
 
 Every entry point (``init_context``, ``ClusterServing.start``,
-``python -m analytics_zoo_tpu.serving``, ``chip_smoke.py``, the bench
-children) calls :func:`enable_compile_cache` before its first compile:
+``python -m analytics_zoo_tpu.serving``, ``chip_smoke.py``) calls
+:func:`enable_compile_cache` before its first compile:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX keeps its cache there by
   itself and this code sets nothing.

@@ -351,7 +351,6 @@ class InferenceModel:
                                prefix_directory=None,
                                replica_id: int = 0,
                                fault_injector=None,
-                               record_timings: bool = False,
                                telemetry=None, qos=None,
                                flight=None, flight_capacity: int = 2048):
         """Build a ``serving.continuous.ContinuousEngine`` from a model
@@ -450,7 +449,7 @@ class InferenceModel:
             kv_host_store_bytes=kv_host_store_bytes,
             prefix_directory=prefix_directory, replica_id=replica_id,
             fault_injector=fault_injector,
-            record_timings=record_timings, telemetry=telemetry,
+            telemetry=telemetry,
             qos=qos, flight=flight, flight_capacity=flight_capacity,
             **spec)
 

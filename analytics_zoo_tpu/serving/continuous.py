@@ -266,7 +266,6 @@ class ContinuousEngine:
                  fault_injector=None,
                  chunked: bool = False,
                  tick_token_budget: Optional[int] = None,
-                 record_timings: bool = False,
                  telemetry: Optional[Telemetry] = None,
                  qos: Optional[QosPolicy] = None,
                  flight: Optional[FlightRecorder] = None,
@@ -584,7 +583,7 @@ class ContinuousEngine:
             M = -(-L // bs)         # logical blocks per row, ceil(L/bs)
             # int8 rows cost D + 2 bytes (1/elt + a bf16 scale) vs
             # 2D for bf16 — block_bytes() is the shared ledger the
-            # budget split, capacity report, and bench all bill at
+            # budget split and the capacity report both bill at
             if self._kv_int8:
                 per_block = block_bytes(model.num_layers, bs, H, D,
                                         "int8")
@@ -800,7 +799,6 @@ class ContinuousEngine:
         # incremental chunks packed alongside decodes under a per-tick
         # token budget — long prompts stop stalling active decoders.
         self.chunked = bool(chunked)
-        self.record_timings = bool(record_timings)
         self._prefill_stall_ticks = 0
         self._prefill_preemptions = 0
         self._budget_tokens_used = 0
@@ -3273,7 +3271,7 @@ class ContinuousEngine:
         return self.resize_pool(n + delta)
 
     def cache_metrics(self) -> dict:
-        """Serving-visible cache counters (bench_serving.py columns).
+        """Serving-visible cache counters.
 
         The snapshot is taken under the ENGINE lock (and, for the pool
         merge, the pool lock), so a caller on another thread can never
@@ -3331,8 +3329,7 @@ class ContinuousEngine:
                     "spec_rounds": getattr(self, "_spec_rounds", 0),
                     "spec_emitted": getattr(self, "_spec_emitted", 0),
                     # cumulative draft proposals / acceptances (same
-                    # counters /metrics exports); the ratio is the
-                    # acceptance rate the bench records
+                    # counters /metrics exports)
                     "spec_proposed": self.telemetry.c_spec_proposed.value,
                     "spec_accepted": self.telemetry.c_spec_accepted.value,
                 })
@@ -3361,29 +3358,6 @@ class ContinuousEngine:
                                    else 0),
             })
         return out
-
-    @property
-    def record_timings(self) -> bool:
-        """Back-compat shim: raw per-request stamp retention now lives
-        in the telemetry facade (the percentile histograms are always
-        on regardless — this flag only controls the unbounded per-uri
-        store ``pop_request_timings`` drains)."""
-        return self.telemetry.keep_request_stamps
-
-    @record_timings.setter
-    def record_timings(self, v: bool) -> None:
-        self.telemetry.keep_request_stamps = bool(v)
-
-    def pop_request_timings(self) -> Dict[str, dict]:
-        """Drain per-request wall-clock stamps collected under
-        ``record_timings=True``: uri -> {"arrival": t, "token_times":
-        [t0, t1, ...]} (``time.monotonic()`` seconds).  TTFT =
-        token_times[0] - arrival; TPOT = consecutive token_times
-        deltas.  Clears the store — the bench pops once per run.
-        The stamps are written by the SAME telemetry hooks that feed
-        the always-on histograms, so the two surfaces agree by
-        construction."""
-        return self.telemetry.pop_request_stamps()
 
     def _install_slot(self, slot, uri, plen, mn, on_done, on_error,
                       temp, seed, first, top_p=0.0, req=None,

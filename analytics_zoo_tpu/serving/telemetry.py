@@ -1,9 +1,8 @@
 """Engine-wide telemetry: metrics registry, span event log, exports.
 
 The serving stack grew three ad-hoc metric paths — the engine's
-``cache_metrics()`` dict, the opt-in ``record_timings``/
-``pop_request_timings`` stamp store, and the HTTP frontend's private
-``_Percentiles`` window.  None of them can answer the operational
+``cache_metrics()`` dict, an opt-in per-request stamp store, and the
+HTTP frontend's private ``_Percentiles`` window.  None of them can answer the operational
 questions the ROADMAP's scale-out items need (route on pool pressure,
 shed on queue depth, alert on TTFT p99) from OUTSIDE the process.
 This module is the one substrate behind all three, plus the export
@@ -23,8 +22,8 @@ surfaces:
   (enqueued → admitted → first token → finished/preempted/errored)
   feed TTFT / inter-token-gap / queue-wait histograms and lifecycle
   spans from ONE ``time.monotonic()`` stamp per event, so the rolling
-  metrics, the Perfetto timeline and the legacy per-request stamp
-  store can never disagree.
+  metrics, the Perfetto timeline and the per-request stamp store can
+  never disagree.
 
 Design constraints (enforced by tier-1):
 
@@ -512,9 +511,9 @@ class Telemetry:
     that owns it): a :class:`MetricsRegistry`, an :class:`EventLog`,
     and the request-lifecycle helpers the engine's state transitions
     call.  Always on — the opt-in part is only ``keep_request_stamps``
-    (the legacy per-request raw stamp store behind the engine's
-    ``record_timings``/``pop_request_timings`` shim), because per-uri
-    retention is unbounded where the histograms are not.
+    (the per-request raw stamp store that ``pop_request_stamps``
+    drains), because per-uri retention is unbounded where the
+    histograms are not.
 
     Metric-name convention: callers prefix by layer — ``zoo_engine_*``
     (ContinuousEngine), ``zoo_serving_*`` (ClusterServing),
@@ -741,7 +740,7 @@ class Telemetry:
                       prefilling: bool = False) -> None:
         """Partial tokens are discarded and the request requeues: the
         clock keeps its ORIGINAL arrival (TTFT spans the preemption,
-        like the legacy stamp store) but forgets its token history, so
+        like the stamp store) but forgets its token history, so
         readmission re-records a first token."""
         now = time.monotonic()
         with self._lock:
@@ -959,26 +958,21 @@ class Telemetry:
         self.events.instant("pool_" + kind, None, EventLog.TID_ENGINE,
                             info or None)
 
-    # -- legacy stamp store (record_timings shim) --------------------
+    # -- per-request stamp store ---------------------------------------
 
     def pop_request_stamps(self) -> Dict[str, dict]:
-        """Drain the raw per-request stamp store (the engine's
-        ``pop_request_timings`` back-compat surface): uri ->
-        {"arrival": t, "token_times": [...]}."""
+        """Drain the raw per-request stamps kept while
+        ``keep_request_stamps`` is set: uri -> {"arrival": t,
+        "token_times": [t0, t1, ...]} (``time.monotonic()`` seconds).
+        TTFT = token_times[0] - arrival; TPOT = consecutive
+        token_times deltas.  The same hooks write the always-on
+        histograms, so the two surfaces agree by construction."""
         with self._lock:
             out = self._stamps
             self._stamps = {}
         return out
 
-    # -- maintenance ---------------------------------------------------
-
-    def reset_windows(self) -> None:
-        """Clear every histogram's sliding window (cumulative counts
-        stand) — benchmarks call this after warmup so compile time
-        never pollutes a percentile."""
-        for _, metric in self.metrics.items():
-            if isinstance(metric, WindowHistogram):
-                metric.reset_window()
+    # -- export --------------------------------------------------------
 
     def dump_trace(self, path: Optional[str] = None,
                    process_name: str = "serving-engine") -> dict:

@@ -100,7 +100,7 @@ class CompileMeter:
 
 
 # ---------------------------------------------------------------------------
-# models (exactly the knobs the importers / bench set)
+# models (exactly the knobs the importers set)
 # ---------------------------------------------------------------------------
 
 def qwen_lm(tiny: bool):
@@ -573,7 +573,7 @@ def _fit_checked(name, est, data, batch, tiny, platform, epochs=2):
 
 
 def _bert_estimator(tiny: bool, mesh=None):
-    """BERT-base exactly as bench.py:bench_bert builds it."""
+    """BERT-base (the model's own defaults), or a toy for the dry run."""
     import optax
 
     from analytics_zoo_tpu.learn import Estimator
@@ -640,7 +640,7 @@ def leg_train(tiny: bool, platform: str) -> dict:
         del est
         gc.collect()
 
-        # the 111M LM at seq 2048, as bench.py:bench_lm builds it — the
+        # the 111M LM at seq 2048 — the
         # flash kernel's forward and backward inside the pjit train step
         B, T, V = (8, 64, 512) if tiny else (8, 2048, 32000)
         rng = np.random.default_rng(0)

@@ -1,10 +1,10 @@
 """PR 21 bring-up contracts: nothing on the chip paths hides the device.
 
 Each test is a few seconds and compiles nothing large: the compile-cache
-rule, loud failure without a TPU (bench.py, bench_serving.py,
-chip_smoke.py), no silent kernel interpret / flash fallback, engine
-memory reads of the engine's own devices, replica r on device r, weights
-as program operands, and the content-keyed native build."""
+rule, loud failure without a TPU (chip_smoke.py), no silent kernel
+interpret / flash fallback, engine memory reads of the engine's own
+devices, replica r on device r, weights as program operands, and the
+content-keyed native build."""
 
 import json
 import os
@@ -87,33 +87,6 @@ def test_exactly_one_cache_dir_update_in_the_tree():
 
 
 # ---- loud failure without the chip ---------------------------------------
-
-def test_peak_for_raises_on_unknown_device_kind():
-    import bench
-
-    v5e = bench._peak_for(types.SimpleNamespace(device_kind="TPU v5 lite"))
-    assert v5e["flops_per_s"] == 197e12 and v5e["bytes_per_s"] == 819e9
-    with pytest.raises(ValueError, match="device_kind"):
-        bench._peak_for(types.SimpleNamespace(device_kind="TPU v9 mega"))
-    with pytest.raises(ValueError, match="device_kind"):
-        bench._peak_for(types.SimpleNamespace(device_kind="cpu"))
-
-
-def test_bench_without_tpu_exits_nonzero_and_skips_nothing():
-    p = _run(["bench.py"], JAX_PLATFORMS="cpu")
-    assert p.returncode != 0
-    assert "skipped" not in p.stdout and "skipped" not in p.stderr
-    assert "bert" in p.stderr and "not 'tpu'" in p.stderr
-    assert not any(l.startswith("{") for l in p.stdout.splitlines())
-
-
-def test_bench_serving_scenario_without_tpu_exits_nonzero():
-    p = _run(["bench_serving.py", "--one", "mlp", "1", "2", "8"],
-             JAX_PLATFORMS="cpu")
-    assert p.returncode != 0
-    assert "not 'tpu'" in p.stderr
-    assert not any(l.startswith("{") for l in p.stdout.splitlines())
-
 
 def test_chip_smoke_without_tpu_prints_no_result():
     p = _run(["chip_smoke.py"], JAX_PLATFORMS="cpu")

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from analytics_zoo_tpu.models.lm import TransformerLM
 from analytics_zoo_tpu.serving.continuous import ContinuousEngine
+from analytics_zoo_tpu.serving.telemetry import Telemetry
 
 
 @pytest.fixture(scope="module")
@@ -274,15 +275,17 @@ def test_precompile_requires_chunked(lm):
 def test_request_timings_recorded(lm):
     rng = np.random.default_rng(21)
     prompts = [rng.integers(1, 32, 12).astype(np.int32)]
+    telemetry = Telemetry()
+    telemetry.keep_request_stamps = True
     _, eng = _run(lm, prompts, engine_kw=dict(
-        chunked=True, tick_token_budget=8, record_timings=True))
-    t = eng.pop_request_timings()
+        chunked=True, tick_token_budget=8, telemetry=telemetry))
+    t = eng.telemetry.pop_request_stamps()
     assert set(t) == {"r0"}
     stamps = t["r0"]["token_times"]
     assert len(stamps) == 6                   # max_new_tokens
     assert stamps[0] >= t["r0"]["arrival"]
     assert stamps == sorted(stamps)
-    assert eng.pop_request_timings() == {}    # pop clears
+    assert eng.telemetry.pop_request_stamps() == {}    # pop clears
 
 
 def test_config_knobs(tmp_path):

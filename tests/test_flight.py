@@ -360,7 +360,7 @@ class TestBundleAndCli:
         FILE runs on a bare python (no jax, no numpy — ``-S`` keeps
         site-packages out and a stray dependency import would fail).
         The ``-m`` spelling additionally needs the package importable;
-        the serve-smoke anomaly leg covers that path."""
+        ``test_serve_smoke.py``'s starved-pool test covers that path."""
         from analytics_zoo_tpu.serving import debug
 
         path = self._bundle(tmp_path)

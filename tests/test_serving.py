@@ -372,6 +372,13 @@ class TestHttpFrontend:
         assert status == 200
         assert m["latency"]["count"] >= 1
         assert m["latency"]["p50_ms"] > 0
+        # the job books a batch after its results are visible and its
+        # entries acknowledged (server._publish_batch), so the reply can
+        # overtake the counter: wait for it
+        deadline = time.monotonic() + 10
+        while m["serving"]["requests"] < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
+            m = self._get(fe.port, "/metrics?format=json")[1]
         assert m["serving"]["requests"] >= 2
         assert "backlog" in m
 

@@ -273,23 +273,23 @@ class TestEngineTelemetry:
             assert eng.step() == 0
         assert eng.telemetry.c_ticks.value == before
 
-    def test_record_timings_shim(self, lm):
+    def test_request_stamps_kept_on_request(self, lm):
         from analytics_zoo_tpu.serving.continuous import ContinuousEngine
 
         model, variables = lm
         eng = ContinuousEngine(model, variables, max_new_tokens=4,
                                max_slots=2, prompt_buckets=(8,))
-        eng.record_timings = True
-        assert eng.record_timings is True
+        assert eng.telemetry.keep_request_stamps is False
+        eng.telemetry.keep_request_stamps = True
         done = {}
         eng.submit("r0", np.arange(1, 7, dtype=np.int32),
                    on_done=lambda u, t: done.__setitem__(u, t))
         eng.drain()
-        stamps = eng.pop_request_timings()
+        stamps = eng.telemetry.pop_request_stamps()
         assert set(stamps) == {"r0"}
         assert len(stamps["r0"]["token_times"]) == 4
         assert stamps["r0"]["arrival"] <= stamps["r0"]["token_times"][0]
-        assert eng.pop_request_timings() == {}      # pop clears
+        assert eng.telemetry.pop_request_stamps() == {}  # pop clears
 
     def test_engine_prometheus_surface(self, lm):
         from analytics_zoo_tpu.serving.continuous import ContinuousEngine
