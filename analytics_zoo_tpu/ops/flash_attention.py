@@ -584,94 +584,224 @@ def paged_kv_update(pool_k, pool_v, tables, pos, new_k, new_v,
 # ---------------------------------------------------------------------------
 # fused paged-attention kernel
 #
-# Grid (B, KH, M): one program per (batch row, kv head, logical block),
-# the M dimension ``arbitrary`` so online-softmax state carries across
-# it in VMEM scratch while Mosaic pipelines the next block's DMA against
-# compute.  The block-table indirection lives in the K/V BlockSpec
-# index_maps — ``tables``/``pos`` ride as scalar-prefetch operands, so
-# each grid step DMAs exactly ONE [bs, D] tile per tensor straight from
-# the pool in HBM: the [B, M*bs, KH, D] gather is never materialised.
+# Grid (B, KH // hb): one grid step per (batch row, group of ``hb`` KV
+# heads), and the walk over the row's blocks is a loop INSIDE the step
+# that ends at the row's frontier: ``nb = (pos[b] + S - 1) // bs + 1``
+# blocks (at most the table's M), whatever the table's width.  The pools
+# stay in HBM (``pl.ANY``); block j of row b is fetched by one async copy
+# per tensor, ``pool[tables[b, j], h*hb:(h+1)*hb]`` = ``[hb, bs, D]``
+# (the pool is ``[N, KH, bs, D]``, so all heads of a block are ONE
+# contiguous read), into one of two VMEM slots while the other slot is
+# computed on; the last block's iteration fetches the NEXT grid step's
+# first block instead, so a row's first copy is hidden behind the row
+# before it (the slot the next step starts in rides in SMEM scratch;
+# both grid dimensions are ``arbitrary``: the steps run in order on the
+# one core a v5e has).  ``tables``/``pos`` are scalar-prefetch operands.
+# The [B, M*bs, KH, D] gather is never materialised, a table column past
+# a row's frontier costs nothing, and a slot that holds nothing
+# (``pos`` 0, table all sink: the engine re-pins it each pass) costs one
+# block of the sink.
+#
+# What a step carries (measured on a v5e at B = 32, M = 10, bs = 256,
+# D = 128; PERF.md, PR 33): a grid step's fixed cost is ~0.35 us and a
+# [256, 128] bf16 tile takes 0.08 us at 819 GB/s, so one KV head and one
+# table column a step — grid (B, KH, M), 2560 steps a layer on Mistral —
+# was bound by the steps, not by HBM.  With all 8 heads a copy moves
+# 1 MiB (K and V) in ~1.3 us and the kernel runs at 600-720 GB/s.
+#
+# Heads per step (``_paged_heads_per_step``): the largest divisor of KH
+# whose tiles fit a VMEM budget of 8 MiB, half of the 16 MiB a kernel
+# may scope on a v5e.  Per head: the query tile and the f32 output tile
+# (both double-buffered by the pipeline), the f32 accumulator, the m/l
+# columns (lane-padded to 128), two slots each of a K and a V block, and
+# three [SGp, bs] f32 temporaries (logits, probabilities, their cast).
+# At S = 1 (SGp = 8) that is ~0.3 MiB a head: every head of every
+# configuration in one step.  At a chunk's S = 256 it is 6.3 MiB
+# (Mistral, SGp 1024) or 9.3 MiB (Qwen, SGp 1536) a head: one head a
+# step, as before — there the matmul of a step (0.7-1 us) hides the
+# step's cost and nothing is to gain.
 #
 # Queries are regrouped head-major ([B, KH, S*G, D], row r = s*G + g,
-# padded to 8 sublanes): each program owns ALL G query heads of its KV
-# head, which is what makes grouped-query attention free here.  VMEM
-# per program: q/acc [SGp, D] + m/l columns + one [bs, D] K/V tile each.
+# padded to 8 sublanes): each head of a step owns ALL G query heads of
+# its KV head, which is what makes grouped-query attention free here;
+# the heads of a step are the batch axis of one ``dot_general``.
 # Masking matches the gather fallback exactly — query s attends logical
-# positions <= pos[b] + s; blocks past the frontier skip compute via
-# pl.when (their table entries point at the sink, so the DMA is
-# harmless), in-block tails mask element-wise to NEG_INF.
+# positions <= pos[b] + s; in-block tails mask element-wise to NEG_INF.
 #
-# int8 pools add two [bs]-lane scale operands: k-scales multiply the
-# logits columns post-matmul, v-scales fold into p pre-matmul — both
-# in-register, algebraically identical to dequantizing the tiles.
+# int8 pools add the two scale operands, a row's [M, hb, 1, bs] a step:
+# k-scales multiply the logits columns post-matmul, v-scales fold into p
+# pre-matmul — both in-register, algebraically identical to dequantizing
+# the tiles.
 # ---------------------------------------------------------------------------
 
-def _paged_fused_kernel(tables_ref, pos_ref, *refs, scale, bs, G, S,
+_PAGED_VMEM_BUDGET = 8 * 2 ** 20
+
+
+def _paged_heads_per_step(SGp, KH, bs, D, q_itemsize, kv_itemsize):
+    """KV heads one grid step of the fused kernel carries: the largest
+    divisor of ``KH`` whose VMEM reckoning (the comment above) fits
+    ``_PAGED_VMEM_BUDGET``, and never less than one."""
+    per_head = (2 * SGp * D * q_itemsize        # query tile, two buffers
+                + 2 * SGp * D * 4               # f32 output tile, two
+                + SGp * D * 4                   # accumulator
+                + 2 * SGp * 128 * 4             # m, l (lane-padded)
+                + 4 * bs * D * kv_itemsize      # K, V: two slots each
+                + 3 * SGp * bs * 4)             # logits, p, p's cast
+    fit = max(1, _PAGED_VMEM_BUDGET // per_head)
+    return max(h for h in range(1, KH + 1) if KH % h == 0 and h <= fit)
+
+
+def _paged_block_update(q, k, v, sk, sv, j, pos, acc_ref, m_ref, l_ref, *,
+                        scale, bs, G):
+    """Fold logical block ``j`` of a row into the online-softmax state of
+    the step's heads: q ``[hb, SGp, D]``, k/v ``[hb, bs, D]``, the int8
+    scales sk/sv ``[hb, 1, bs]`` (None for a plain pool)."""
+    if sk is not None:
+        # one operand dtype into the MXU; |k| <= 127 is exact in
+        # bf16 and f32 alike, so the cast changes no product
+        k = k.astype(q.dtype)
+    s = scale * jax.lax.dot_general(                   # [hb, SGp, bs] f32
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    if sk is not None:
+        s = s * sk.astype(jnp.float32)                 # [hb, 1, bs] bcast
+    lpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    qrow = jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 1) // G                    # row r -> s=r//G
+    s = jnp.where(lpos <= pos + qrow, s, NEG_INF)
+    m_prev, l_prev = m_ref[:], l_ref[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    # fully-masked row: subtract 0 instead of NEG_INF so
+    # exp(NEG_INF) underflows to 0 (same trick as _fwd_kernel)
+    m_sub = jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0)
+    p = jnp.exp(s - m_sub)
+    alpha = jnp.exp(m_prev - m_new)
+    m_ref[:] = m_new
+    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    if sv is not None:
+        # fold the v scales into p's columns: (p * sv) @ v_int8
+        # == p @ (v_int8 * sv[:, None]) without a [bs, D] dequant
+        p = p * sv.astype(jnp.float32)
+        v = v.astype(jnp.float32)
+    else:
+        p = p.astype(v.dtype)
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p, v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+
+def _paged_state_init(acc_ref, m_ref, l_ref):
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _paged_state_out(o_ref, acc_ref, l_ref):
+    l = l_ref[:]
+    l_safe = jnp.where(l > 0, l, 1.0)
+    o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+
+
+def _paged_fused_kernel(tables_ref, pos_ref, *refs, scale, bs, G, S, hb,
                         quant):
+    if quant:
+        (q_ref, k_hbm, v_hbm, sk_ref, sv_ref, o_ref,
+         kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref) = refs
+    else:
+        (q_ref, k_hbm, v_hbm, o_ref,
+         kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref) = refs
+    b, h = pl.program_id(0), pl.program_id(1)
+    nB, nH = pl.num_programs(0), pl.num_programs(1)
+    M = tables_ref.shape[1]
+
+    def copies(row, hg, j, slot):
+        blk = tables_ref[row, j]
+        heads = pl.ds(hg * hb, hb)
+        return (pltpu.make_async_copy(k_hbm.at[blk, heads], kbuf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[blk, heads], vbuf.at[slot],
+                                      sem.at[1, slot]))
+
+    @pl.when((b == 0) & (h == 0))
+    def _first():
+        slot_ref[0] = 0
+        for c in copies(b, h, 0, 0):
+            c.start()
+
+    pos = pos_ref[b]
+    # block j holds logical positions [j*bs, (j+1)*bs); the furthest
+    # position any query row attends is pos + S - 1
+    nb = jnp.minimum((pos + (S - 1)) // bs, M - 1) + 1
+    slot0 = slot_ref[0]     # where the step before put this step's block 0
+    _paged_state_init(acc_ref, m_ref, l_ref)
+
+    def block(j, carry):
+        slot = (slot0 + j) % 2
+
+        @pl.when(j + 1 < nb)
+        def _next_block():
+            for c in copies(b, h, j + 1, 1 - slot):
+                c.start()
+
+        @pl.when((j + 1 == nb) & ((b + 1 < nB) | (h + 1 < nH)))
+        def _next_step():
+            wrap = h + 1 == nH
+            for c in copies(jnp.where(wrap, b + 1, b),
+                            jnp.where(wrap, 0, h + 1), 0, 1 - slot):
+                c.start()
+
+        for c in copies(b, h, j, slot):
+            c.wait()
+        _paged_block_update(
+            q_ref[0], kbuf[slot], vbuf[slot],
+            sk_ref[0, j] if quant else None,
+            sv_ref[0, j] if quant else None,
+            j, pos, acc_ref, m_ref, l_ref, scale=scale, bs=bs, G=G)
+        return carry
+
+    jax.lax.fori_loop(0, nb, block, None)
+    slot_ref[0] = (slot0 + nb) % 2
+    _paged_state_out(o_ref, acc_ref, l_ref)
+
+
+def _paged_fused_kernel_narrow(tables_ref, pos_ref, *refs, scale, bs, G, S,
+                               quant):
+    """The walk by BlockSpecs, for heads under a lane tile wide (grid
+    ``(B, KH // hb, M)``, the M dimension ``arbitrary``): a column past
+    the row's frontier re-names the tile the step before it had, so it
+    copies nothing, and skips the update."""
     if quant:
         (q_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref,
          acc_ref, m_ref, l_ref) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b, j = pl.program_id(0), pl.program_id(2)
-    nj = pl.num_programs(2)
+    pos = pos_ref[b]
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _paged_state_init(acc_ref, m_ref, l_ref)
 
-    # block j holds logical positions [j*bs, (j+1)*bs); the furthest
-    # position any query row attends is pos[b] + S - 1
-    @pl.when(j * bs <= pos_ref[b] + (S - 1))
+    @pl.when(j * bs <= pos + (S - 1))
     def _accumulate():
-        q = q_ref[0, 0]                                # [SGp, D]
-        k = k_ref[0, 0]                                # [bs, D]
-        if quant:
-            # one operand dtype into the MXU; |k| <= 127 is exact in
-            # bf16 and f32 alike, so the cast changes no product
-            k = k.astype(q.dtype)
-        s = scale * jax.lax.dot_general(               # [SGp, bs] f32
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if quant:
-            s = s * sk_ref[0, 0].astype(jnp.float32)   # [1, bs] bcast
-        rows = acc_ref.shape[0]
-        lpos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, bs), 1)
-        qrow = jax.lax.broadcasted_iota(
-            jnp.int32, (rows, bs), 0) // G             # row r -> s=r//G
-        s = jnp.where(lpos <= pos_ref[b] + qrow, s, NEG_INF)
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # fully-masked row: subtract 0 instead of NEG_INF so
-        # exp(NEG_INF) underflows to 0 (same trick as _fwd_kernel)
-        m_sub = jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0)
-        p = jnp.exp(s - m_sub)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quant:
-            # fold the v scales into p's columns: (p * sv) @ v_int8
-            # == p @ (v_int8 * sv[:, None]) without a [bs, D] dequant
-            p = p * sv_ref[0, 0].astype(jnp.float32)
-            v = v_ref[0, 0].astype(jnp.float32)
-        else:
-            v = v_ref[0, 0]
-            p = p.astype(v.dtype)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _paged_block_update(
+            q_ref[0], k_ref[0], v_ref[0],
+            sk_ref[0, 0] if quant else None,
+            sv_ref[0, 0] if quant else None,
+            j, pos, acc_ref, m_ref, l_ref, scale=scale, bs=bs, G=G)
 
-    @pl.when(j == nj - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        l = l_ref[:]
-        l_safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+        _paged_state_out(o_ref, acc_ref, l_ref)
 
 
-def _paged_attention_fused(q, pool_k, pool_v, tables, pos, interpret):
+# jitted on its own: a step program calls it once a layer (twice in a
+# fused decode + chunk program) with the same shapes, and a call of a
+# jitted function is traced and lowered to Mosaic ONCE a program, not
+# once a call site; XLA inlines the calls
+@functools.partial(jax.jit, static_argnames=("interpret", "looped"))
+def _paged_attention_fused(q, pool_k, pool_v, tables, pos, interpret,
+                           looped=None):
     B, S, H, D = q.shape
     quant = isinstance(pool_k, QuantKV)
     kd = pool_k.data if quant else pool_k
@@ -684,54 +814,77 @@ def _paged_attention_fused(q, pool_k, pool_v, tables, pos, interpret):
     M = tables.shape[1]
     SG = S * G
     SGp = -(-SG // 8) * 8          # Mosaic sublane multiple
+    hb = _paged_heads_per_step(SGp, KH, bs, D, q.dtype.itemsize,
+                               kd.dtype.itemsize)
+    if looped is None:
+        # Mosaic pads an HBM ref whose rows are under a lane tile wide
+        # and then refuses to slice a block out of it; the interpreter
+        # has no lanes (``looped`` is an argument for the tests alone)
+        looped = interpret or D % 128 == 0
     # [B, S, H, D] -> [B, KH, S*G, D]: row r of kv head h is query
     # (s = r // G, head h*G + r % G), padded rows are mask-dead
     qf = q.reshape(B, S, KH, G, D).transpose(0, 2, 1, 3, 4)
-    qf = _pad_to(qf.reshape(B, KH, SG, D), 8, axis=2)
+    qf = qf.reshape(B, KH, SG, D)
+    if SGp != SG:
+        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, SGp - SG), (0, 0)))
     scale = 1.0 / float(np.sqrt(D))
-    kernel = functools.partial(_paged_fused_kernel, scale=scale,
-                               bs=bs, G=G, S=S, quant=quant)
-    in_specs = [
-        pl.BlockSpec((1, 1, SGp, D), lambda b, h, j, t, p: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, D),
-                     lambda b, h, j, t, p: (t[b, j], h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, D),
-                     lambda b, h, j, t, p: (t[b, j], h, 0, 0)),
-    ]
     operands = [qf, kd, vd]
+    state = [pltpu.VMEM((hb, SGp, D), jnp.float32),
+             pltpu.VMEM((hb, SGp, 1), jnp.float32),
+             pltpu.VMEM((hb, SGp, 1), jnp.float32)]
     if quant:
         # the scales of the blocks the tables name, gathered here
-        # ([B*M, KH, bs]: some KB) and indexed by (b, j), not by
-        # t[b, j]: handing the kernel the whole [N, KH, bs] array made
-        # XLA re-lay ALL of it out, padded to Mosaic's tiles, before
-        # every call (the scatter that writes the scales keeps them
-        # in a layout of its own).  They ride as [.., KH, 1, bs]: a
-        # (1, 1, bs) block of the 3-D array would put a 1
-        # against KH in the second-minor (sublane) slot, which Mosaic
-        # refuses unless KH == 1; with the unit axis the tile's last two
-        # dims (1, bs) equal the array's and any KH is legal
-        sspec = pl.BlockSpec((1, 1, 1, bs),
-                             lambda b, h, j, t, p: (b * M + j, h, 0, 0))
-        in_specs += [sspec, sspec]
-        rows = tables.reshape(-1)
-        operands += [jnp.take(pool_k.scale, rows, axis=0)[:, :, None, :],
-                     jnp.take(pool_v.scale, rows, axis=0)[:, :, None, :]]
+        # ([B, M, KH, bs]: some KB): handing the kernel the whole
+        # [N, KH, bs] array made XLA re-lay ALL of it out, padded to
+        # Mosaic's tiles, before every call (the scatter that writes the
+        # scales keeps them in a layout of its own).  They ride as
+        # [.., KH, 1, bs]: with the unit axis the tile's last two dims
+        # (1, bs) equal the array's, any ``hb`` is legal, and block j's
+        # scales are a leading-axis index
+        operands += [jnp.take(pool_k.scale, tables, axis=0)[:, :, :, None],
+                     jnp.take(pool_v.scale, tables, axis=0)[:, :, :, None]]
+    if looped:
+        kernel = functools.partial(_paged_fused_kernel, scale=scale, bs=bs,
+                                   G=G, S=S, hb=hb, quant=quant)
+        grid = (B, KH // hb)
+        qspec = pl.BlockSpec((1, hb, SGp, D),
+                             lambda b, h, t, p: (b, h, 0, 0))
+        kvspec = pl.BlockSpec(memory_space=pl.ANY)
+        sspec = pl.BlockSpec((1, M, hb, 1, bs),
+                             lambda b, h, t, p: (b, 0, h, 0, 0))
+        scratch = [pltpu.VMEM((2, hb, bs, D), kd.dtype),
+                   pltpu.VMEM((2, hb, bs, D), vd.dtype),
+                   pltpu.SemaphoreType.DMA((2, 2)),
+                   pltpu.SMEM((1,), jnp.int32)] + state
+    else:
+        kernel = functools.partial(_paged_fused_kernel_narrow, scale=scale,
+                                   bs=bs, G=G, S=S, quant=quant)
+        grid = (B, KH // hb, M)
+        qspec = pl.BlockSpec((1, hb, SGp, D),
+                             lambda b, h, j, t, p: (b, h, 0, 0))
+
+        def live(b, j, p):      # column j, held at the row's frontier
+            return jnp.minimum(j, jnp.minimum((p[b] + (S - 1)) // bs,
+                                              M - 1))
+        kvspec = pl.BlockSpec(
+            (1, hb, bs, D),
+            lambda b, h, j, t, p: (t[b, live(b, j, p)], h, 0, 0))
+        sspec = pl.BlockSpec(
+            (1, 1, hb, 1, bs),
+            lambda b, h, j, t, p: (b, live(b, j, p), h, 0, 0))
+        scratch = state
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, KH, M),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, SGp, D),
-                               lambda b, h, j, t, p: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((SGp, D), jnp.float32),
-            pltpu.VMEM((SGp, 1), jnp.float32),
-            pltpu.VMEM((SGp, 1), jnp.float32),
-        ])
+        num_scalar_prefetch=2, grid=grid,
+        in_specs=[qspec, kvspec, kvspec] + ([sspec, sspec] if quant else []),
+        out_specs=qspec, scratch_shapes=scratch)
+    params = ({"interpret": True} if interpret else
+              {"compiler_params": pltpu.CompilerParams(
+                  dimension_semantics=("arbitrary",) * len(grid))})
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, SGp, D), jnp.float32),
         name="zoo_paged_attn_decode",
-        **_params(interpret, 1),
+        **params,
     )(tables.astype(jnp.int32), pos.astype(jnp.int32), *operands)
     out = out[:, :, :SG, :].reshape(B, KH, S, G, D)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, S, H, D)
@@ -744,8 +897,8 @@ def _paged_attention_fused_tp(q, pool_k, pool_v, tables, pos, mesh,
     A Mosaic kernel is a custom call XLA cannot GSPMD-partition, so the
     tp-sharded pool is read through :func:`shard_map` instead: each chip
     runs :func:`_paged_attention_fused` on its LOCAL pool shard — the
-    kv-heads grid dimension shrinks tp-fold (grid ``(B, KH/tp, M)`` per
-    chip) and the block-table indirection needs no change because
+    kv heads of a step shrink tp-fold (``KH/tp`` heads of a block a
+    copy) and the block-table indirection needs no change because
     tables/pos are replicated host-side state.  Correctness rides the
     head-contiguity of the layout: query head ``h = kh*G + g`` (GQA
     fold), so a contiguous shard of the KV heads owns exactly the
@@ -827,12 +980,18 @@ def paged_attention(q, pool_k, pool_v, tables, pos, *,
     masking/GQA/quantization contract, so greedy decode is
     token-identical across them:
 
-    - ``"fused"`` — the Pallas TPU kernel above: grid ``(B, KH, M)``
-      with the block dimension ``arbitrary``, block tables as
-      scalar-prefetch operands indirecting the K/V BlockSpecs, one
-      ``[bs, D]`` tile DMA'd HBM->VMEM per grid step, online softmax in
-      VMEM scratch (the dense flash kernel's structure), int8 scales
-      applied in-register.  The decode hot path on TPU.
+    - ``"fused"`` — the Pallas TPU kernel above: grid ``(B, KH // hb)``,
+      one step per row and group of ``hb`` KV heads (all of them at
+      decode and verify widths, one at a chunk's: the VMEM rule of
+      ``_paged_heads_per_step``); inside a step a loop over the row's
+      live blocks — up to its frontier ``(pos[b] + S - 1) // bs``,
+      whatever M is — copies ``[hb, bs, D]`` of K and of V HBM->VMEM
+      through the scalar-prefetched block table, double-buffered, the
+      next step's first block fetched under this step's last; online
+      softmax in VMEM scratch (the dense flash kernel's structure),
+      int8 scales applied in-register.  A row whose table is all sink
+      is cheap only if its ``pos`` is small: the engine holds an empty
+      slot's at 0.  The decode hot path on TPU.
     - ``"gather"`` — the ``jnp.take`` fallback: one materialised
       ``[B, M, KH, bs, D]`` gather (int8 pools dequantize the gathered
       rows) then the masked einsum-softmax the dense decode path runs,

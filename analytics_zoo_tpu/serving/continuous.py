@@ -2040,6 +2040,15 @@ class ContinuousEngine:
         to a power of two so a burst costs a handful of compiles, not
         one per burst size); their K/V splice into slots one
         dynamic_update_slice each.  Returns the number admitted."""
+        # a slot that holds nothing rests at position 0: every step
+        # program advances ``pos`` for ALL rows and the host reads it
+        # back whole, so a freed slot's would creep to Lmax - 1 while
+        # its table is all sink, and the fused kernel walks a row's
+        # table up to ``pos``.  Re-pinned here because every tick path
+        # starts and ends in ``_admit``, as ``_reanchor_prefill`` re-pins
+        # a PREFILLING row's.
+        if self._free:
+            self._pos[list(self._free)] = 0
         if self._deadline_seen:
             self._shed_expired_waiting()
         deferred = (self._brownout_defer_extract()
@@ -3641,8 +3650,9 @@ class ContinuousEngine:
         rec["kernel"] = self.kernel if self.paged else "dense"
         rec["kv_dtype"] = self.kv_dtype
         rec["kv_bytes_per_token"] = self._kv_bytes_per_token
-        rec["decode_uris"] = [s.uri for s in self._slots
-                              if s is not None and s.state == "DECODE"]
+        decoding = [i for i, s in enumerate(self._slots)
+                    if s is not None and s.state == "DECODE"]
+        rec["decode_uris"] = [self._slots[i].uri for i in decoding]
         rec["prefill_uris"] = [s.uri for s in self._slots
                                if s is not None and s.state != "DECODE"]
         rec["preempted"] = delta("preempt", self._preemptions)
@@ -3691,6 +3701,13 @@ class ContinuousEngine:
             self._alloc_fail_streak = \
                 self._alloc_fail_streak + 1 if fails else 0
             rec["alloc_fail_streak"] = self._alloc_fail_streak
+        if self.paged:
+            # what the fused kernel's frontier stop has to skip: the
+            # blocks the decode rows' next step reads, of the slots x
+            # table-width blocks a grid over the whole table would visit
+            rec["attn_live_blocks"] = int(
+                (self._pos[decoding] // self._bs + 1).sum())
+            rec["attn_table_blocks"] = self._tables.size
         if self.after_dispatch is not None:
             # a pump drives this engine: the token-stream events it sent
             # in this cycle, and those of them sent under a device call

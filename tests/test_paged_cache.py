@@ -144,6 +144,60 @@ def test_paged_matches_arena_and_solo(lm):
     assert m["mode"] == "paged" and m["referenced_blocks"] == 0
 
 
+def test_freed_slot_rests_at_zero_and_prefill_at_its_frontier(lm):
+    """Host state the fused kernel's frontier stop reads: after EVERY
+    step a slot that holds nothing sits at ``pos`` 0 with a table that
+    is all sink (every step program advances ``pos`` for all rows and
+    the host reads it back whole: unpinned, a freed slot's would creep
+    to the table's end), a PREFILLING row sits frozen at its fill
+    frontier, and the rows still running decode what they decode alone."""
+    model, variables = lm
+    rng = np.random.default_rng(3)
+    prompts = {"short": rng.integers(1, 32, 3).astype(np.int32),
+               "long": rng.integers(1, 32, 5).astype(np.int32),
+               "late": rng.integers(1, 32, 14).astype(np.int32)}
+    budget = {"short": 2, "long": 12, "late": 6}
+    eng = ContinuousEngine(model, variables, max_new_tokens=12,
+                           max_slots=3, prompt_buckets=(8, 16),
+                           paged=True, block_size=4, chunked=True,
+                           tick_token_budget=8, kernel="fused")
+    results = {}
+    seen = {"freed": 0, "prefilling": 0}
+
+    def step_and_check():
+        eng.step()
+        for i, st in enumerate(eng._slots):
+            if st is None:
+                seen["freed"] += 1
+                assert eng._pos[i] == 0
+                assert (eng._tables[i] == SINK_BLOCK).all()
+            elif st.state == "PREFILLING":
+                seen["prefilling"] += 1
+                assert eng._pos[i] == st.fill_pos and eng._done[i]
+
+    for uri in ("short", "long"):
+        eng.submit(uri, prompts[uri], max_new=budget[uri],
+                   on_done=_collect(results))
+    while "short" not in results:
+        step_and_check()
+    for _ in range(3):      # the freed slot idles beside a running row
+        step_and_check()
+    freed_ticks = seen["freed"]
+    eng.submit("late", prompts["late"], max_new=budget["late"],
+               on_done=_collect(results))     # two chunks at budget 8
+    while len(results) < 3:
+        step_and_check()
+    assert freed_ticks >= 3 and seen["prefilling"] >= 1
+    for uri, p in prompts.items():
+        solo = np.asarray(generate(model, variables, jnp.asarray(p[None]),
+                                   budget[uri]))[0]
+        np.testing.assert_array_equal(results[uri][:budget[uri]], solo,
+                                      err_msg=uri)
+    rec = eng.flight.snapshot()[-1]
+    assert rec["attn_table_blocks"] == eng._tables.size
+    assert 0 <= rec["attn_live_blocks"] <= rec["attn_table_blocks"]
+
+
 def test_paged_eos_and_sampling_parity(lm):
     """EOS frozen-tail semantics and seeded sampling both survive the
     paged path: eos output matches generate(eos_id=...), and a sampled

@@ -47,50 +47,118 @@ from analytics_zoo_tpu.serving.paged_cache import (BlockPool,
 # ---------------------------------------------------------------------------
 
 def _pool_case(B=2, S=1, H=4, KH=2, D=16, bs=4, M=5, seed=0,
-               int8=False):
+               int8=False, pos=None, empty=()):
     """A filled pool + valid tables/pos: every row owns M distinct
     physical blocks (ids 1..B*M — block 0 stays the garbage sink),
-    pos is ragged so masking frontiers differ per row."""
+    pos is ragged so masking frontiers differ per row (or is the
+    ``pos`` given).  A row in ``empty`` is a slot that holds nothing,
+    as the engine leaves it: table all sink, pos 0."""
     rng = np.random.default_rng(seed)
     N = B * M + 1
     ks = jax.random.split(jax.random.key(seed), 3)
     pk = jax.random.normal(ks[0], (N, KH, bs, D), jnp.float32)
     pv = jax.random.normal(ks[1], (N, KH, bs, D), jnp.float32)
     q = jax.random.normal(ks[2], (B, S, H, D), jnp.float32)
-    tables = jnp.asarray(
-        1 + np.arange(B * M).reshape(B, M), jnp.int32)
+    tables = 1 + np.arange(B * M).reshape(B, M)
     maxp = M * bs - S
-    pos = jnp.asarray(rng.integers(0, maxp + 1, B), jnp.int32)
+    if pos is None:
+        pos = rng.integers(0, maxp + 1, B)
+    pos = np.asarray(pos, np.int32)
+    for b in empty:
+        tables[b], pos[b] = 0, 0
     if int8:
         pk = fa.QuantKV(*fa.quantize_kv(pk))
         pv = fa.QuantKV(*fa.quantize_kv(pv))
-    return q, pk, pv, tables, pos
+    return q, pk, pv, jnp.asarray(tables, jnp.int32), jnp.asarray(pos)
 
 
-@pytest.mark.parametrize("H,KH,S", [(4, 4, 1), (4, 2, 1), (4, 1, 1),
-                                    (4, 2, 5)])
-def test_fused_matches_gather(H, KH, S):
-    q, pk, pv, tables, pos = _pool_case(H=H, KH=KH, S=S)
+def _assert_fused_matches_gather(case, **fused_kw):
+    q, pk, pv, tables, pos = case
     ref = fa.paged_attention(q, pk, pv, tables, pos, kernel="gather")
-    out = fa.paged_attention(q, pk, pv, tables, pos, kernel="fused",
-                             interpret=True)
+    if fused_kw:    # the private entry: the walk chosen by hand
+        out = fa._paged_attention_fused(q, pk, pv, tables, pos,
+                                        interpret=True, **fused_kw)
+    else:
+        out = fa.paged_attention(q, pk, pv, tables, pos, kernel="fused",
+                                 interpret=True)
     assert out.dtype == ref.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("S", [1, 3])
-def test_fused_matches_gather_int8(S):
+# rows at the first position, the last of block 0, the first of block 1
+# and the table's very last position, in ONE call (bs = 4, M = 5)
+_RAGGED = dict(B=4, pos=[0, 3, 4, 19])
+
+_SHAPES = {
+    "mha": dict(H=4, KH=4, S=1),
+    "gqa2": dict(H=4, KH=2, S=1),
+    "mqa": dict(H=4, KH=1, S=1),
+    "gqa2-s5": dict(H=4, KH=2, S=5),
+    "ragged-frontiers": dict(H=4, KH=2, S=1, **_RAGGED),
+    "ragged-frontiers-s3": dict(H=4, KH=2, S=3, B=4, pos=[0, 1, 2, 17]),
+    "empty-slot-beside-full": dict(H=4, KH=2, S=1, B=3, pos=[19, 0, 7],
+                                   empty=(1,)),
+    "all-slots-empty": dict(H=4, KH=2, S=1, B=2, empty=(0, 1)),
+    "kh1-g4": dict(H=4, KH=1, S=1, **_RAGGED),
+    "kh1-g6-s3": dict(H=6, KH=1, S=3),
+    "kh2-g6": dict(H=12, KH=2, S=1, **_RAGGED),
+    "kh2-g1-s3": dict(H=2, KH=2, S=3),
+    "kh8-g1": dict(H=8, KH=8, S=1, **_RAGGED),
+    "kh8-g4": dict(H=32, KH=8, S=1, **_RAGGED),
+    "kh8-g4-s3": dict(H=32, KH=8, S=3),
+    # a prefill chunk on a narrow table: one row mid-table, one at 0
+    "chunk-narrow-table": dict(H=12, KH=2, S=16, bs=8, M=4, B=2,
+                               pos=[9, 0]),
+    "one-column-table": dict(H=4, KH=2, S=1, M=1, B=2, pos=[0, 3]),
+}
+
+
+@pytest.mark.parametrize("shape", list(_SHAPES))
+def test_fused_matches_gather(shape):
+    _assert_fused_matches_gather(_pool_case(**_SHAPES[shape]))
+
+
+@pytest.mark.parametrize("shape", ["gqa2", "ragged-frontiers",
+                                   "empty-slot-beside-full", "kh8-g4-s3",
+                                   "chunk-narrow-table"])
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_fused_narrow_heads_walk_matches_gather(shape, int8):
+    """The walk by BlockSpecs that a compiled kernel takes for heads
+    under a lane tile wide (interpreted here, where the choice has to be
+    made by hand): the same results, a dead column skipped."""
+    _assert_fused_matches_gather(_pool_case(int8=int8, **_SHAPES[shape]),
+                                 looped=False)
+
+
+@pytest.mark.parametrize("case", [
+    dict(S=1), dict(S=3),
+    dict(S=1, **_RAGGED),
+    dict(S=3, B=4, pos=[0, 1, 2, 17]),
+    dict(S=1, B=3, pos=[19, 0, 7], empty=(1,)),
+    dict(S=1, H=32, KH=8, **_RAGGED),
+], ids=["s1", "s3", "ragged-frontiers", "ragged-frontiers-s3",
+        "empty-slot-beside-full", "kh8-g4-ragged"])
+def test_fused_matches_gather_int8(case):
     """Both kernels read the SAME stored (int8, scale) pairs, so their
     outputs agree to float tolerance — and argmax over a vocab-sized
     projection agrees exactly with the f32 pool's (the greedy-decode
     criterion, checked end-to-end below)."""
-    q, pk, pv, tables, pos = _pool_case(S=S, int8=True)
-    ref = fa.paged_attention(q, pk, pv, tables, pos, kernel="gather")
-    out = fa.paged_attention(q, pk, pv, tables, pos, kernel="fused",
-                             interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    _assert_fused_matches_gather(_pool_case(int8=True, **case))
+
+
+@pytest.mark.parametrize("name,S,H,KH,want", [
+    ("qwen-decode", 1, 12, 2, 2), ("mistral-decode", 1, 32, 8, 8),
+    ("mistral-verify", 5, 32, 8, 8), ("mha-32-decode", 1, 32, 32, 16),
+    ("qwen-chunk", 256, 12, 2, 1), ("mistral-chunk", 256, 32, 8, 1),
+])
+def test_heads_per_step_rule(name, S, H, KH, want):
+    """All KV heads of a block in one grid step at decode and verify
+    widths, one head a step at the cells' chunk width (bs 256, D 128,
+    bf16): a pure function of the static shapes."""
+    SGp = -(-S * (H // KH) // 8) * 8
+    hb = fa._paged_heads_per_step(SGp, KH, 256, 128, 2, 2)
+    assert hb == want and KH % hb == 0
 
 
 def test_fused_under_jit_decode_shape():
