@@ -100,6 +100,7 @@ def beam_search(model: TransformerLM, variables, prompt,
     would waste (K-1)/K of the prefill FLOPs); ragged batches run one
     K-wide scan with per-row teacher-forcing, like ``generate()``.
     """
+    _arena_model(model, "beam_search()")
     B, Pn = prompt.shape
     K = int(beam_size)
     L = Pn + max_new_tokens
@@ -1536,6 +1537,18 @@ class LMWithFusedLoss(nn.Module):
         return total / n
 
 
+def _arena_model(model, what: str) -> None:
+    """``generate()`` and ``beam_search()`` thread a K/V arena through
+    ``TransformerLM``'s cached methods; a model that keeps other state
+    (models/hybrid_lm.py) is served by the paged engine alone."""
+    if getattr(model, "state_layers", 0):
+        raise NotImplementedError(
+            f"{what} threads a K/V arena and nothing else: a model with "
+            f"state-space layers keeps a recurrent state a row, which "
+            f"only the paged + chunked ContinuousEngine carries "
+            f"(docs/serving.md)")
+
+
 def generate(model: TransformerLM, variables, prompt,
              max_new_tokens: int, prompt_len=None, *,
              temperature: float = 0.0, top_k: int = 0,
@@ -1571,6 +1584,7 @@ def generate(model: TransformerLM, variables, prompt,
     row freezes at eos — the fixed-shape analog of stop-on-EOS (same
     contract as seq2seq.greedy_generate; output stays [B, max_new]).
     """
+    _arena_model(model, "generate()")
     B, Pn = prompt.shape
     L = Pn + max_new_tokens
     if L > model.max_position:

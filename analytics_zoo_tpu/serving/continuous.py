@@ -43,12 +43,14 @@ import numpy as np
 
 from analytics_zoo_tpu.learn.inference_model import (
     _next_bucket, filter_prompt_buckets)
+from analytics_zoo_tpu.models.hybrid_lm import HYBRID_COUNTERS, HybridLM
 from analytics_zoo_tpu.models.lm import (TransformerLM,
                                          top_p_filter)
 from analytics_zoo_tpu.models.speculative import accept_proposals
 from analytics_zoo_tpu.ops.flash_attention import (KV_SCALE_DTYPE,
                                                    QuantKV)
 from analytics_zoo_tpu.ops.sparse_attention import IndexedKeys
+from analytics_zoo_tpu.ops.ssm import HybridCache
 from analytics_zoo_tpu.serving.frontdoor import (PRIORITIES, QosPolicy,
                                                  WeightedWaitQueue)
 from analytics_zoo_tpu.serving import policy as scheduler_policy
@@ -69,6 +71,8 @@ logger = logging.getLogger("analytics_zoo_tpu")
 # in the order the step program returns them (docs/observability.md)
 DSA_COUNTERS = ("dsa_ctx_tokens", "dsa_read_tokens", "moe_assignments",
                 "moe_max_load")
+# (those of a model with state-space layers are ``HYBRID_COUNTERS``; of
+# either tuple the last is a maximum and the others are sums)
 
 
 def _zeros_like(x):
@@ -403,7 +407,19 @@ class ContinuousEngine:
         # index keys too.  A model without one takes none of these
         # branches: its programs are what they were.
         self._dsa = bool(getattr(model, "indexer_topk", 0))
-        self._dsa_counts = dict.fromkeys(DSA_COUNTERS, 0)
+        # ---- a model with state-space layers (models/hybrid_lm.py) -----
+        # keeps a fixed-size recurrent state a SLOT and state-space layer
+        # beside the paged K/V of its attention layers; the engine holds
+        # both as ONE pytree in the K pool's place (ops.ssm.HybridCache)
+        # and reaches the model through sibling step programs.  No other
+        # model takes these branches.
+        self._ssm = bool(getattr(model, "state_layers", 0))
+        # the counters such a model's step programs return, booked to the
+        # tick's flight record (``_note_counters``); none for any other
+        self._counter_names = (DSA_COUNTERS if self._dsa
+                               else HYBRID_COUNTERS if self._ssm else ())
+        self._counts = dict.fromkeys(self._counter_names, 0)
+        self._passes = 0
         # validate cache_dtype EAGERLY with a serving-level message — a
         # bad value must not surface as a bare jnp.dtype TypeError deep
         # inside arena allocation
@@ -485,6 +501,33 @@ class ContinuousEngine:
                     "(indexer_topk > 0) is served by the paged engine on "
                     "one chip with a bf16/float cache; not supported "
                     "with it: " + "; ".join(unsupported)
+                    + " (docs/serving.md)")
+        if self._ssm:
+            unsupported = [what for what, on in (
+                ("paged=False (the slot arena's programs carry no "
+                 "recurrent state)", not paged),
+                ("chunked=False (monolithic admission carries no state "
+                 "from the prompt to the decode rows)", not chunked),
+                ("enable_prefix_cache=True (a chain of KV block hashes "
+                 "does not describe a prefix whose state-space layers "
+                 "keep one state a slot: a matched block would skip "
+                 "tokens the state has to see)", enable_prefix_cache),
+                ("a tp mesh (the state arena is not sharded)",
+                 mesh is not None and int(mesh.shape.get("tp", 1)) > 1),
+                ("kv_dtype='int8'", kv_dtype == "int8"),
+                ("a draft model (a rejected proposal would have to roll "
+                 "the state back)", draft_model is not None),
+                ("the host tier (kv_host_store_bytes / "
+                 "prefix_directory)",
+                 kv_host_store_bytes > 0 or prefix_directory is not None),
+                ("elastic_pool=True", elastic_pool),
+            ) if on]
+            if unsupported:
+                raise ValueError(
+                    "a model with state-space layers (HybridLM) is served "
+                    "by the paged + chunked engine on one chip with a "
+                    "bf16/float cache and the prefix cache off; not "
+                    "supported with it: " + "; ".join(unsupported)
                     + " (docs/serving.md)")
         self.kernel = kernel
         if kv_dtype == "bf16":
@@ -587,6 +630,10 @@ class ContinuousEngine:
             if self._kv_int8:
                 per_block = block_bytes(model.num_layers, bs, H, D,
                                         "int8")
+            elif self._ssm:
+                # only the attention layers keep K/V in the pool
+                per_block = 2 * model.kv_layers * bs * H * D \
+                    * cdtype.itemsize
             else:
                 per_block = 2 * model.num_layers * bs * H * D \
                     * cdtype.itemsize
@@ -697,6 +744,25 @@ class ContinuousEngine:
                      geo[name][1]), cdtype, device=pool_sh)
                 self._pk = IndexedKeys(arena("k"), arena("index"))
                 self._pv = arena("v")
+            elif self._ssm:
+                # the K/V pool of the attention layers alone, and beside
+                # it one state and one convolution window a SLOT and
+                # state-space layer (an array a layer: a step program
+                # updates each in place, and none is sliced out of a
+                # stack).  The state is float32 whatever the cache's
+                # dtype: it is summed into at every token
+                geo = model.state_geometry()
+                kv_shape = (model.kv_layers, n_blocks, H, bs, D)
+                self._pk = HybridCache(
+                    jnp.zeros(kv_shape, cdtype),
+                    tuple(jnp.zeros((S,) + geo["ssm"], jnp.float32)
+                          for _ in range(model.state_layers)),
+                    tuple(jnp.zeros((S,) + geo["conv"], cdtype)
+                          for _ in range(model.state_layers)))
+                self._pv = jnp.zeros(kv_shape, cdtype)
+                self._ssm_row_bytes = model.state_layers * sum(
+                    int(np.prod(geo[k])) * isz for k, isz in
+                    (("ssm", 4), ("conv", cdtype.itemsize)))
             else:
                 self._pk = jnp.zeros(shape, cdtype, device=pool_sh)
                 self._pv = jnp.zeros(shape, cdtype, device=pool_sh)
@@ -1013,6 +1079,33 @@ class ContinuousEngine:
                                      jnp.max(stats[:, 3:], axis=0)])
             return toks, tok, pos, done, stats, pk, pv
 
+        def step_fn_paged_ssm(variables, pk, pv, tok, pos, done, tables,
+                              temps, seeds, topps, n_ticks, use_sample,
+                              use_topp):
+            """``step_fn_paged`` of a model with state-space layers: ``pk``
+            is a ``HybridCache`` whose state arenas are indexed by the
+            slot, and the counters (``HYBRID_COUNTERS``, summed over the
+            scan's ticks, the last the largest of them) come back after
+            ``done``.  Only the rows that decode (``dsa_live``) advance
+            their state."""
+
+            def one(carry, _):
+                tok, pos, done, pk, pv = carry
+                logits, pk, pv, stats = model.apply(
+                    variables, tok, pk, pv, tables, pos,
+                    dsa_live(done, tables), kernel=kern,
+                    method=HybridLM.decode_step_paged_ssm)
+                nxt, done = pick_next(logits, pos, done, temps, seeds,
+                                      topps, use_sample, use_topp)
+                pos = jnp.minimum(pos + 1, Lmax - 1)
+                return (nxt, pos, done, pk, pv), (nxt, stats)
+
+            (tok, pos, done, pk, pv), (toks, stats) = jax.lax.scan(
+                one, (tok, pos, done, pk, pv), None, length=n_ticks)
+            stats = jnp.concatenate([jnp.sum(stats[:, :-1], axis=0),
+                                     jnp.max(stats[:, -1:], axis=0)])
+            return toks, tok, pos, done, stats, pk, pv
+
         # one compiled program per (n_ticks, sampled) pair — n_ticks is
         # bounded by ticks_per_step, so the cache stays small
         self._step_cache: Dict[Tuple[int, bool, bool],
@@ -1029,6 +1122,8 @@ class ContinuousEngine:
                 fn = step_fn_paged if self.paged else step_fn
                 if self._dsa:
                     fn = step_fn_paged_dsa
+                elif self._ssm:
+                    fn = step_fn_paged_ssm
                 self._step_cache[key] = _WeightedJit(
                     partial(fn, n_ticks=n, use_sample=sampled,
                             use_topp=use_topp),
@@ -1205,6 +1300,41 @@ class ContinuousEngine:
             return (nxt, pos, done, cnxt,
                     dsa_stats(ctx, n_read, load + cload), pk, pv)
 
+        def fused_paged_ssm_fn(variables, pk, pv, tok, pos, done, tables,
+                               temps, seeds, topps, ctoks, cpos, clens,
+                               ctabs, ctemps, cseeds, ctopps, cslots,
+                               with_decode, use_sample, use_topp):
+            """``fused_paged_fn`` of a model with state-space layers: ``pk``
+            a ``HybridCache``; the chunk rows name their slots (``cslots``,
+            after the llama operands; a padding row's is out of range) so
+            that each reads its own state and writes it back; the tick's
+            counters come back after ``cnxt``.  A PREFILLING row is frozen
+            in the decode half, so its state moves in the chunk half
+            alone."""
+            stats = jnp.zeros((len(HYBRID_COUNTERS),), jnp.int32)
+            if with_decode:
+                logits, pk, pv, stats = model.apply(
+                    variables, tok, pk, pv, tables, pos,
+                    dsa_live(done, tables), kernel=kern,
+                    method=HybridLM.decode_step_paged_ssm)
+                nxt, done = pick_next(logits, pos, done, temps, seeds,
+                                      topps, use_sample, use_topp)
+                pos = jnp.minimum(pos + 1, Lmax - 1)
+            else:
+                nxt = tok
+            wpos = (cpos + jnp.minimum(jnp.min(nxt), 0) if with_decode
+                    else cpos)          # orders the writes: fused_paged_fn
+            clog, pk, pv, cstats = model.apply(
+                variables, ctoks, pk, pv, ctabs, wpos, clens, cslots,
+                kernel=kern, method=HybridLM.prefill_chunk_paged_ssm)
+            cnxt, _ = pick_next(
+                clog, cpos + clens - 1,
+                jnp.zeros(clens.shape, jnp.bool_), ctemps, cseeds,
+                ctopps, use_sample, use_topp)
+            stats = jnp.concatenate([stats[:-1] + cstats[:-1],
+                                     jnp.maximum(stats[-1:], cstats[-1:])])
+            return nxt, pos, done, cnxt, stats, pk, pv
+
         # one program per (with_decode, sampled, topp, read_len) —
         # read_len only varies on the arena path (O(log L) buckets)
         self._fused_cache: Dict[Tuple[bool, bool, bool, int],
@@ -1217,6 +1347,7 @@ class ContinuousEngine:
                 self.telemetry.jit_build("fused", key)
                 if self.paged:
                     fn = partial(fused_paged_dsa_fn if self._dsa
+                                 else fused_paged_ssm_fn if self._ssm
                                  else fused_paged_fn,
                                  with_decode=with_decode,
                                  use_sample=sampled, use_topp=use_topp)
@@ -1795,6 +1926,11 @@ class ContinuousEngine:
             raise ValueError(
                 f"prefix length {P} leaves no room for a suffix inside "
                 f"max prompt width {self.max_prompt_width}")
+        if self._ssm:
+            raise ValueError(
+                "register_prefix: a model with state-space layers shares "
+                "no prefix (its state is a slot's, not a block's); "
+                "docs/serving.md")
         if self.paged:
             return self._register_prefix_paged(tokens)
         _, ks, vs = self.model.apply(self._variables,
@@ -1948,6 +2084,12 @@ class ContinuousEngine:
             raise ValueError(
                 f"priority must be one of {PRIORITIES}, got {priority!r}")
         if handoff_cb is not None:
+            if self._ssm:
+                raise ValueError(
+                    "prefill/decode handoff ships a KV block chain; a "
+                    "model with state-space layers also carries a state "
+                    "a slot, which the wire format has no place for "
+                    "(docs/serving.md)")
             if not self.paged:
                 raise ValueError(
                     "handoff_cb requires paged=True: a prefill/decode "
@@ -1993,10 +2135,11 @@ class ContinuousEngine:
         Thread-safe like ``submit`` — the source pump may call straight
         into the destination engine; all device writes happen later on
         THIS engine's pump thread at admission."""
-        if not self.paged:
+        if not self.paged or self._ssm:
             raise ValueError(
-                "submit_handoff requires a paged engine: the handoff "
-                "wire format is a KV block chain")
+                "submit_handoff requires a paged engine of a model whose "
+                "whole cache rides the block table: the handoff wire "
+                "format is a KV block chain")
         if self.draft_model is not None:
             raise ValueError(
                 "prefill/decode handoff does not compose with "
@@ -3590,13 +3733,16 @@ class ContinuousEngine:
             from .fault import InjectedFault
             raise InjectedFault(msg)
 
-    def _note_dsa(self, counts) -> None:
-        """Book the four counters one device call of a model with an
-        indexer returned (``dsa_stats``) to the tick's flight record."""
-        c = self._dsa_counts
-        for name, v in zip(DSA_COUNTERS[:3], counts[:3]):
+    def _note_counters(self, counts, passes: int = 1) -> None:
+        """Book the counters one device call of a model with an indexer
+        or with state-space layers returned (``_counter_names``: the last
+        a maximum, the others sums) to the tick's flight record, and the
+        passes over the model the call made."""
+        c, names = self._counts, self._counter_names
+        for name, v in zip(names[:-1], counts[:-1]):
             c[name] += int(v)
-        c[DSA_COUNTERS[3]] = max(c[DSA_COUNTERS[3]], int(counts[3]))
+        c[names[-1]] = max(c[names[-1]], int(counts[-1]))
+        self._passes += passes
 
     def _tick_samples(self, n_active: int) -> dict:
         """Post-tick residency mix + queue/pool pressure, as plain host
@@ -3716,12 +3862,22 @@ class ContinuousEngine:
             rec["flush_events_overlapped"] = delta(
                 "flush_overlapped",
                 self.telemetry.c_flush_overlapped.value)
-        if self._dsa:
-            # what the selection read and how the experts were loaded,
-            # from the step program itself (docs/observability.md); the
-            # record of any other model has none of these
-            rec.update(self._dsa_counts)
-            self._dsa_counts = dict.fromkeys(DSA_COUNTERS, 0)
+        if self._counter_names:
+            # from the step programs themselves (docs/observability.md):
+            # what the selection read and how the experts were loaded (a
+            # model with an indexer), or whose recurrent state the tick
+            # advanced and what the experts held here were sent (one with
+            # state-space layers); the record of any other model has none
+            rec.update(self._counts)
+            if self._ssm:
+                # a row's state is read once and written once a pass, and
+                # a step of ``ticks_per_step`` tokens passes the model
+                # that many times
+                rec["ssm_state_bytes"] = 2 * self._ssm_row_bytes \
+                    * self._counts["ssm_rows"]
+                rec["ssm_passes"] = self._passes
+            self._counts = dict.fromkeys(self._counter_names, 0)
+            self._passes = 0
         if self._qos is not None:
             rec["qos_depths"] = {f"{c}/{t}" if t else c: n
                                  for (c, t), n in
@@ -3860,6 +4016,10 @@ class ContinuousEngine:
             # single-tick steps keep the write frontier inside the
             # pos + spec_k coverage _ensure_blocks grants this engine
             n_eff = 1
+        elif self._ssm and n_eff < self.ticks_per_step:
+            # two step programs and no more (precompile_chunked warms
+            # both): the last tokens of a draining engine go one a call
+            n_eff = 1
         step = self._get_step(n_eff, sampled, use_topp)
         lap("plan")
         if self.paged:
@@ -3888,8 +4048,8 @@ class ContinuousEngine:
         self._tok = np.array(tok)
         self._pos = np.array(pos)
         self._done = np.array(done)
-        if self._dsa:
-            self._note_dsa(np.asarray(dsa[0]))
+        if self._counter_names:
+            self._note_counters(np.asarray(dsa[0]), n_eff)
         lap("device_wait")
         for i in active:
             for j in range(n_eff):
@@ -4039,7 +4199,10 @@ class ContinuousEngine:
                 jnp.asarray(ctabs, jnp.int32),
                 jnp.asarray(ctemps, jnp.float32),
                 jnp.asarray(cseeds, jnp.uint32),
-                jnp.asarray(ctopps, jnp.float32))
+                jnp.asarray(ctopps, jnp.float32),
+                # (a model with state-space layers: whose state each
+                # chunk row carries)
+                *((jnp.asarray(cslots, jnp.int32),) if self._ssm else ()))
         else:
             read_len = next(b for b in self._read_buckets
                             if b >= need)
@@ -4063,10 +4226,10 @@ class ContinuousEngine:
                 jnp.asarray(ctopps, jnp.float32))
         self._dispatched()
         # one host sync for decode picks + chunk first-token picks
-        if self._dsa:
+        if self._counter_names:
             nxt, pos2, done2, cnxt, counts = jax.device_get(
                 (nxt, pos2, done2, cnxt, dsa[0]))
-            self._note_dsa(counts)
+            self._note_counters(counts, 1 + with_decode)
         else:
             nxt, pos2, done2, cnxt = jax.device_get(
                 (nxt, pos2, done2, cnxt))
@@ -4143,8 +4306,8 @@ class ContinuousEngine:
         self._tok = np.array(tok)
         self._pos = np.array(pos)
         self._done = np.array(done)
-        if self._dsa:
-            self._note_dsa(np.asarray(dsa[0]))
+        if self._counter_names:
+            self._note_counters(np.asarray(dsa[0]))
         lap("device_wait")
         self._reanchor_prefill()
         for i in decode_rows:
@@ -4176,6 +4339,12 @@ class ContinuousEngine:
         throughout — engine state is untouched."""
         if not self.chunked:
             raise ValueError("precompile_chunked requires chunked=True")
+        if self._ssm and self.n_active:
+            # a second, zeroed state arena beside the first would not fit
+            # where the first was sized to fill the chip
+            raise RuntimeError(
+                "precompile_chunked of a model with state-space layers "
+                "runs on the engine's own caches: call it before serving")
         S = self._S
         kmax = min(max_chunk_rows or S, S)
         kbs, kb = [], 1
@@ -4251,7 +4420,24 @@ class ContinuousEngine:
                         count += 1
                         continue
                     for wd in (False, True):
-                        if self.paged:
+                        if self._ssm:
+                            # on the engine's OWN caches (idle: checked
+                            # above).  Every decode row is frozen and
+                            # every chunk row padding (sink tables,
+                            # out-of-range slots), so the call hands them
+                            # back as it got them but for the sink block
+                            fn = self._get_fused(wd, sampled, use_topp)
+                            *_, self._pk, self._pv = fn(
+                                self._pk, self._pv, tok, pos, done,
+                                jnp.full((S, self._M), SINK_BLOCK,
+                                         jnp.int32),
+                                temps, seeds, topps, ctoks, cpos, clens,
+                                jnp.full((kb, width), SINK_BLOCK,
+                                         jnp.int32),
+                                *czeros, cslots)
+                            # tpulint: disable-next-line=TZ001
+                            jax.block_until_ready(self._pv)
+                        elif self.paged:
                             fn = self._get_fused(wd, sampled, use_topp)
                             # wait for the call: the next one allocates
                             # its zeroed pools when it is dispatched,
@@ -4295,6 +4481,18 @@ class ContinuousEngine:
                     jnp.zeros_like(self._dck),
                     jnp.zeros_like(self._dcv),
                     tok, pos, pos, done)
+            count += 1
+        if self._ssm and self.ticks_per_step > 1:
+            # the decode step of ``ticks_per_step`` tokens: the first
+            # request's last tokens go one a call (``_step_impl``), so no
+            # warm-up request would reach it
+            *_, self._pk, self._pv = self._get_step(
+                self.ticks_per_step, sampled, use_topp)(
+                self._pk, self._pv, tok, pos, done,
+                jnp.full((S, self._M), SINK_BLOCK, jnp.int32),
+                temps, seeds, topps)
+            # tpulint: disable-next-line=TZ001
+            jax.block_until_ready(self._pv)
             count += 1
         return count
 
@@ -4349,7 +4547,8 @@ class ContinuousEngine:
                 spec((kb,), jnp.int32), spec((kb,), jnp.int32),
                 spec((kb, self._M), jnp.int32),
                 spec((kb,), jnp.float32), spec((kb,), jnp.uint32),
-                spec((kb,), jnp.float32))
+                spec((kb,), jnp.float32),
+                *((spec((kb,), jnp.int32),) if self._ssm else ()))
         mem = lowered.compile().memory_analysis()
         return {
             "pool_bytes": sum(a.addressable_shards[0].data.nbytes

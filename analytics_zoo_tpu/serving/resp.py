@@ -108,6 +108,7 @@ class Stream:
     def __init__(self):
         self.entries: List[Tuple[bytes, List[bytes]]] = []  # (id, kv flat)
         self.seq = itertools.count(1)
+        self.last_ms = 0
         self.cond = threading.Condition()
         # consumer groups: name -> {"last": delivered-up-to id,
         #                           "pending": {id: consumer}}
@@ -116,18 +117,31 @@ class Stream:
         self.groups: Dict[bytes, Dict] = {}
 
     def add(self, fields: List[bytes]) -> bytes:
-        eid = f"{int(time.time() * 1000)}-{next(self.seq)}".encode()
         with self.cond:
+            # ids only grow, as Redis's do, whatever the wall clock does:
+            # ``after`` reads the entries as sorted
+            self.last_ms = max(self.last_ms, int(time.time() * 1000))
+            eid = f"{self.last_ms}-{next(self.seq)}".encode()
             self.entries.append((eid, fields))
             self.cond.notify_all()
         return eid
 
+    def after(self, last: bytes) -> List[Tuple[bytes, List[bytes]]]:
+        """The entries whose id lies above ``last``, oldest first: the
+        list's tail, found from its end, so that a reader who follows a
+        stream pays for what is new and not for all it has read (a token
+        stream holds a whole answer until its reader deletes it).  Called
+        with ``cond`` held."""
+        key = _parse_id(last)
+        i = len(self.entries)
+        while i and _parse_id(self.entries[i - 1][0]) > key:
+            i -= 1
+        return self.entries[i:]
 
-def _id_after(eid: bytes, last: bytes) -> bool:
-    def parse(x: bytes):
-        a, _, b = x.partition(b"-")
-        return (int(a), int(b or 0))
-    return parse(eid) > parse(last)
+
+def _parse_id(x: bytes) -> Tuple[int, int]:
+    a, _, b = x.partition(b"-")
+    return (int(a), int(b or 0))
 
 
 def _range_bound(x: bytes, *, high: bool) -> Tuple[int, int]:
@@ -326,12 +340,9 @@ class RespServer:
             count = int(args[5]) if len(args) > 5 and \
                 args[4].upper() == b"COUNT" else None
 
-            def _pid(eid: bytes) -> Tuple[int, int]:
-                a, _, b = eid.partition(b"-")
-                return (int(a), int(b or 0))
             with s.cond:
                 got = [[eid, fv] for eid, fv in s.entries
-                       if lo <= _pid(eid) <= hi]
+                       if lo <= _parse_id(eid) <= hi]
             return got[:count] if count else got
         if cmd == b"XDEL":
             s = self._stream(args[1])
@@ -358,7 +369,7 @@ class RespServer:
                     last = s.entries[-1][0] if s.entries else b"0-0"
 
             def select():
-                fresh = [e for e in s.entries if _id_after(e[0], last)]
+                fresh = s.after(last)
                 return fresh[:count] if count else fresh
 
             got = _await_fresh(s, block_ms, select)
@@ -398,8 +409,7 @@ class RespServer:
                 g = s.groups.get(group)
                 if g is None:
                     return None
-                fresh = [e for e in s.entries
-                         if _id_after(e[0], g["last"])]
+                fresh = s.after(g["last"])
                 if not fresh:
                     return None
                 if count:

@@ -67,6 +67,41 @@ class TestRespBroker:
         finally:
             srv.stop()
 
+    def test_a_follower_reads_the_tail_whatever_the_clock_does(self,
+                                                               monkeypatch):
+        """A reader that follows a stream gets what lies above its last id
+        and no more, found from the stream's end (``Stream.after``): ids
+        grow even when the wall clock steps back, entries deleted in the
+        middle leave the order standing, and a cursor from before the
+        first entry reads them all."""
+        import analytics_zoo_tpu.serving.resp as resp
+
+        now = [1000.0]
+        monkeypatch.setattr(resp.time, "time", lambda: now[0])
+        srv = RespServer(port=0).start()
+        try:
+            c = RespClient("127.0.0.1", srv.port)
+            ids = []
+            for i in range(6):
+                ids.append(c.execute("XADD", "s", "*", "k", str(i)))
+                now[0] -= 0.25 if i == 2 else -0.001
+            key = lambda e: tuple(map(int, e.split(b"-")))
+            assert sorted(ids, key=key) == ids
+            read = lambda last, *opt: [e[0] for e in c.execute(
+                "XREAD", *opt, "STREAMS", "s", last)[0][1]]
+            assert read("0-0") == ids
+            assert read(ids[3]) == ids[4:]
+            assert read(ids[1], "COUNT", "2") == ids[2:4]
+            assert c.execute("XREAD", "STREAMS", "s", ids[5]) is None
+            c.execute("XDEL", "s", ids[2], ids[4])
+            assert read(ids[0]) == [ids[1], ids[3], ids[5]]
+            c.execute("XGROUP", "CREATE", "s", "g", ids[3])
+            got = c.execute("XREADGROUP", "GROUP", "g", "w", "COUNT", "8",
+                            "STREAMS", "s", ">")
+            assert [e[0] for e in got[0][1]] == [ids[5]]
+        finally:
+            srv.stop()
+
     def test_split_pipeline_returns_what_pipeline_returned(self):
         """send() + collect() are the two halves of pipeline(): the
         same commands give the same replies, on the same connection, and
