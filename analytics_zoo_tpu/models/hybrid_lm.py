@@ -1,6 +1,10 @@
-"""A decoder whose layers are of two kinds — Mamba-2 state-space mixers
-and NoPE attention — each followed by routed experts beside a shared
-expert (IBM Granite 4.0-H: ``granitemoehybrid``).
+"""A decoder whose layers mix a recurrent kind and an attention kind, each
+followed by routed experts beside a shared expert (or, in the leading
+layers, a dense SwiGLU): Mamba-2 state-space mixers with NoPE attention
+(IBM Granite 4.0-H: ``granitemoehybrid``), or gated delta-rule mixers (KDA)
+with latent attention (MLA) under a sigmoid router (inclusionAI Ling 3.0:
+``bailing_hybrid``).  Each model takes ONE recurrent and ONE attention
+kind: the engine holds one state geometry and one pool geometry a model.
 
 A sibling of ``models/lm.py:TransformerLM``, not a mode of it: nothing here
 is on the path a llama or keye model traces.  Served by the paged + chunked
@@ -32,6 +36,29 @@ the convolution's):
   all of them, computes its own experts' gated outputs and leaves the rest
   out (``HeldExperts``) — the chip's share of an expert-parallel layer,
   run without its exchange.
+
+The second pair of kinds (``"kda"`` / ``"mla"``; x = RMSNorm(stream)):
+
+- KDA (``ops/kda.py``), H heads of dk = dv: ``[q | k | v] = silu(conv(x
+  W_qkv))`` (causal, depthwise, kernel K, no bias), ``q = L2(q) dk^-1/2``,
+  ``k = L2(k)``; ``g = lower_bound * sigmoid(exp(A_log_h) (x W_f +
+  dt_bias))`` a channel of the key; ``beta = sigmoid(x W_b)`` a head; the
+  delta rule; ``out = (RMSNorm_head(o) * sigmoid(x W_g)) W_o``.
+- MLA, H heads: ``[q_nope | q_pe] = x W_q``; ``[c | k_pe] = x W_kva``, ``c =
+  RMSNorm(c)``; ``[k_nope | v] = c W_kvb`` a head; rotary (interleaved
+  pairs) on q_pe and the ONE shared k_pe; ``score = (q_nope.k_nope +
+  q_pe.k_pe) / sqrt(nope + rope)``, causal softmax, a sigmoid gate a head
+  (``sigmoid(x W_gate)``), ``W_o``.  Served ABSORBED: the paged cache
+  holds ``c`` (the K pool) and ``k_pe`` (the V pool) a token and no head,
+  ``q_nope W_kvb,k^T`` scores against c, and ``(sum p c) W_kvb,v`` is the
+  head's value.
+- experts under ``router_score="sigmoid"``: ``s = sigmoid(x W_r)`` in
+  float32, ``b = s + bias`` for the selection alone: a group's score is the
+  sum of its two largest b, the ``expert_groups_kept`` best of
+  ``expert_groups`` groups stay, and the ``top_k`` largest b inside them
+  are chosen; gates ``routed_scale * s / sum(s chosen)``.
+- the first ``dense_layers`` layers: a SwiGLU of ``dense_width`` in the
+  experts' place; the head is untied where ``tie_embeddings`` is false.
 """
 
 from __future__ import annotations
@@ -43,13 +70,19 @@ import jax
 import jax.numpy as jnp
 
 from analytics_zoo_tpu.models.lm import _flat_pools, _stacked_pools
+from analytics_zoo_tpu.ops.kda import KDA_BLOCK, kda_chunk_scan, kda_step
 from analytics_zoo_tpu.ops.ssm import (HybridCache, conv_chunk, conv_step,
                                        ssm_chunk_scan, ssm_step)
 
 # what a tick of such a model says of itself (the engine books them to the
-# flight record under these names; docs/observability.md)
+# flight record under these names; docs/observability.md); a model with
+# latent attention also counts the latent cache rows its MLA layers read
 HYBRID_COUNTERS = ("ssm_rows", "ssm_chunk_tokens", "moe_assignments",
                    "moe_held_assignments", "moe_max_load")
+LATENT_COUNTERS = ("ssm_rows", "ssm_chunk_tokens", "latent_kv_rows",
+                   "moe_assignments", "moe_held_assignments", "moe_max_load")
+STATE_KINDS = ("mamba", "kda")
+ATTENTION_KINDS = ("attention", "mla")
 
 _init = nn.initializers.lecun_normal()
 
@@ -69,6 +102,24 @@ class _Scale(nn.Module):
         return xf * scale.astype(jnp.float32)
 
 
+def sigmoid_route(logits, bias, top_k, groups, groups_kept, scale):
+    """The group-limited sigmoid choice (DeepSeek-V3's ``noaux_tc``):
+    logits ``[N, X]`` float32 -> (gates ``[N, top_k]``, chosen experts
+    ``[N, top_k]``).  ``bias`` moves the choice and never a gate."""
+    N, X = logits.shape
+    s = jax.nn.sigmoid(logits)
+    b = s + bias.astype(jnp.float32)
+    per = X // groups
+    best = jax.lax.top_k(b.reshape(N, groups, per), 2)[0].sum(-1)
+    _, kept = jax.lax.top_k(best, groups_kept)             # [N, kept]
+    keep = jnp.zeros((N, groups), bool).at[
+        jnp.arange(N)[:, None], kept].set(True)
+    b = jnp.where(jnp.repeat(keep, per, axis=1), b, -jnp.inf)
+    _, chosen = jax.lax.top_k(b, top_k)
+    sc = jnp.take_along_axis(s, chosen, axis=1)
+    return scale * sc / jnp.sum(sc, -1, keepdims=True), chosen
+
+
 class HeldExperts(nn.Module):
     """Token-choice experts of which this chip holds a contiguous share.
 
@@ -82,7 +133,8 @@ class HeldExperts(nn.Module):
     batchmates.
 
     Parameters: ``router [E, X]``, ``w_in [held, E, 2F]`` (gate | up),
-    ``w_out [held, F, E]``.
+    ``w_out [held, F, E]``; under ``score="sigmoid"`` also ``bias [X]``,
+    and the choice is group-limited (:func:`sigmoid_route`).
     """
 
     experts_total: int
@@ -91,6 +143,10 @@ class HeldExperts(nn.Module):
     expert_width: int
     top_k: int
     dtype: jnp.dtype = jnp.bfloat16
+    score: str = "softmax"
+    groups: int = 1
+    groups_kept: int = 1
+    scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, count=None):
@@ -110,8 +166,14 @@ class HeldExperts(nn.Module):
         xt = x.reshape(-1, E)
         N = xt.shape[0]
         logits = jnp.dot(xt.astype(jnp.float32), router.astype(jnp.float32))
-        top, chosen = jax.lax.top_k(logits, K)              # [N, K]
-        gates = jax.nn.softmax(top, axis=-1)
+        if self.score == "sigmoid":
+            bias = self.param("bias", nn.initializers.zeros, (X,),
+                              jnp.float32)
+            gates, chosen = sigmoid_route(logits, bias, K, self.groups,
+                                          self.groups_kept, self.scale)
+        else:
+            top, chosen = jax.lax.top_k(logits, K)          # [N, K]
+            gates = jax.nn.softmax(top, axis=-1)
         local = chosen.reshape(-1) - lo                     # [N*K]
         held = (local >= 0) & (local < Xh)
         group = jnp.where(held, local, Xh)      # absent: past every group
@@ -293,6 +355,214 @@ class NopeAttention(nn.Module):
         return self.attn_out(o.astype(self.dtype)), pool_k, pool_v
 
 
+def _l2(x, eps=1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+
+
+class KdaMixer(nn.Module):
+    """The gated delta-rule mixer, in the shape of :class:`Mamba2Mixer`:
+    ``chunk`` runs T positions a row from a carried (state, window) and
+    gives both back, ``step`` runs one.  The input projection is one
+    matrix, ``[q | k | v | f | beta | gate]``."""
+
+    hidden_size: int
+    heads: int
+    head_dim: int
+    conv_kernel: int
+    lower_bound: float
+    eps: float
+    dtype: jnp.dtype
+
+    def setup(self):
+        H, P, E = self.heads, self.heads * self.head_dim, self.hidden_size
+        f32 = jnp.float32
+        self.in_proj = self.param("in_proj", _init, (E, 5 * P + H), f32)
+        self.conv_w = self.param("conv_w", _init, (self.conv_kernel, 3 * P),
+                                 f32)
+        self.dt_bias = self.param("dt_bias", nn.initializers.zeros, (P,),
+                                  f32)
+        self.A_log = self.param("A_log", nn.initializers.zeros, (H,), f32)
+        self.norm = self.param("norm", nn.initializers.ones,
+                               (self.head_dim,), f32)
+        self.out_proj = self.param("out_proj", _init, (P, E), f32)
+
+    def _split(self, u):
+        """-> qkv [.., 3P] before the convolution, g [.., H, dk], beta
+        [.., H], gate [.., P], all float32."""
+        p = jnp.dot(u.astype(self.dtype), self.in_proj.astype(self.dtype),
+                    preferred_element_type=jnp.float32)
+        H, dk = self.heads, self.head_dim
+        P = H * dk
+        f = p[..., 3 * P:4 * P] + self.dt_bias.astype(jnp.float32)
+        f = f.reshape(f.shape[:-1] + (H, dk)) * jnp.exp(
+            self.A_log.astype(jnp.float32))[:, None]
+        g = self.lower_bound * jax.nn.sigmoid(f)
+        beta = jax.nn.sigmoid(p[..., 4 * P:4 * P + H])
+        return p[..., :3 * P], g, beta, p[..., 4 * P + H:]
+
+    def _qkv(self, a):
+        """silu of the convolution's output -> q, k, v [.., H, dk]."""
+        a = jax.nn.silu(a)
+        H, dk = self.heads, self.head_dim
+        q, k, v = (a[..., i * H * dk:(i + 1) * H * dk].reshape(
+            a.shape[:-1] + (H, dk)) for i in range(3))
+        return _l2(q) * dk ** -0.5, _l2(k), v
+
+    def _out(self, o, gate):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + self.eps)
+        o = (o * self.norm.astype(jnp.float32)).reshape(gate.shape) \
+            * jax.nn.sigmoid(gate)
+        return jnp.dot(o.astype(self.dtype), self.out_proj.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+
+    def chunk(self, u, S, window, lens):
+        """u ``[R, T, E]``, S ``[R, H, dk, dv]``, window ``[R, K - 1,
+        3P]``, lens ``[R]``: positions at or past ``lens`` advance
+        neither."""
+        T = u.shape[1]
+        qkv, g, beta, gate = self._split(u)
+        a, window = conv_chunk(window, qkv, lens, self.conv_w, None)
+        q, k, v = self._qkv(a)
+        real = jnp.arange(T)[None, :] < lens[:, None]
+        g = jnp.where(real[..., None, None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+        Q = min(KDA_BLOCK, T)
+        pad = -T % Q
+        if pad:
+            padT = lambda t: jnp.pad(
+                t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            q, k, v, g, beta = (padT(t) for t in (q, k, v, g, beta))
+        o, S = kda_chunk_scan(S, q, k, v, g, beta, Q)
+        return self._out(o[:, :T], gate), S, window
+
+    def step(self, u, S, window, live):
+        """u ``[R, E]``; rows where ``live`` is false keep their state."""
+        qkv, g, beta, gate = self._split(u)
+        a, moved = conv_step(window, qkv, self.conv_w, None)
+        window = jnp.where(live[:, None, None], moved, window)
+        q, k, v = self._qkv(a)
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
+        o, S = kda_step(S, q, k, v, g, beta)
+        return self._out(o, gate), S, window
+
+
+def rope_interleaved(x, pos, theta):
+    """Rotary over the last axis in interleaved pairs ``(x[2i], x[2i+1])``
+    at angle ``pos * theta ** (-2i / d)``: x ``[B, T, (H,) d]``, pos ``[B,
+    T]``."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[..., None] * freqs
+    if x.ndim == ang.ndim + 1:
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                     -1).reshape(x.shape)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (no query latent) with a sigmoid gate a
+    head, served ABSORBED: dense (``__call__``) or against the paged
+    latent cache (``paged``: ``c`` in the K pool, ``k_pe`` in the V pool,
+    one row a token and no head)."""
+
+    hidden_size: int
+    num_heads: int
+    nope_dim: int
+    rope_dim: int
+    value_dim: int
+    latent_rank: int
+    rope_theta: float
+    eps: float
+    dtype: jnp.dtype
+
+    def setup(self):
+        H, E, L = self.num_heads, self.hidden_size, self.latent_rank
+        f32 = jnp.float32
+        self.wq = self.param("wq", _init, (E, H, self.nope_dim
+                                          + self.rope_dim), f32)
+        self.wkva = self.param("wkva", _init, (E, L + self.rope_dim), f32)
+        self.kv_norm = self.param("kv_norm", nn.initializers.ones, (L,),
+                                  f32)
+        self.wkvb = self.param("wkvb", _init,
+                               (L, H, self.nope_dim + self.value_dim), f32)
+        self.gate = self.param("gate", _init, (E, H), f32)
+        self.wo = self.param("wo", _init, (H, self.value_dim, E), f32)
+
+    def _project(self, x, pos):
+        """x ``[B, T, E]`` at positions ``pos [B, T]`` -> (the absorbed
+        query ``[B, T, H, L]``, q_pe ``[B, T, H, r]``, c ``[B, T, L]``,
+        k_pe ``[B, T, r]``)."""
+        dt, f32 = self.dtype, jnp.float32
+        mm = lambda spec, a, w: jnp.einsum(spec, a.astype(dt), w.astype(dt),
+                                           preferred_element_type=f32)
+        q = mm("bte,ehd->bthd", x, self.wq)
+        q_pe = rope_interleaved(q[..., self.nope_dim:], pos, self.rope_theta)
+        ckv = mm("bte,el->btl", x, self.wkva)
+        L = self.latent_rank
+        c = ckv[..., :L]
+        c = c * jax.lax.rsqrt(jnp.mean(c * c, -1, keepdims=True) + self.eps) \
+            * self.kv_norm.astype(f32)
+        k_pe = rope_interleaved(ckv[..., L:], pos, self.rope_theta)
+        qa = mm("bthn,lhn->bthl", q[..., :self.nope_dim],
+                self.wkvb[..., :self.nope_dim])
+        return qa, q_pe, c, k_pe
+
+    def _attend(self, x, qa, q_pe, cs, pes, mask):
+        """Scores of the absorbed queries against the latent rows ``cs
+        [B, C, L]`` / ``pes [B, C, r]`` where ``mask [B, T, C]``, the
+        latent mean back through ``W_kvb,v``, the gate, ``W_o``."""
+        dt, f32 = self.dtype, jnp.float32
+        s = (jnp.einsum("bthl,bcl->bhtc", qa.astype(dt), cs.astype(dt),
+                        preferred_element_type=f32)
+             + jnp.einsum("bthr,bcr->bhtc", q_pe.astype(dt), pes.astype(dt),
+                          preferred_element_type=f32)) \
+            * (self.nope_dim + self.rope_dim) ** -0.5
+        s = jnp.where(mask[:, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, -1)
+        oc = jnp.einsum("bhtc,bcl->bthl", p.astype(dt), cs.astype(dt),
+                        preferred_element_type=f32)
+        o = jnp.einsum("bthl,lhv->bthv", oc.astype(dt),
+                       self.wkvb[..., self.nope_dim:].astype(dt),
+                       preferred_element_type=f32)
+        o = o * jax.nn.sigmoid(jnp.einsum(
+            "bte,eh->bth", x.astype(dt), self.gate.astype(dt),
+            preferred_element_type=f32))[..., None]
+        return jnp.einsum("bthv,hve->bte", o.astype(dt), self.wo.astype(dt),
+                          preferred_element_type=f32)
+
+    def __call__(self, x):
+        B, T, _ = x.shape
+        pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+        qa, q_pe, c, k_pe = self._project(x, pos)
+        mask = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (B, T, T))
+        return self._attend(x, qa, q_pe, c, k_pe, mask)
+
+    def paged(self, xs, pool_c, pool_pe, tables, pos, limit, kernel):
+        """``NopeAttention.paged``'s contract over the latent pools
+        (``[N, 1, bs, L]`` and ``[N, 1, bs, r]``): the chunk's rows are
+        written first (``limit`` drops padding), then every row reads its
+        whole table in one gather.  ``kernel`` is not used: no kernel takes
+        these widths."""
+        from analytics_zoo_tpu.ops.flash_attention import paged_kv_update
+
+        B, T, _ = xs.shape
+        at = pos[:, None] + jnp.arange(T)[None, :]
+        qa, q_pe, c, k_pe = self._project(xs, at)
+        pool_c, pool_pe = paged_kv_update(pool_c, pool_pe, tables, pos,
+                                          c[:, :, None], k_pe[:, :, None],
+                                          limit=limit)
+        rows = lambda pool: jnp.take(pool, tables, axis=0).reshape(
+            B, -1, pool.shape[-1])
+        cs, pes = rows(pool_c), rows(pool_pe)
+        mask = jnp.arange(cs.shape[1])[None, None, :] <= at[:, :, None]
+        return (self._attend(xs, qa, q_pe, cs, pes, mask), pool_c,
+                pool_pe)
+
+
 class _Dims(NamedTuple):
     """What a layer needs of the model's numbers."""
 
@@ -315,13 +585,27 @@ class _Dims(NamedTuple):
     residual_multiplier: float
     ln_eps: float
     dtype: jnp.dtype
+    kda_heads: int
+    kda_head_dim: int
+    kda_lower_bound: float
+    latent_rank: int
+    rope_dim: int
+    value_dim: int
+    rope_theta: float
+    dense_width: int
+    router_score: str
+    expert_groups: int
+    expert_groups_kept: int
+    routed_scale: float
 
 
 class HybridLayer(nn.Module):
-    """One decoder layer of either kind."""
+    """One decoder layer of any kind; a ``dense`` one has a SwiGLU of
+    ``dense_width`` where the others have their experts."""
 
     kind: str
     cfg: "_Dims"
+    dense: bool = False
 
     def setup(self):
         c = self.cfg
@@ -332,18 +616,38 @@ class HybridLayer(nn.Module):
                                      c.ssm_head_dim, c.ssm_state,
                                      c.ssm_conv, c.ssm_chunk, c.ln_eps,
                                      c.dtype)
+        elif self.kind == "kda":
+            self.kda = KdaMixer(c.hidden_size, c.kda_heads, c.kda_head_dim,
+                                c.ssm_conv, c.kda_lower_bound,
+                                c.ln_eps, c.dtype)
+        elif self.kind == "mla":
+            self.attention = LatentAttention(
+                c.hidden_size, c.num_heads, c.head_dim, c.rope_dim,
+                c.value_dim, c.latent_rank, c.rope_theta, c.ln_eps, c.dtype)
         else:
             self.attention = NopeAttention(
                 c.hidden_size, c.num_heads, c.num_kv_heads, c.head_dim,
                 c.attention_multiplier, c.dtype)
+        if self.dense:
+            self.mlp = _SharedExpert(c.dense_width, c.dtype)
+            return
         self.moe = HeldExperts(c.experts_total, c.experts_held,
                                c.first_expert, c.expert_width,
-                               c.experts_per_token, c.dtype)
+                               c.experts_per_token, c.dtype,
+                               c.router_score, c.expert_groups,
+                               c.expert_groups_kept, c.routed_scale)
         self.shared = _SharedExpert(c.shared_width, c.dtype)
+
+    def recurrent(self):
+        """The state-carrying mixer of a ``"mamba"`` or ``"kda"`` layer."""
+        return self.mamba if self.kind == "mamba" else self.kda
 
     def ffn(self, x, count):
         """x float32 ``[..., E]`` after the mixer's residual."""
         v = self.ln_ffn(x).astype(self.cfg.dtype)
+        if self.dense:
+            return x + self.cfg.residual_multiplier * self.mlp(v), \
+                jnp.zeros((3,), jnp.int32)
         y, stats = self.moe(v, count)
         return x + self.cfg.residual_multiplier * (
             y + self.shared(v)), stats
@@ -356,8 +660,8 @@ class HybridLayer(nn.Module):
 
 
 class HybridLM(nn.Module):
-    """The whole decoder.  ``layer_types`` is the pattern, one of
-    ``"mamba"`` / ``"attention"`` a layer."""
+    """The whole decoder.  ``layer_types`` is the pattern: ``"mamba"`` /
+    ``"attention"`` (granite) or ``"kda"`` / ``"mla"`` (Ling) a layer."""
 
     vocab_size: int
     hidden_size: int
@@ -383,6 +687,24 @@ class HybridLM(nn.Module):
     max_position: int = 131072
     ln_eps: float = 1e-5
     dtype: jnp.dtype = jnp.bfloat16
+    # the gated delta-rule layers (``"kda"``): heads of dk = dv, the
+    # decay's floor a position (log)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_lower_bound: float = -5.0
+    # the latent attention layers (``"mla"``; head_dim is the nope width)
+    latent_rank: int = 0
+    rope_dim: int = 0
+    value_dim: int = 0
+    rope_theta: float = 10000.0
+    # the first ``dense_layers`` layers' SwiGLU, the router, the head
+    dense_layers: int = 0
+    dense_width: int = 0
+    router_score: str = "softmax"
+    expert_groups: int = 1
+    expert_groups_kept: int = 1
+    routed_scale: float = 1.0
+    tie_embeddings: bool = True
 
     pp_stages = 0               # what the engine asks of every model
 
@@ -392,39 +714,76 @@ class HybridLM(nn.Module):
 
     @property
     def kv_layers(self) -> int:
-        """Layers that keep K/V in the block pool."""
-        return sum(t == "attention" for t in self.layer_types)
+        """Layers that keep K/V (or the latent) in the block pool."""
+        return sum(t in ATTENTION_KINDS for t in self.layer_types)
 
     @property
     def state_layers(self) -> int:
         """Layers that keep a recurrent state a slot."""
-        return sum(t == "mamba" for t in self.layer_types)
+        return sum(t in STATE_KINDS for t in self.layer_types)
+
+    @property
+    def latent(self) -> bool:
+        return "mla" in self.layer_types
 
     @property
     def kv_heads(self) -> int:
-        return self.num_kv_heads
+        return 1 if self.latent else self.num_kv_heads
 
     @property
     def head_size(self) -> int:
-        return self.head_dim
+        return self.latent_rank + self.rope_dim if self.latent \
+            else self.head_dim
+
+    @property
+    def counter_names(self) -> tuple:
+        """What the step programs return, in order (``HYBRID_COUNTERS``,
+        or ``LATENT_COUNTERS`` under latent attention)."""
+        return LATENT_COUNTERS if self.latent else HYBRID_COUNTERS
+
+    def paged_cache_geometry(self) -> dict:
+        """(heads, width) a token of ONE attention layer keeps in the K
+        pool and in the V pool: K and V heads, or the latent ``c`` and the
+        shared rotary key."""
+        if self.latent:
+            return {"k": (1, self.latent_rank), "v": (1, self.rope_dim)}
+        return {"k": (self.num_kv_heads, self.head_dim),
+                "v": (self.num_kv_heads, self.head_dim)}
 
     def state_geometry(self) -> dict:
-        """Shapes a SLOT holds for ONE state-space layer."""
+        """Shapes a SLOT holds for ONE recurrent layer."""
+        if "kda" in self.layer_types:
+            P = self.kda_heads * self.kda_head_dim
+            return {"ssm": (self.kda_heads, self.kda_head_dim,
+                            self.kda_head_dim),
+                    "conv": (self.ssm_conv - 1, 3 * P)}
         I = self.ssm_heads * self.ssm_head_dim
         return {"ssm": (self.ssm_heads, self.ssm_head_dim, self.ssm_state),
                 "conv": (self.ssm_conv - 1, I + 2 * self.ssm_state)}
 
     def setup(self):
-        bad = set(self.layer_types) - {"mamba", "attention"}
+        kinds = set(self.layer_types)
+        bad = kinds - set(STATE_KINDS) - set(ATTENTION_KINDS)
         if bad or not self.layer_types:
             raise ValueError(f"layer_types holds {sorted(bad)}: a layer is "
-                             f"'mamba' or 'attention'")
+                             f"'mamba', 'kda', 'attention' or 'mla'")
+        if len(kinds & set(STATE_KINDS)) > 1 \
+                or len(kinds & set(ATTENTION_KINDS)) > 1:
+            raise ValueError(
+                f"layer_types mixes {sorted(kinds)}: a model has one "
+                f"recurrent kind and one attention kind (one state and one "
+                f"pool geometry)")
         self.embed = nn.Embed(self.vocab_size, self.hidden_size,
                               param_dtype=jnp.float32)
         dims = _Dims(*(getattr(self, f) for f in _Dims._fields))
-        self.layers = [HybridLayer(t, dims, name=f"layer_{i}")
+        self.layers = [HybridLayer(t, dims, i < self.dense_layers,
+                                   name=f"layer_{i}")
                        for i, t in enumerate(self.layer_types)]
         self.ln_f = _Scale(self.ln_eps)
+        if not self.tie_embeddings:
+            self.lm_head = self.param("lm_head", _init, (self.hidden_size,
+                                                         self.vocab_size),
+                                      jnp.float32)
 
     def _embed(self, tok):
         return self.embedding_multiplier \
@@ -432,6 +791,10 @@ class HybridLM(nn.Module):
 
     def _logits(self, x):
         h = self.ln_f(x).astype(self.dtype)
+        if not self.tie_embeddings:
+            return jnp.dot(h, self.lm_head.astype(self.dtype),
+                           preferred_element_type=jnp.float32) \
+                / self.logits_scaling
         return jnp.dot(h, self.embed.embedding.astype(self.dtype).T,
                        preferred_element_type=jnp.float32) \
             / self.logits_scaling
@@ -445,8 +808,8 @@ class HybridLM(nn.Module):
         lens = jnp.full((B,), T, jnp.int32)
         for layer in self.layers:
             u = layer.normed(x)
-            if layer.kind == "mamba":
-                out, _, _ = layer.mamba.chunk(
+            if layer.kind in STATE_KINDS:
+                out, _, _ = layer.recurrent().chunk(
                     u, jnp.zeros((B,) + geo["ssm"], jnp.float32),
                     jnp.zeros((B,) + geo["conv"], self.dtype), lens)
             else:
@@ -456,11 +819,21 @@ class HybridLM(nn.Module):
 
     # ---- against the engine's caches -----------------------------------
 
-    def _stats(self, rows, chunk_tokens, moe):
+    def _stats(self, rows, chunk_tokens, moe, latent_rows=None):
         moe = jnp.stack(moe)                                # [layers, 3]
-        return jnp.stack([rows, chunk_tokens, jnp.sum(moe[:, 0]),
-                          jnp.sum(moe[:, 1]),
-                          jnp.max(moe[:, 2])]).astype(jnp.int32)
+        first = [rows, chunk_tokens]
+        if latent_rows is not None:
+            first.append(latent_rows)
+        return jnp.stack(first + [jnp.sum(moe[:, 0]), jnp.sum(moe[:, 1]),
+                                  jnp.max(moe[:, 2])]).astype(jnp.int32)
+
+    def _latent_rows(self, cache, tables):
+        """Latent cache rows the MLA layers' gathers read in one pass:
+        every row's whole table, a layer (None without latent
+        attention)."""
+        if not self.latent:
+            return None
+        return jnp.int32(self.kv_layers * tables.size * cache.k.shape[3])
 
     def _paged_trunk(self, x, cache, pool_v, mamba, attend, count):
         """The layers over the engine's caches: ``mamba(mixer, u, h, w)``
@@ -473,9 +846,9 @@ class HybridLM(nn.Module):
         ia = im = 0
         for layer in self.layers:
             u = layer.normed(x)
-            if layer.kind == "mamba":
-                out, ssm[im], conv[im] = mamba(layer.mamba, u, ssm[im],
-                                               conv[im])
+            if layer.kind in STATE_KINDS:
+                out, ssm[im], conv[im] = mamba(layer.recurrent(), u,
+                                               ssm[im], conv[im])
                 im += 1
             else:
                 out, pk, pv = attend(layer.attention, u, pk, pv, ia * N)
@@ -488,21 +861,22 @@ class HybridLM(nn.Module):
     def decode_step_paged_ssm(self, tok, cache: HybridCache, pool_v,
                               tables, pos, live, kernel="gather"):
         """One token a row.  ``cache.ssm`` / ``cache.conv`` hold one array
-        a state-space layer, ``[slots, ...]``, row b of the batch IS slot
+        a recurrent layer, ``[slots, ...]``, row b of the batch IS slot
         b; rows where ``live`` is false (frozen, empty) advance no state
         and count for nothing.  Returns (logits [B, V], cache, pool_v,
-        counters int32 [5]: ``HYBRID_COUNTERS``, the expert counts summed
-        over the layers and the load the largest of any layer)."""
+        counters int32: ``counter_names``, the expert counts summed over
+        the layers and the load the largest of any layer)."""
         def attend(attention, u, pk, pv, first):
             out, pk, pv = attention.paged(u[:, None], pk, pv,
                                           tables + first, pos, None, kernel)
             return out[:, 0], pk, pv
 
-        x, cache, pv, moe = self._paged_trunk(
+        x, new, pv, moe = self._paged_trunk(
             self._embed(tok), cache, pool_v,
             lambda mixer, u, h, w: mixer.step(u, h, w, live), attend, live)
-        stats = self._stats(jnp.sum(live), jnp.int32(0), moe)
-        return self._logits(x), cache, pv, stats
+        stats = self._stats(jnp.sum(live), jnp.int32(0), moe,
+                            self._latent_rows(cache, tables))
+        return self._logits(x), new, pv, stats
 
     def prefill_chunk_paged_ssm(self, toks, cache: HybridCache, pool_v,
                                 tables, pos, lens, slots, kernel="gather"):
@@ -528,11 +902,12 @@ class HybridLM(nn.Module):
             return (out, hs.at[slots].set(h, mode="drop"),
                     ws.at[slots].set(w, mode="drop"))
 
-        x, cache, pv, moe = self._paged_trunk(
+        x, new, pv, moe = self._paged_trunk(
             self._embed(toks), cache, pool_v, mamba,
             lambda attention, u, pk, pv, first: attention.paged(
                 u, pk, pv, tables + first, pos, pos + lens, kernel),
             count)
         last = jnp.take_along_axis(x, (lens - 1)[:, None, None], axis=1)
-        stats = self._stats(jnp.sum(real), jnp.sum(count), moe)
-        return self._logits(last)[:, 0], cache, pv, stats
+        stats = self._stats(jnp.sum(real), jnp.sum(count), moe,
+                            self._latent_rows(cache, tables))
+        return self._logits(last)[:, 0], new, pv, stats

@@ -41,6 +41,9 @@ class HybridCache(NamedTuple):
     k: jax.Array          # [attn_layers, N, KH, bs, D]
     ssm: tuple            # a state-space layer: [slots, H, P, N] float32
     conv: tuple           # a state-space layer: [slots, K - 1, C]
+    # (a gated delta-rule layer, ops/kda.py, keeps [slots, H, dk, dv] and
+    # [slots, K - 1, 3 H dk] in the same two places; under latent attention
+    # ``k`` is the latent pool: models.hybrid_lm.LatentAttention)
 
 
 def ssm_step(h, x, dt, A, B, C, D):
@@ -127,11 +130,13 @@ def conv_step(window, u, w, b):
     """One token a row through the causal depthwise convolution.  window
     ``[R, K - 1, C]`` (the row's last inputs, oldest first), u ``[R, C]``,
     w ``[K, C]`` (tap k multiplies the input K - 1 - k positions back),
-    b ``[C]``.  Returns (out ``[R, C]`` float32 before the activation,
-    the window moved on by one)."""
+    b ``[C]`` or None (no bias).  Returns (out ``[R, C]`` float32 before
+    the activation, the window moved on by one)."""
     f = jnp.float32
     full = jnp.concatenate([window.astype(f), u.astype(f)[:, None]], 1)
-    out = jnp.einsum("rkc,kc->rc", full, w.astype(f)) + b.astype(f)
+    out = jnp.einsum("rkc,kc->rc", full, w.astype(f))
+    if b is not None:
+        out = out + b.astype(f)
     return out, full[:, 1:].astype(window.dtype)
 
 
@@ -139,14 +144,15 @@ def conv_chunk(window, u, lens, w, b):
     """A chunk of T positions a row: window ``[R, K - 1, C]``, u ``[R, T,
     C]``, lens ``[R]`` (the real positions; the window that comes back
     holds the last K - 1 inputs at or before position ``lens - 1``, so
-    padding does not move it).  Returns (out ``[R, T, C]`` float32,
-    window)."""
+    padding does not move it), b as :func:`conv_step`'s.  Returns (out
+    ``[R, T, C]`` float32, window)."""
     f = jnp.float32
     K = w.shape[0]
     T = u.shape[1]
     full = jnp.concatenate([window.astype(f), u.astype(f)], 1)
-    out = sum(full[:, k:k + T] * w[k].astype(f) for k in range(K)) \
-        + b.astype(f)
+    out = sum(full[:, k:k + T] * w[k].astype(f) for k in range(K))
+    if b is not None:
+        out = out + b.astype(f)
     # input j of the chunk sits at full[:, K - 1 + j]: the last K - 1
     # real inputs are full[:, lens : lens + K - 1]
     idx = lens[:, None] + jnp.arange(K - 1)[None, :]

@@ -43,7 +43,7 @@ import numpy as np
 
 from analytics_zoo_tpu.learn.inference_model import (
     _next_bucket, filter_prompt_buckets)
-from analytics_zoo_tpu.models.hybrid_lm import HYBRID_COUNTERS, HybridLM
+from analytics_zoo_tpu.models.hybrid_lm import HybridLM
 from analytics_zoo_tpu.models.lm import (TransformerLM,
                                          top_p_filter)
 from analytics_zoo_tpu.models.speculative import accept_proposals
@@ -75,8 +75,9 @@ DSA_COUNTERS = ("dsa_ctx_tokens", "dsa_read_tokens", "moe_assignments",
 # its program passed the decode rows and the chunk through ONE expert pass
 # (``fused_step_paged_sparse``), else 0
 DSA_SHARED = "moe_shared_assignments"
-# (those of a model with state-space layers are ``HYBRID_COUNTERS``; of
-# either tuple the last is a maximum and the others are sums)
+# (those of a model with state-space layers are its ``counter_names``:
+# models/hybrid_lm.py's ``HYBRID_COUNTERS`` or ``LATENT_COUNTERS``; of
+# every such tuple the last is a maximum and the others are sums)
 
 
 def _zeros_like(x):
@@ -421,7 +422,8 @@ class ContinuousEngine:
         # the counters such a model's step programs return, booked to the
         # tick's flight record (``_note_counters``); none for any other
         self._counter_names = (DSA_COUNTERS if self._dsa
-                               else HYBRID_COUNTERS if self._ssm else ())
+                               else model.counter_names if self._ssm
+                               else ())
         self._counter_fields = self._counter_names + (
             (DSA_SHARED,) if self._dsa else ())
         self._counts = dict.fromkeys(self._counter_fields, 0)
@@ -637,9 +639,11 @@ class ContinuousEngine:
                 per_block = block_bytes(model.num_layers, bs, H, D,
                                         "int8")
             elif self._ssm:
-                # only the attention layers keep K/V in the pool
-                per_block = 2 * model.kv_layers * bs * H * D \
-                    * cdtype.itemsize
+                # only the attention layers keep K/V (or the latent) in
+                # the pool, at the widths the model says a token keeps
+                per_block = model.kv_layers * bs * cdtype.itemsize * sum(
+                    h * w for h, w in
+                    model.paged_cache_geometry().values())
             else:
                 per_block = 2 * model.num_layers * bs * H * D \
                     * cdtype.itemsize
@@ -756,19 +760,27 @@ class ContinuousEngine:
                 # state-space layer (an array a layer: a step program
                 # updates each in place, and none is sliced out of a
                 # stack).  The state is float32 whatever the cache's
-                # dtype: it is summed into at every token
+                # dtype: it is summed into at every token.  Under latent
+                # attention the K pool holds the latent and the V pool the
+                # shared rotary key (paged_cache_geometry)
                 geo = model.state_geometry()
-                kv_shape = (model.kv_layers, n_blocks, H, bs, D)
+                kv = model.paged_cache_geometry()
+                pool = lambda n: jnp.zeros(
+                    (model.kv_layers, n_blocks, kv[n][0], bs, kv[n][1]),
+                    cdtype)
                 self._pk = HybridCache(
-                    jnp.zeros(kv_shape, cdtype),
+                    pool("k"),
                     tuple(jnp.zeros((S,) + geo["ssm"], jnp.float32)
                           for _ in range(model.state_layers)),
                     tuple(jnp.zeros((S,) + geo["conv"], cdtype)
                           for _ in range(model.state_layers)))
-                self._pv = jnp.zeros(kv_shape, cdtype)
+                self._pv = pool("v")
                 self._ssm_row_bytes = model.state_layers * sum(
                     int(np.prod(geo[k])) * isz for k, isz in
                     (("ssm", 4), ("conv", cdtype.itemsize)))
+                # a latent cache row: c and k_pe of one token, one layer
+                self._latent_row_bytes = cdtype.itemsize * sum(
+                    h * w for h, w in kv.values())
             else:
                 self._pk = jnp.zeros(shape, cdtype, device=pool_sh)
                 self._pv = jnp.zeros(shape, cdtype, device=pool_sh)
@@ -1090,7 +1102,7 @@ class ContinuousEngine:
                               use_topp):
             """``step_fn_paged`` of a model with state-space layers: ``pk``
             is a ``HybridCache`` whose state arenas are indexed by the
-            slot, and the counters (``HYBRID_COUNTERS``, summed over the
+            slot, and the counters (``counter_names``, summed over the
             scan's ticks, the last the largest of them) come back after
             ``done``.  Only the rows that decode (``dsa_live``) advance
             their state."""
@@ -1318,7 +1330,7 @@ class ContinuousEngine:
             counters come back after ``cnxt``.  A PREFILLING row is frozen
             in the decode half, so its state moves in the chunk half
             alone."""
-            stats = jnp.zeros((len(HYBRID_COUNTERS),), jnp.int32)
+            stats = jnp.zeros((len(self._counter_names),), jnp.int32)
             if with_decode:
                 logits, pk, pv, stats = model.apply(
                     variables, tok, pk, pv, tables, pos,
@@ -3883,6 +3895,10 @@ class ContinuousEngine:
                 rec["ssm_state_bytes"] = 2 * self._ssm_row_bytes \
                     * self._counts["ssm_rows"]
                 rec["ssm_passes"] = self._passes
+                if "latent_kv_rows" in self._counts:
+                    # the latent rows the MLA layers' gathers read
+                    rec["latent_kv_bytes"] = self._latent_row_bytes \
+                        * self._counts["latent_kv_rows"]
             self._counts = dict.fromkeys(self._counter_fields, 0)
             self._passes = 0
         if self._qos is not None:
