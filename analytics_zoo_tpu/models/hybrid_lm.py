@@ -63,7 +63,8 @@ The second pair of kinds (``"kda"`` / ``"mla"``; x = RMSNorm(stream)):
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+import math
+from typing import Callable, NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
@@ -277,11 +278,17 @@ class Mamba2Mixer(nn.Module):
     def _A(self):
         return -jnp.exp(self.A_log.astype(jnp.float32))
 
-    def chunk(self, u, h, window, lens):
-        """u ``[R, T, E]``, h ``[R, H, P, N]``, window ``[R, K - 1, C]``,
-        lens ``[R]``: positions at or past ``lens`` advance neither."""
-        T = u.shape[1]
+    def project(self, u):
+        """The input projection, row by row: (z, what ``_out`` takes
+        beside the core's output; (xbc, dt), what the core takes)."""
         z, xbc, dt = self._split(u)
+        return z, (xbc, dt)
+
+    def chunk_core(self, xd, h, window, lens):
+        """:meth:`chunk` between its projections: ``xd`` of ``[R, T]``
+        rows -> (y ``[R, T, H, P]``, h, window)."""
+        xbc, dt = xd
+        T = xbc.shape[1]
         a, window = conv_chunk(window, xbc, lens, self.conv_w, self.conv_b)
         x, B, C = self._xbc(a)
         real = jnp.arange(T)[None, :] < lens[:, None]
@@ -293,16 +300,29 @@ class Mamba2Mixer(nn.Module):
                 t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             x, dt, B, C = padT(x), padT(dt), padT(B), padT(C)
         y, h = ssm_chunk_scan(h, x, dt, self._A(), B, C, self.D, Q)
-        return self._out(y[:, :T], z), h, window
+        return y[:, :T], h, window
 
-    def step(self, u, h, window, live):
-        """u ``[R, E]``; rows where ``live`` is false keep their state."""
-        z, xbc, dt = self._split(u)
+    def step_core(self, xd, h, window, live):
+        """:meth:`step` between its projections: y ``[R, H, P]``."""
+        xbc, dt = xd
         a, moved = conv_step(window, xbc, self.conv_w, self.conv_b)
         window = jnp.where(live[:, None, None], moved, window)
         x, B, C = self._xbc(a)
         dt = jnp.where(live[:, None], self._dt(dt), 0.0)
         y, h = ssm_step(h, x, dt, self._A(), B, C, self.D)
+        return y, h, window
+
+    def chunk(self, u, h, window, lens):
+        """u ``[R, T, E]``, h ``[R, H, P, N]``, window ``[R, K - 1, C]``,
+        lens ``[R]``: positions at or past ``lens`` advance neither."""
+        z, xd = self.project(u)
+        y, h, window = self.chunk_core(xd, h, window, lens)
+        return self._out(y, z), h, window
+
+    def step(self, u, h, window, live):
+        """u ``[R, E]``; rows where ``live`` is false keep their state."""
+        z, xd = self.project(u)
+        y, h, window = self.step_core(xd, h, window, live)
         return self._out(y, z), h, window
 
 
@@ -415,12 +435,17 @@ class KdaMixer(nn.Module):
         return jnp.dot(o.astype(self.dtype), self.out_proj.astype(self.dtype),
                        preferred_element_type=jnp.float32)
 
-    def chunk(self, u, S, window, lens):
-        """u ``[R, T, E]``, S ``[R, H, dk, dv]``, window ``[R, K - 1,
-        3P]``, lens ``[R]``: positions at or past ``lens`` advance
-        neither."""
-        T = u.shape[1]
+    def project(self, u):
+        """The input projection, row by row: (gate, what ``_out`` takes
+        beside the core's output; (qkv, g, beta), what the core takes)."""
         qkv, g, beta, gate = self._split(u)
+        return gate, (qkv, g, beta)
+
+    def chunk_core(self, xd, S, window, lens):
+        """:meth:`chunk` between its projections: ``xd`` of ``[R, T]``
+        rows -> (o ``[R, T, H, dv]``, S, window)."""
+        qkv, g, beta = xd
+        T = qkv.shape[1]
         a, window = conv_chunk(window, qkv, lens, self.conv_w, None)
         q, k, v = self._qkv(a)
         real = jnp.arange(T)[None, :] < lens[:, None]
@@ -433,17 +458,31 @@ class KdaMixer(nn.Module):
                 t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             q, k, v, g, beta = (padT(t) for t in (q, k, v, g, beta))
         o, S = kda_chunk_scan(S, q, k, v, g, beta, Q)
-        return self._out(o[:, :T], gate), S, window
+        return o[:, :T], S, window
 
-    def step(self, u, S, window, live):
-        """u ``[R, E]``; rows where ``live`` is false keep their state."""
-        qkv, g, beta, gate = self._split(u)
+    def step_core(self, xd, S, window, live):
+        """:meth:`step` between its projections: o ``[R, H, dv]``."""
+        qkv, g, beta = xd
         a, moved = conv_step(window, qkv, self.conv_w, None)
         window = jnp.where(live[:, None, None], moved, window)
         q, k, v = self._qkv(a)
         g = jnp.where(live[:, None, None], g, 0.0)
         beta = jnp.where(live[:, None], beta, 0.0)
         o, S = kda_step(S, q, k, v, g, beta)
+        return o, S, window
+
+    def chunk(self, u, S, window, lens):
+        """u ``[R, T, E]``, S ``[R, H, dk, dv]``, window ``[R, K - 1,
+        3P]``, lens ``[R]``: positions at or past ``lens`` advance
+        neither."""
+        gate, xd = self.project(u)
+        o, S, window = self.chunk_core(xd, S, window, lens)
+        return self._out(o, gate), S, window
+
+    def step(self, u, S, window, live):
+        """u ``[R, E]``; rows where ``live`` is false keep their state."""
+        gate, xd = self.project(u)
+        o, S, window = self.step_core(xd, S, window, live)
         return self._out(o, gate), S, window
 
 
@@ -835,45 +874,108 @@ class HybridLM(nn.Module):
             return None
         return jnp.int32(self.kv_layers * tables.size * cache.k.shape[3])
 
-    def _paged_trunk(self, x, cache, pool_v, mamba, attend, count):
-        """The layers over the engine's caches: ``mamba(mixer, u, h, w)``
-        and ``attend(attention, u, pk, pv, first_block)`` run a layer's
-        mixer of either kind and give its caches back; ``count`` says
-        whose expert picks are counted.  Returns (x, cache, pool_v, the
-        layers' expert counters)."""
+    def _paged_trunk(self, parts, cache, pool_v):
+        """The layers over the engine's caches for one or two PARTS of
+        rows (``_Part``: the decode rows, a grid of chunk rows, or the two
+        in that order).  One part runs each layer's mixer whole
+        (``part.mix``); two share every matrix that acts row by row: a
+        recurrent layer projects both parts' rows in one matmul, runs the
+        two parts' cores one after the other on the state arrays, and
+        projects out once; an attention layer runs the
+        decode rows' call, then the chunk rows'; the expert layer (or dense
+        SwiGLU) takes every row in one call.  Returns (the parts' x, cache,
+        pool_v, the layers' expert counters)."""
         (pk, pv), N = _flat_pools((cache.k, pool_v))
         ssm, conv, moe = list(cache.ssm), list(cache.conv), []
+        xs = [p.x for p in parts]
         ia = im = 0
         for layer in self.layers:
-            u = layer.normed(x)
+            us = [layer.normed(x) for x in xs]
             if layer.kind in STATE_KINDS:
-                out, ssm[im], conv[im] = mamba(layer.recurrent(), u,
-                                               ssm[im], conv[im])
+                outs, ssm[im], conv[im] = _recurrent(
+                    layer.recurrent(), parts, us, ssm[im], conv[im])
                 im += 1
             else:
-                out, pk, pv = attend(layer.attention, u, pk, pv, ia * N)
+                outs = []
+                for part, u in zip(parts, us):
+                    lag = None
+                    if outs:
+                        # this part's first write to the pool needs no
+                        # value that the part before's read of it
+                        # produces, so nothing orders the two and a
+                        # compiler may keep a copy of the pool for that
+                        # read.  Ordering them by a value does: min(0 or
+                        # 1, 0) adds 0, whatever the sum holds
+                        lag = jnp.minimum(jnp.isnan(jnp.sum(
+                            outs[-1])).astype(jnp.int32), 0)
+                    out, pk, pv = part.attend(layer.attention, u, pk, pv,
+                                              ia * N, lag)
+                    outs.append(out)
                 ia += 1
-            x, st = layer.ffn(layer.mixed(x, out), count)
+            xs, st = _joined(layer.ffn,
+                             [layer.mixed(x, o) for x, o in zip(xs, outs)],
+                             [p.count for p in parts])
             moe.append(st)
         pk, pv = _stacked_pools((pk, pv), max(ia, 1))
-        return x, HybridCache(pk, tuple(ssm), tuple(conv)), pv, moe
+        return xs, HybridCache(pk, tuple(ssm), tuple(conv)), pv, moe
 
-    def decode_step_paged_ssm(self, tok, cache: HybridCache, pool_v,
-                              tables, pos, live, kernel="gather"):
-        """One token a row.  ``cache.ssm`` / ``cache.conv`` hold one array
-        a recurrent layer, ``[slots, ...]``, row b of the batch IS slot
-        b; rows where ``live`` is false (frozen, empty) advance no state
-        and count for nothing.  Returns (logits [B, V], cache, pool_v,
-        counters int32: ``counter_names``, the expert counts summed over
-        the layers and the load the largest of any layer)."""
-        def attend(attention, u, pk, pv, first):
+    def _decode_part(self, tok, tables, pos, live, kernel):
+        """The trunk's part of the decode rows: one token a row; ``cache.
+        ssm`` / ``cache.conv`` hold one array a recurrent layer, ``[slots,
+        ...]``, and row b of the batch IS slot b; rows where ``live`` is
+        false (frozen, empty) advance no state and count for nothing."""
+        def attend(attention, u, pk, pv, first, lag):
             out, pk, pv = attention.paged(u[:, None], pk, pv,
                                           tables + first, pos, None, kernel)
             return out[:, 0], pk, pv
 
-        x, new, pv, moe = self._paged_trunk(
-            self._embed(tok), cache, pool_v,
-            lambda mixer, u, h, w: mixer.step(u, h, w, live), attend, live)
+        return _Part(
+            self._embed(tok),
+            lambda mixer, u, h, w: mixer.step(u, h, w, live),
+            lambda mixer, xd, h, w: mixer.step_core(xd, h, w, live),
+            attend, live)
+
+    def _chunk_part(self, toks, n_slots, tables, pos, lens, slots, kernel):
+        """The trunk's part of a grid of chunk rows (what
+        :meth:`prefill_chunk_paged_ssm` says of them) and (whether each
+        row is real, which positions count)."""
+        S = toks.shape[1]
+        real = slots < n_slots
+        read = jnp.minimum(slots, n_slots - 1)
+        fresh = pos == 0
+        count = (jnp.arange(S)[None, :] < lens[:, None]) & real[:, None]
+
+        def carried(run, hs, ws):
+            """``run(h, w)`` from each row's own state (zero where the
+            row starts at position 0), written back to its slot."""
+            h = jnp.where(fresh[:, None, None, None], 0.0, hs[read])
+            w = jnp.where(fresh[:, None, None], 0, ws[read]).astype(ws.dtype)
+            y, h, w = run(h, w)
+            return (y, hs.at[slots].set(h, mode="drop"),
+                    ws.at[slots].set(w, mode="drop"))
+
+        def attend(attention, u, pk, pv, first, lag):
+            return attention.paged(u, pk, pv, tables + first,
+                                   pos if lag is None else pos + lag,
+                                   pos + lens, kernel)
+
+        part = _Part(
+            self._embed(toks),
+            lambda mixer, u, hs, ws: carried(
+                lambda h, w: mixer.chunk(u, h, w, lens), hs, ws),
+            lambda mixer, xd, hs, ws: carried(
+                lambda h, w: mixer.chunk_core(xd, h, w, lens), hs, ws),
+            attend, count)
+        return part, real, count
+
+    def decode_step_paged_ssm(self, tok, cache: HybridCache, pool_v,
+                              tables, pos, live, kernel="gather"):
+        """One token a row (``_decode_part``).  Returns (logits [B, V],
+        cache, pool_v, counters int32: ``counter_names``, the expert counts
+        summed over the layers and the load the largest of any layer)."""
+        (x,), new, pv, moe = self._paged_trunk(
+            [self._decode_part(tok, tables, pos, live, kernel)], cache,
+            pool_v)
         stats = self._stats(jnp.sum(live), jnp.int32(0), moe,
                             self._latent_rows(cache, tables))
         return self._logits(x), new, pv, stats
@@ -888,26 +990,108 @@ class HybridLM(nn.Module):
         advance neither the state nor the convolution's window, and write
         no K/V.  Returns (last-real-position logits [B, V], cache, pool_v,
         counters)."""
-        S = toks.shape[1]
         n_slots = cache.ssm[0].shape[0] if cache.ssm else 1
-        real = slots < n_slots
-        read = jnp.minimum(slots, n_slots - 1)
-        fresh = pos == 0
-        count = (jnp.arange(S)[None, :] < lens[:, None]) & real[:, None]
-
-        def mamba(mixer, u, hs, ws):
-            h = jnp.where(fresh[:, None, None, None], 0.0, hs[read])
-            w = jnp.where(fresh[:, None, None], 0, ws[read]).astype(ws.dtype)
-            out, h, w = mixer.chunk(u, h, w, lens)
-            return (out, hs.at[slots].set(h, mode="drop"),
-                    ws.at[slots].set(w, mode="drop"))
-
-        x, new, pv, moe = self._paged_trunk(
-            self._embed(toks), cache, pool_v, mamba,
-            lambda attention, u, pk, pv, first: attention.paged(
-                u, pk, pv, tables + first, pos, pos + lens, kernel),
-            count)
+        part, real, count = self._chunk_part(toks, n_slots, tables, pos,
+                                             lens, slots, kernel)
+        (x,), new, pv, moe = self._paged_trunk([part], cache, pool_v)
         last = jnp.take_along_axis(x, (lens - 1)[:, None, None], axis=1)
         stats = self._stats(jnp.sum(real), jnp.sum(count), moe,
                             self._latent_rows(cache, tables))
         return self._logits(last)[:, 0], new, pv, stats
+
+    def fused_step_paged_ssm(self, tok, cache: HybridCache, pool_v, tables,
+                             pos, live, ctoks, ctabs, cpos, clens, cslots,
+                             kernel="gather"):
+        """:meth:`decode_step_paged_ssm` of the decode rows, then
+        :meth:`prefill_chunk_paged_ssm` of a grid of chunk rows on the
+        caches it left, in ONE pass: every matrix that acts row by row (a
+        recurrent mixer's projections, the router, the experts, the shared
+        expert or dense SwiGLU, the head) takes both kinds of row in one
+        call, so a tick reads those weights once, not twice.  A PREFILLING
+        row is frozen in the decode rows (``live`` false), so its chunk
+        reads the state it would have read after the decode pass.  The
+        same caches, chunk logits and live rows' logits as the two passes
+        (a frozen row's, which the engine takes no token from, may
+        differ); the counters are theirs summed, but for the largest load
+        of an expert, which is now over both kinds of row.  Returns (logits [B, V], the chunks'
+        last-real-position logits [kb, V], cache, pool_v, counters)."""
+        n_slots = cache.ssm[0].shape[0] if cache.ssm else 1
+        chunk, real, count = self._chunk_part(ctoks, n_slots, ctabs, cpos,
+                                              clens, cslots, kernel)
+        (xd, xc), new, pv, moe = self._paged_trunk(
+            [self._decode_part(tok, tables, pos, live, kernel), chunk],
+            cache, pool_v)
+        last = jnp.take_along_axis(xc, (clens - 1)[:, None, None], axis=1)
+        latent = self._latent_rows(cache, tables)
+        stats = self._stats(
+            jnp.sum(live) + jnp.sum(real), jnp.sum(count), moe,
+            None if latent is None
+            else latent + self._latent_rows(cache, ctabs))
+        logits = self._logits(jnp.concatenate([xd, last[:, 0]]))
+        B = tok.shape[0]
+        return logits[:B], logits[B:], new, pv, stats
+
+
+class _Part(NamedTuple):
+    """What :meth:`HybridLM._paged_trunk` needs of one kind of row: ``x``
+    (the embedded rows), ``mix(mixer, u, hs, ws)`` and ``core(mixer,
+    projected, hs, ws)`` (a recurrent layer's whole mixer, or the mixer
+    between its projections, over the slot-indexed state arrays), ``attend
+    (attention, u, pk, pv, first_block, lag)`` (``lag``: an int32 zero that
+    orders this part's pool writes after the part before's reads, or None)
+    and ``count`` (whose expert picks are counted)."""
+
+    x: jax.Array
+    mix: Callable
+    core: Callable
+    attend: Callable
+    count: jax.Array
+
+
+def _recurrent(mixer, parts, us, hs, ws):
+    """A recurrent layer's mixer over the parts of
+    :meth:`HybridLM._paged_trunk`: (the parts' outputs, hs, ws)."""
+    if len(parts) == 1:
+        out, hs, ws = parts[0].mix(mixer, us[0], hs, ws)
+        return [out], hs, ws
+    gate, proj = mixer.project(jnp.concatenate(
+        [u.reshape(-1, u.shape[-1]) for u in us]))
+    leads = [u.shape[:-1] for u in us]
+    starts = [0, math.prod(leads[0])]
+    ys = [None, None]
+    # the chunk rows' core first, then the decode rows' on the state
+    # arrays it returned: the rows a chunk carries are frozen among the
+    # decode rows and the decode rows' slots carry no chunk, so either
+    # order gives the same states, and this one keeps fewer temporaries
+    # alive (compiled for a v5e: granite's fused program of 4 chunk rows
+    # 1.70 GB the other way round, 1.17 GB this way)
+    for i in (1, 0):
+        lead, n, at = leads[i], math.prod(leads[i]), starts[i]
+        mine = jax.tree.map(
+            lambda a: a[at:at + n].reshape(lead + a.shape[1:]), proj)
+        y, hs, ws = parts[i].core(mixer, mine, hs, ws)
+        ys[i] = y.reshape((n,) + y.shape[len(lead):])
+    out = mixer._out(jnp.concatenate(ys), gate)
+    return _split_rows(out, leads), hs, ws
+
+
+def _split_rows(y, leads):
+    """``y [sum of rows, ...]`` back into arrays of the leading shapes."""
+    out, at = [], 0
+    for lead in leads:
+        n = math.prod(lead)
+        out.append(y[at:at + n].reshape(lead + y.shape[1:]))
+        at += n
+    return out
+
+
+def _joined(fn, xs, counts):
+    """``fn(x, count) -> (y, stats)`` over the rows of every part at once
+    (one call, whatever the parts' shapes); one part goes as it is."""
+    if len(xs) == 1:
+        y, stats = fn(xs[0], counts[0])
+        return [y], stats
+    E = xs[0].shape[-1]
+    y, stats = fn(jnp.concatenate([x.reshape(-1, E) for x in xs]),
+                  jnp.concatenate([c.reshape(-1) for c in counts]))
+    return _split_rows(y, [x.shape[:-1] for x in xs]), stats

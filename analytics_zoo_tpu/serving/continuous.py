@@ -68,16 +68,16 @@ logger = logging.getLogger("analytics_zoo_tpu")
 
 
 # the flight record's fields of a model with a sparse-attention indexer,
-# in the order the step program returns them (docs/observability.md)
+# in the order the step program returns them (docs/observability.md);
+# those of a model with state-space layers are its ``counter_names``
+# (models/hybrid_lm.py's ``HYBRID_COUNTERS`` or ``LATENT_COUNTERS``); of
+# every such tuple the last is a maximum and the others are sums
 DSA_COUNTERS = ("dsa_ctx_tokens", "dsa_read_tokens", "moe_assignments",
                 "moe_max_load")
-# booked beside them by the engine: the tick's ``moe_assignments`` where
+# booked beside either by the engine: the tick's ``moe_assignments`` where
 # its program passed the decode rows and the chunk through ONE expert pass
-# (``fused_step_paged_sparse``), else 0
-DSA_SHARED = "moe_shared_assignments"
-# (those of a model with state-space layers are its ``counter_names``:
-# models/hybrid_lm.py's ``HYBRID_COUNTERS`` or ``LATENT_COUNTERS``; of
-# every such tuple the last is a maximum and the others are sums)
+# (``fused_step_paged_sparse``, ``fused_step_paged_ssm``), else 0
+MOE_SHARED = "moe_shared_assignments"
 
 
 def _zeros_like(x):
@@ -425,7 +425,7 @@ class ContinuousEngine:
                                else model.counter_names if self._ssm
                                else ())
         self._counter_fields = self._counter_names + (
-            (DSA_SHARED,) if self._dsa else ())
+            (MOE_SHARED,) if self._counter_names else ())
         self._counts = dict.fromkeys(self._counter_fields, 0)
         self._passes = 0
         # validate cache_dtype EAGERLY with a serving-level message — a
@@ -1327,24 +1327,29 @@ class ContinuousEngine:
             a ``HybridCache``; the chunk rows name their slots (``cslots``,
             after the llama operands; a padding row's is out of range) so
             that each reads its own state and writes it back; the tick's
-            counters come back after ``cnxt``.  A PREFILLING row is frozen
-            in the decode half, so its state moves in the chunk half
-            alone."""
-            stats = jnp.zeros((len(self._counter_names),), jnp.int32)
+            counters come back after ``cnxt``.  With decode rows the tick
+            is ONE pass (``fused_step_paged_ssm``): each layer's
+            row-by-row matrices take the decode rows and the chunk rows in
+            one call, so a tick reads those weights once.  A PREFILLING
+            row is frozen among the decode rows, so its state moves in the
+            chunk rows alone."""
             if with_decode:
-                logits, pk, pv, stats = model.apply(
+                logits, clog, pk, pv, stats = model.apply(
                     variables, tok, pk, pv, tables, pos,
-                    dsa_live(done, tables), kernel=kern,
-                    method=HybridLM.decode_step_paged_ssm)
+                    dsa_live(done, tables), ctoks, ctabs, cpos, clens,
+                    cslots, kernel=kern,
+                    method=HybridLM.fused_step_paged_ssm)
                 nxt, done = pick_next(logits, pos, done, temps, seeds,
                                       topps, use_sample, use_topp)
                 pos = jnp.minimum(pos + 1, Lmax - 1)
-            else:
-                nxt = tok
-            wpos = (cpos + jnp.minimum(jnp.min(nxt), 0) if with_decode
-                    else cpos)          # orders the writes: fused_paged_fn
+                cnxt, _ = pick_next(
+                    clog, cpos + clens - 1,
+                    jnp.zeros(clens.shape, jnp.bool_), ctemps, cseeds,
+                    ctopps, use_sample, use_topp)
+                return nxt, pos, done, cnxt, stats, pk, pv
+            stats = jnp.zeros((len(self._counter_names),), jnp.int32)
             clog, pk, pv, cstats = model.apply(
-                variables, ctoks, pk, pv, ctabs, wpos, clens, cslots,
+                variables, ctoks, pk, pv, ctabs, cpos, clens, cslots,
                 kernel=kern, method=HybridLM.prefill_chunk_paged_ssm)
             cnxt, _ = pick_next(
                 clog, cpos + clens - 1,
@@ -1352,7 +1357,7 @@ class ContinuousEngine:
                 ctopps, use_sample, use_topp)
             stats = jnp.concatenate([stats[:-1] + cstats[:-1],
                                      jnp.maximum(stats[-1:], cstats[-1:])])
-            return nxt, pos, done, cnxt, stats, pk, pv
+            return tok, pos, done, cnxt, stats, pk, pv
 
         # one program per (with_decode, sampled, topp, read_len) —
         # read_len only varies on the arena path (O(log L) buckets)
@@ -4252,12 +4257,15 @@ class ContinuousEngine:
         if self._counter_names:
             nxt, pos2, done2, cnxt, counts = jax.device_get(
                 (nxt, pos2, done2, cnxt, dsa[0]))
-            self._note_counters(counts, 1 + with_decode)
-            if self._dsa and with_decode:
+            # one pass over the model: the chunk's alone, or the decode
+            # rows' and the chunk's together
+            self._note_counters(counts)
+            if with_decode:
                 # both kinds of row went through ONE expert pass
-                # (fused_paged_dsa_fn): all of the tick's picks
-                self._counts[DSA_SHARED] += int(
-                    counts[DSA_COUNTERS.index("moe_assignments")])
+                # (fused_paged_dsa_fn, fused_paged_ssm_fn): all of the
+                # tick's picks
+                self._counts[MOE_SHARED] += int(
+                    counts[self._counter_names.index("moe_assignments")])
         else:
             nxt, pos2, done2, cnxt = jax.device_get(
                 (nxt, pos2, done2, cnxt))

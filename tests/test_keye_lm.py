@@ -335,7 +335,7 @@ def test_fused_tick_books_its_shared_expert_pass(fam):
 def test_llama_flight_record_gains_nothing():
     from analytics_zoo_tpu.models import TransformerLM
     from analytics_zoo_tpu.serving.continuous import (DSA_COUNTERS,
-                                                      DSA_SHARED,
+                                                      MOE_SHARED,
                                                       ContinuousEngine)
 
     model = TransformerLM(vocab_size=32, hidden_size=32, num_layers=2,
@@ -347,7 +347,7 @@ def test_llama_flight_record_gains_nothing():
     eng.submit("a", np.arange(1, 7, dtype=np.int32))
     eng.drain()
     recs = eng.flight.snapshot()
-    assert recs and not any(set(DSA_COUNTERS + (DSA_SHARED,)) & set(r)
+    assert recs and not any(set(DSA_COUNTERS + (MOE_SHARED,)) & set(r)
                             for r in recs)
 
 
