@@ -323,6 +323,11 @@ class ContinuousEngine:
         # the cycle's lap clock (telemetry.LapClock): step() and its
         # tick paths name every phase they pass through
         self._lap = self.telemetry.clock.lap
+        # the process's fan-out threads (telemetry.FanoutClock), read at
+        # each tick into the flight record: a serving job gives every
+        # replica's telemetry its own clock; an engine with no front
+        # door reads one where no thread books, and records zeros
+        self.fanout = self.telemetry.fanout.cursor()
         # called once per device call, right after it is enqueued and
         # before the host waits for its result (_dispatched): the
         # serving pump hangs its token flush here, so that the broker's
@@ -3729,9 +3734,10 @@ class ContinuousEngine:
         dur = clock.lap("admit") - t0   # every tick path ends in _admit()
         phases, folded = clock.take()
         samples = self._tick_samples(n)
-        self.telemetry.tick(t0, dur, samples, phases, folded)
+        fanout = self.fanout.take()
+        self.telemetry.tick(t0, dur, samples, phases, folded, fanout)
         if self.flight is not None:
-            self._flight_record(t0, dur, samples, phases)
+            self._flight_record(t0, dur, samples, phases, fanout)
         return n
 
     def _fault_tick(self) -> None:
@@ -3790,7 +3796,7 @@ class ContinuousEngine:
         return samples
 
     def _flight_record(self, ts: float, dur: float,
-                       samples: dict, phases) -> None:
+                       samples: dict, phases, fanout: dict) -> None:
         """Append one tick snapshot to the flight ring: the telemetry
         samples plus resident row sets, tick kind, and the per-tick
         DELTAS of every cumulative counter an incident reader wants on
@@ -3815,6 +3821,9 @@ class ContinuousEngine:
         rec["phases"] = [[name, round(wall * 1e3, 4), round(cpu * 1e3, 4)]
                          for name, wall, cpu in phases]
         rec["kind"] = self._tick_kind
+        # the fan-out threads' CPU, wakes, events and token lag since
+        # this engine's previous record (telemetry.FanoutCursor)
+        rec.update(fanout)
         # which read path / storage mode this tick ran on — a bundle
         # reader's first question when a regression bisects to config
         rec["kernel"] = self.kernel if self.paged else "dense"

@@ -71,7 +71,7 @@ from analytics_zoo_tpu.serving.policy import (brownout_admit,
 from analytics_zoo_tpu.serving.queues import (
     BacklogFull, ImageBytes, InputQueue, OutputQueue)
 from analytics_zoo_tpu.serving.telemetry import (
-    MetricsRegistry, WindowHistogram, render_prometheus)
+    FanoutClock, MetricsRegistry, WindowHistogram, render_prometheus)
 
 
 def _decode_value(v):
@@ -165,6 +165,10 @@ class HttpFrontend:
         self.c_rejected = self.registry.counter(
             "zoo_http_backpressure_rejections_total",
             "requests answered 429 under a full admission queue")
+        # the stream writers' fan-out accounts: the serving job's clock
+        # (the process's), or one of the frontend's own when detached
+        self.fanout = getattr(getattr(serving, "telemetry", None),
+                              "fanout", None) or FanoutClock()
         self.c_disconnects = self.registry.counter(
             "zoo_http_stream_disconnects_total",
             "SSE clients that disconnected mid-stream (each triggers "
@@ -459,7 +463,10 @@ class HttpFrontend:
                 """Tail the request's token stream onto the socket as
                 SSE.  A failed write means the client hung up: cancel
                 the request so its KV blocks free NOW, not at the TTL
-                prune."""
+                prune.  The thread writes on its fan-out account: a wake
+                per stream read that brought entries, an event per
+                event written, and the pump's stamp on a token against
+                the clock after its ``flush()``."""
                 inq, outq = pair
                 self.send_response(200)
                 self.send_header("Content-Type", "text/event-stream")
@@ -469,15 +476,18 @@ class HttpFrontend:
                 self.end_headers()
                 self.close_connection = True
                 clean = False
+                acct = frontend.fanout.open("door")
                 try:
                     self.wfile.write(sse_event("start", {"uri": uri}))
                     self.wfile.flush()
                     for ev in outq.stream_events(
-                            uri, timeout=frontend.timeout):
+                            uri, timeout=frontend.timeout, account=acct):
                         if "ping" in ev:
                             # heartbeat: touches the socket so a dead
                             # client surfaces between tokens
                             self.wfile.write(b": ping\n\n")
+                            self.wfile.flush()
+                            continue
                         elif "token" in ev:
                             self.wfile.write(sse_event(
                                 "token", {"index": ev["index"],
@@ -512,6 +522,7 @@ class HttpFrontend:
                                 kind, {"uri": uri, "error": err}))
                             clean = True
                         self.wfile.flush()
+                        acct.wrote(ev.get("stamp"))
                         if clean:
                             break
                 except (BrokenPipeError, ConnectionResetError,
@@ -530,6 +541,8 @@ class HttpFrontend:
                     except OSError:
                         pass
                     clean = True
+                finally:
+                    frontend.fanout.close(acct)
                 if clean:
                     frontend._release(pair)
                 else:

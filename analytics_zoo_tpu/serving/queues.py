@@ -199,7 +199,7 @@ class OutputQueue:
         return decode_ndarray(fields["value"])
 
     def stream_events(self, uri: str, timeout: float = 30.0,
-                      poll_s: float = 1.0):
+                      poll_s: float = 1.0, account=None):
         """Tail the per-token stream of a ``stream=True`` request.
 
         Yields dicts in emission order: ``{"token": t, "index": i}``
@@ -217,7 +217,10 @@ class OutputQueue:
         resets to 0 and the generation re-streams from the start —
         consumers must discard buffered tokens, never splice.
         Raises ``TimeoutError`` when no event lands for ``timeout``
-        seconds."""
+        seconds.  A token event whose entry carries the pump's flush
+        stamp (field ``s``, µs of ``time.monotonic()``) has it under
+        ``"stamp"``.  ``account`` (a ``telemetry.FanoutAccount``) books
+        a wake for each read that returned entries."""
         key = TOKEN_PREFIX + uri
         last = b"0-0"
         next_index = 0
@@ -235,6 +238,8 @@ class OutputQueue:
             if not resp:
                 yield {"ping": True}
                 continue
+            if account is not None:
+                account.wake()
             for eid, flat in resp[0][1]:
                 last = eid
                 f = {flat[i].decode(): flat[i + 1]
@@ -252,7 +257,10 @@ class OutputQueue:
                         continue
                     next_index = idx + 1
                     deadline = time.monotonic() + timeout
-                    yield {"token": int(f["t"]), "index": idx}
+                    ev = {"token": int(f["t"]), "index": idx}
+                    if "s" in f:
+                        ev["stamp"] = int(f["s"])
+                    yield ev
                 elif "done" in f:
                     self.client.execute("DEL", key)
                     yield {"done": True}

@@ -21,6 +21,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from analytics_zoo_tpu.serving.telemetry import FanoutAccount, FanoutClock
+
 
 # ---------------------------------------------------------------------------
 # RESP2 encoding
@@ -29,6 +31,8 @@ from typing import Dict, List, Optional, Tuple
 def encode(obj) -> bytes:
     """Python -> RESP2: bytes/str -> bulk, int -> integer, list -> array,
     None -> null bulk, Exception -> error, bool ok-marker via _OK."""
+    if type(obj) is bytes:      # first: most of what a stream carries
+        return b"$%d\r\n%s\r\n" % (len(obj), obj)
     if obj is None:
         return b"$-1\r\n"
     if isinstance(obj, _OK):
@@ -44,8 +48,7 @@ def encode(obj) -> bytes:
     if isinstance(obj, (bytes, bytearray)):
         return b"$" + str(len(obj)).encode() + b"\r\n" + bytes(obj) + b"\r\n"
     if isinstance(obj, (list, tuple)):
-        out = b"*" + str(len(obj)).encode() + b"\r\n"
-        return out + b"".join(encode(x) for x in obj)
+        return b"*%d\r\n" % len(obj) + b"".join([encode(x) for x in obj])
     raise TypeError(f"cannot RESP-encode {type(obj)}")
 
 
@@ -55,27 +58,38 @@ class _OK:
 
 
 class _Reader:
-    """Buffered RESP2 parser over a socket."""
+    """Buffered RESP2 parser over a socket.  ``pos`` is where parsing has
+    got to in ``buf``: the parsed head is cut off when bytes arrive, not
+    at every line, so a pipeline of N commands in one buffer costs O(N)
+    and not O(N²) copies."""
 
     def __init__(self, sock: socket.socket):
         self.sock = sock
         self.buf = b""
+        self.pos = 0
+
+    def _recv(self) -> bytes:
+        chunk = self.sock.recv(65536)
+        if not chunk:
+            raise ConnectionError("peer closed")
+        return chunk
+
+    def _fill(self) -> None:
+        self.buf = self.buf[self.pos:] + self._recv()
+        self.pos = 0
 
     def _read_until(self, n: Optional[int] = None) -> bytes:
         if n is None:  # read a \r\n-terminated line
-            while b"\r\n" not in self.buf:
-                chunk = self.sock.recv(65536)
-                if not chunk:
-                    raise ConnectionError("peer closed")
-                self.buf += chunk
-            line, self.buf = self.buf.split(b"\r\n", 1)
+            i = self.buf.find(b"\r\n", self.pos)
+            while i < 0:
+                self._fill()
+                i = self.buf.find(b"\r\n", self.pos)
+            line, self.pos = self.buf[self.pos:i], i + 2
             return line
-        while len(self.buf) < n + 2:
-            chunk = self.sock.recv(65536)
-            if not chunk:
-                raise ConnectionError("peer closed")
-            self.buf += chunk
-        data, self.buf = self.buf[:n], self.buf[n + 2:]
+        while len(self.buf) - self.pos < n + 2:
+            self._fill()
+        data = self.buf[self.pos:self.pos + n]
+        self.pos += n + 2
         return data
 
     def read(self):
@@ -94,6 +108,20 @@ class _Reader:
             n = int(rest)
             return None if n == -1 else [self.read() for _ in range(n)]
         raise ValueError(f"bad RESP type byte {t!r}")
+
+
+class _ServerReader(_Reader):
+    """The broker's side of a connection: a ``recv`` that returned
+    bytes is a wake of the connection thread (its fan-out account)."""
+
+    def __init__(self, sock: socket.socket, acct: FanoutAccount):
+        super().__init__(sock)
+        self.acct = acct
+
+    def _recv(self) -> bytes:
+        chunk = super()._recv()
+        self.acct.wake()
+        return chunk
 
 
 class RedisError(Exception):
@@ -171,16 +199,21 @@ def _scan_read_opts(args: List[bytes], i: int):
     return count, block_ms, i
 
 
-def _await_fresh(s: "Stream", block_ms, select):
+def _await_fresh(s: "Stream", block_ms, select,
+                 acct: Optional[FanoutAccount] = None):
     """Run `select()` under s.cond until it yields entries or the block
     window expires.  select() may mutate claim state (XREADGROUP) — it is
-    always called with the stream lock held, so claims are atomic."""
+    always called with the stream lock held, so claims are atomic.  A
+    read that blocked and was woken with entries is a wake of ``acct``."""
     deadline = None if block_ms is None else \
         time.monotonic() + block_ms / 1000.0
+    waited = False
     while True:
         with s.cond:
             got = select()
             if got:
+                if waited and acct is not None:
+                    acct.wake()
                 return got
             if deadline is None:
                 return None
@@ -188,13 +221,22 @@ def _await_fresh(s: "Stream", block_ms, select):
             if remaining <= 0:
                 return None
             s.cond.wait(remaining)
+            waited = True
 
 
 class RespServer:
-    """In-process broker. start() binds 127.0.0.1:port (0 = ephemeral)."""
+    """In-process broker. start() binds 127.0.0.1:port (0 = ephemeral).
 
-    def __init__(self, port: int = 0):
+    Each connection thread has an account on ``fanout`` (the serving
+    process's :class:`~analytics_zoo_tpu.serving.telemetry.FanoutClock`
+    when a serving job owns the broker): its CPU and its wakes — a
+    ``recv`` that returned bytes, a blocked stream read woken by an
+    ``XADD``."""
+
+    def __init__(self, port: int = 0,
+                 fanout: Optional[FanoutClock] = None):
         self.port = port
+        self.fanout = fanout if fanout is not None else FanoutClock()
         self.streams: Dict[bytes, Stream] = {}
         self.hashes: Dict[bytes, Dict[bytes, bytes]] = {}
         self.kv: Dict[bytes, bytes] = {}
@@ -235,7 +277,8 @@ class RespServer:
                              daemon=True).start()
 
     def _serve_conn(self, conn: socket.socket):
-        reader = _Reader(conn)
+        acct = self.fanout.open("broker")
+        reader = _ServerReader(conn, acct)
         try:
             while not self._stop.is_set():
                 req = reader.read()
@@ -244,7 +287,7 @@ class RespServer:
                 try:
                     resp = self._dispatch([bytes(x) if isinstance(
                         x, (bytes, bytearray)) else str(x).encode()
-                        for x in req])
+                        for x in req], acct)
                 except RedisError as e:
                     resp = e
                 except Exception as e:  # command bug -> error reply
@@ -254,6 +297,7 @@ class RespServer:
             pass
         finally:
             conn.close()
+            self.fanout.close(acct)
 
     # ---- commands -----------------------------------------------------
 
@@ -263,7 +307,8 @@ class RespServer:
                 self.streams[key] = Stream()
             return self.streams[key]
 
-    def _dispatch(self, args: List[bytes]):
+    def _dispatch(self, args: List[bytes],
+                  acct: Optional[FanoutAccount] = None):
         cmd = args[0].upper()
         if cmd == b"PING":
             return _OK("PONG")
@@ -372,7 +417,7 @@ class RespServer:
                 fresh = s.after(last)
                 return fresh[:count] if count else fresh
 
-            got = _await_fresh(s, block_ms, select)
+            got = _await_fresh(s, block_ms, select, acct)
             if got is None:
                 return None
             return [[key, [[eid, fv] for eid, fv in got]]]
@@ -419,7 +464,7 @@ class RespServer:
                     g["pending"][eid] = consumer
                 return fresh
 
-            got = _await_fresh(s, block_ms, select)
+            got = _await_fresh(s, block_ms, select, acct)
             if got is None:
                 return None
             return [[key, [[eid, fv] for eid, fv in got]]]

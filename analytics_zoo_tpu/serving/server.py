@@ -416,9 +416,18 @@ class ClusterServing:
         if self.config.core_number is not None:
             inference_model.set_concurrency(self.config.core_number)
         self.engine = None      # continuous-batching engine (start())
+        # job-level telemetry; continuous mode hands this same facade
+        # to the engine, so one registry carries zoo_serving_* AND
+        # zoo_engine_* metrics and the event ring interleaves engine
+        # spans with serving-side terminal events (abandonment).  Its
+        # fan-out clock is the process's: the embedded broker's
+        # connection threads and the front door's stream writers book
+        # there, and every engine reads it with a cursor of its own
+        self.telemetry = Telemetry()
         self.broker: Optional[RespServer] = None
         if embedded_broker:
-            self.broker = RespServer(port=0).start()
+            self.broker = RespServer(
+                port=0, fanout=self.telemetry.fanout).start()
             self.config.redis_host = "127.0.0.1"
             self.config.redis_port = self.broker.port
         self.port = self.config.redis_port
@@ -438,11 +447,6 @@ class ClusterServing:
             collections.OrderedDict()
         self.stats = {"requests": 0, "batches": 0, "batch_fill": 0.0,
                       "predict_ms": 0.0}
-        # job-level telemetry; continuous mode hands this same facade
-        # to the engine, so one registry carries zoo_serving_* AND
-        # zoo_engine_* metrics and the event ring interleaves engine
-        # spans with serving-side terminal events (abandonment)
-        self.telemetry = Telemetry()
         self._register_serving_gauges()
         # ---- incident pillar (serving/flight.py) -----------------------
         # SLO watchdog fed from the shared telemetry's request hooks;
@@ -546,7 +550,9 @@ class ClusterServing:
         self.flights = [self.flight]
         self.anomaly_monitors = [self.anomalies]
         for r in range(1, self.n_replicas):
-            tm = Telemetry()
+            # the fan-out clock is the process's: every replica's engine
+            # reads the one the broker and the front door book on
+            tm = Telemetry(fanout=self.telemetry.fanout)
             wd = SloWatchdog(self.config.slo_policy(),
                              registry=tm.metrics)
             tm.watchdog = wd
@@ -976,6 +982,8 @@ class ClusterServing:
         self._stop.set()
         for t in getattr(self, "_threads", []):
             t.join(timeout=5)
+        for eng in self.engines:
+            eng.fanout.close()
         if self.broker is not None:
             self.broker.stop()
         self._decode_pool.shutdown(wait=False)
@@ -1585,7 +1593,10 @@ class ClusterServing:
                        wait: bool = False) -> int:
         """Publish every token/terminal event buffered since the last
         flush in ONE pipeline — per-step, never per-token — and return
-        how many went.  The pipeline is written and NOT waited for: its
+        how many went.  Each token event carries the flush's stamp
+        (field ``s``: µs of ``time.monotonic()``, read once a flush),
+        from which the front door books the fan-out's delay on it.
+        The pipeline is written and NOT waited for: its
         replies are read by the next flush that has something to send,
         a device step later (if the broker has not answered by then,
         that wait is the back-pressure that bounds the backlog to one
@@ -1602,11 +1613,13 @@ class ClusterServing:
         if not batch:
             return 0
         cmds = []
+        stamp = b"%d" % (time.monotonic() * 1e6)
         for uri, events in batch:
             key = TOKEN_PREFIX + uri
             for kind, idx, val in events:
                 if kind == "tok":
-                    cmds.append(("XADD", key, "*", "i", idx, "t", val))
+                    cmds.append(("XADD", key, "*", "i", idx, "t", val,
+                                 b"s", stamp))
                 elif kind == "done":
                     cmds.append(("XADD", key, "*", "done", "1"))
                 elif kind == "cancelled":

@@ -1,11 +1,11 @@
 """Engine-wide telemetry: metrics registry, span event log, exports.
 
-The serving stack grew three ad-hoc metric paths — the engine's
-``cache_metrics()`` dict, an opt-in per-request stamp store, and the
-HTTP frontend's private ``_Percentiles`` window.  None of them can answer the operational
+The serving stack grew ad-hoc metric paths — the engine's
+``cache_metrics()`` dict and the HTTP frontend's private
+``_Percentiles`` window.  Neither can answer the operational
 questions the ROADMAP's scale-out items need (route on pool pressure,
 shed on queue depth, alert on TTFT p99) from OUTSIDE the process.
-This module is the one substrate behind all three, plus the export
+This module is the one substrate behind both, plus the export
 surfaces:
 
 - :class:`MetricsRegistry` — always-on counters, callback gauges and
@@ -18,12 +18,16 @@ surfaces:
 - :class:`LapClock` — the pump thread's lap clock: every millisecond
   of the engine's cycle (end of step N-1 to end of step N) booked to a
   named phase, wall and CPU, with no remainder.
+- :class:`FanoutClock` — the same clock for the threads that carry a
+  token from the pump to a client (the broker's connection threads,
+  the front door's stream writers): CPU, wake-ups, events written and
+  the fan-out's delay on a token, one account per thread, read by
+  each engine into its flight record.
 - :class:`Telemetry` — the per-engine facade: request-lifecycle hooks
   (enqueued → admitted → first token → finished/preempted/errored)
   feed TTFT / inter-token-gap / queue-wait histograms and lifecycle
   spans from ONE ``time.monotonic()`` stamp per event, so the rolling
-  metrics, the Perfetto timeline and the per-request stamp store can
-  never disagree.
+  metrics and the Perfetto timeline can never disagree.
 
 Design constraints (enforced by tier-1):
 
@@ -47,7 +51,9 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "WindowHistogram", "MetricsRegistry",
-           "EventLog", "LapClock", "PHASES", "Telemetry",
+           "EventLog", "LapClock", "PHASES", "FANOUT_CPU_READ_S",
+           "FANOUT_KINDS", "FanoutAccount", "FanoutClock", "FanoutCursor",
+           "Telemetry",
            "render_prometheus", "validate_chrome_trace"]
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
@@ -486,6 +492,191 @@ class LapClock:
         return out
 
 
+# ---- fan-out clock (the threads between the pump and a client) -------
+
+#: The kinds of fan-out thread: ``broker`` — a connection thread of the
+#: embedded RESP broker (``resp.RespServer``); ``door`` — the front
+#: door's thread that writes one SSE stream (``http_frontend``).
+FANOUT_KINDS = ("broker", "door")
+
+#: A fan-out thread reads its CPU clock at a wake only where this many
+#: seconds of wall have passed since its previous reading.  A reading of
+#: ``time.thread_time()`` is a system call: about 6 µs on the TPU host
+#: this repo is measured on, where the clock itself moves in steps of
+#: 10 ms, against 0.1 µs for the ``time.monotonic()`` that gates it.
+FANOUT_CPU_READ_S = 0.5
+
+
+#: The fields of a slot, one column each: CPU seconds, wakes, events
+#: written, summed lag seconds, stamped tokens written.
+_CPU, _WAKES, _EVENTS, _LAG_SUM, _LAG_N = range(5)
+
+
+class FanoutAccount:
+    """One fan-out thread's slot in a :class:`FanoutClock`: a row of the
+    clock's columns for its kind, written by that thread alone, with no
+    lock.
+
+    ``wake()`` is called at each return from a blocking wait that
+    brought work; it counts the wake and, where ``FANOUT_CPU_READ_S``
+    of wall have passed since the thread's previous reading, reads
+    ``time.thread_time()`` once and books all CPU since then, as the
+    lap clock does (a blocked thread burns none).  ``wrote(stamp_us)``
+    counts one event written to a client and, where the event carries
+    the pump's flush stamp (µs of ``time.monotonic()``), books the
+    fan-out's delay on it with one ``time.monotonic()`` reading, into
+    the sum and into each engine cursor's running maximum.  The maximum
+    is best-effort: a lag booked in the instant between a cursor's read
+    of its row and the reset of that row is lost."""
+
+    __slots__ = ("kind", "row", "_cols", "_clock", "_c", "_t")
+
+    def __init__(self, kind: str, row: int, cols: tuple,
+                 clock: "FanoutClock"):
+        self.kind, self.row, self._cols, self._clock = kind, row, cols, clock
+        self._c = time.thread_time()
+        self._t = time.monotonic()
+
+    def wake(self) -> None:
+        cols, i = self._cols, self.row
+        cols[_WAKES][i] += 1
+        t = time.monotonic()
+        if t - self._t < FANOUT_CPU_READ_S:
+            return
+        self._t = t
+        c = time.thread_time()
+        cols[_CPU][i] += c - self._c
+        self._c = c
+
+    def wrote(self, stamp_us: Optional[int] = None) -> None:
+        cols, i = self._cols, self.row
+        cols[_EVENTS][i] += 1
+        if stamp_us is None:
+            return
+        lag = time.monotonic() - stamp_us * 1e-6
+        cols[_LAG_SUM][i] += lag
+        cols[_LAG_N][i] += 1
+        for m in self._clock._peaks:
+            if lag > m[i]:
+                m[i] = lag
+
+
+class FanoutClock:
+    """The serving process's fan-out threads on one account.  Each kind
+    keeps one list a field with one entry a live thread (``open(kind)``
+    gives a thread its row), so that an engine's read is a handful of
+    ``sum()`` calls and not a walk over slot objects; a thread that ends
+    (``close(account)``) folds its row into its kind's totals under the
+    lock, once a thread, and leaves the row to the next thread.  Each
+    engine cursor has its own list of the largest lag a door row booked
+    since that cursor's previous read; a cursor that closes drops it.
+    It belongs to the process (the serving job's :class:`Telemetry`),
+    not to a replica.  Always on: there is no switch."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._cols = {k: ([], [], [], [], []) for k in FANOUT_KINDS}
+        self._free = {k: [] for k in FANOUT_KINDS}
+        self._ended = {k: [0.0, 0, 0, 0.0, 0] for k in FANOUT_KINDS}
+        # one list a cursor, an entry a door row; the tuple is replaced,
+        # never changed, so a writer may walk the one it read
+        self._peaks: Tuple[List[float], ...] = ()
+
+    def open(self, kind: str) -> FanoutAccount:
+        """A slot for the calling thread; its CPU baseline is read now."""
+        if kind not in FANOUT_KINDS:
+            raise ValueError(f"fan-out kind {kind!r} not in {FANOUT_KINDS}")
+        cols = self._cols[kind]
+        with self._lock:
+            if self._free[kind]:
+                row = self._free[kind].pop()
+            else:
+                row = len(cols[0])
+                for col, zero in zip(cols, (0.0, 0, 0, 0.0, 0)):
+                    col.append(zero)
+                if kind == "door":
+                    for m in self._peaks:
+                        m.append(0.0)
+            return FanoutAccount(kind, row, cols, self)
+
+    def close(self, acct: FanoutAccount) -> None:
+        """The calling thread (the slot's own) is done: book its CPU
+        since its last reading and fold its row into its kind's totals.
+        The row's lag maxima stay until each cursor has read them."""
+        cols, i = acct._cols, acct.row
+        cols[_CPU][i] += time.thread_time() - acct._c
+        with self._lock:
+            e = self._ended[acct.kind]
+            for f, (col, zero) in enumerate(zip(cols, (0.0, 0, 0, 0.0,
+                                                       0))):
+                e[f] += col[i]
+                col[i] = zero
+            self._free[acct.kind].append(i)
+
+    def cursor(self) -> "FanoutCursor":
+        """A reader of its own: each read returns what the process's
+        fan-out did since this cursor's previous read."""
+        with self._lock:
+            peaks = [0.0] * len(self._cols["door"][0])
+            self._peaks += (peaks,)
+        return FanoutCursor(self, peaks)
+
+    def drop(self, peaks: List[float]) -> None:
+        """Stop keeping a closed cursor's lag maxima."""
+        with self._lock:
+            self._peaks = tuple(m for m in self._peaks if m is not peaks)
+
+    def totals(self, peaks: Optional[List[float]] = None):
+        """Per kind ``[cpu_s, wakes, events, lag_sum_s, lag_n]`` over the
+        live and the ended slots; with a cursor's ``peaks``, also the
+        largest lag since that cursor's previous read, its maxima reset
+        in place (a door thread may be writing into the same list)."""
+        with self._lock:
+            out = {k: [e + sum(col) for e, col in
+                       zip(self._ended[k], self._cols[k])]
+                   for k in FANOUT_KINDS}
+            peak = 0.0
+            if peaks is not None:
+                peak = max(peaks, default=0.0)
+                peaks[:] = [0.0] * len(peaks)
+        return out, peak
+
+
+class FanoutCursor:
+    """An engine's cursor into a :class:`FanoutClock`: ``take()`` gives
+    the flight record's ``fanout_*`` fields, the process's fan-out since
+    this cursor's previous take, so no record of one engine counts a
+    delta twice.  It starts at the clock's totals when it is made;
+    ``close()`` when its engine stops."""
+
+    def __init__(self, clock: FanoutClock, peaks: List[float]):
+        self.clock, self._peaks = clock, peaks
+        tot, _ = clock.totals(peaks)
+        self._last = self._flat(tot)
+
+    @staticmethod
+    def _flat(tot) -> Tuple[float, float, int, int, float, int]:
+        b, d = tot["broker"], tot["door"]
+        return (b[0], d[0], b[1] + d[1], b[2] + d[2], b[3] + d[3],
+                b[4] + d[4])
+
+    def close(self) -> None:
+        self.clock.drop(self._peaks)
+
+    def take(self) -> Dict[str, float]:
+        tot, peak = self.clock.totals(self._peaks)
+        cur = self._flat(tot)
+        d = [c - p for c, p in zip(cur, self._last)]
+        self._last = cur
+        return {"fanout_broker_cpu_ms": round(d[0] * 1e3, 4),
+                "fanout_door_cpu_ms": round(d[1] * 1e3, 4),
+                "fanout_wakes": d[2],
+                "fanout_events": d[3],
+                "fanout_lag_ms_sum": round(d[4] * 1e3, 4),
+                "fanout_lag_n": d[5],
+                "fanout_lag_ms_max": round(peak * 1e3, 4)}
+
+
 # ---- per-request lifecycle facade -------------------------------------
 
 class _Clock:
@@ -510,10 +701,7 @@ class Telemetry:
     """One instance per serving engine (shareable with the serving job
     that owns it): a :class:`MetricsRegistry`, an :class:`EventLog`,
     and the request-lifecycle helpers the engine's state transitions
-    call.  Always on — the opt-in part is only ``keep_request_stamps``
-    (the per-request raw stamp store that ``pop_request_stamps``
-    drains), because per-uri retention is unbounded where the
-    histograms are not.
+    call.  Always on, with no opt-in part.
 
     Metric-name convention: callers prefix by layer — ``zoo_engine_*``
     (ContinuousEngine), ``zoo_serving_*`` (ClusterServing),
@@ -522,12 +710,15 @@ class Telemetry:
     the catalog)."""
 
     def __init__(self, events_capacity: int = 65536,
-                 window: int = 8192, prefix: str = "zoo_engine_"):
+                 window: int = 8192, prefix: str = "zoo_engine_",
+                 fanout: Optional[FanoutClock] = None):
         self.metrics = MetricsRegistry()
         self.events = EventLog(events_capacity)
         self.clock = LapClock()
-        self.keep_request_stamps = False
-        self._stamps: Dict[str, dict] = {}
+        # the process's fan-out threads (FanoutClock): a serving job's
+        # own, which it hands to its broker, its front door and the
+        # telemetry of each replica, so that every engine reads it
+        self.fanout = fanout if fanout is not None else FanoutClock()
         self._clocks: Dict[str, _Clock] = {}
         self._lock = threading.Lock()
         # Optional SloWatchdog (serving/flight.py) fed from the request
@@ -626,6 +817,20 @@ class Telemetry:
         self.c_flush_overlapped = m.counter(
             p + "flush_events_overlapped_total",
             "token-stream events sent while a device call was in flight")
+        # the fan-out threads (FanoutClock), read lazily at scrape time
+        totals = self.fanout.totals
+        for kind in FANOUT_KINDS:
+            m.gauge(p + f"fanout_cpu_seconds_total_{kind}",
+                    f"CPU seconds the {kind} fan-out threads ran",
+                    fn=lambda k=kind: totals()[0][k][0], kind="counter")
+        m.gauge(p + "fanout_wakes_total",
+                "fan-out thread wake-ups that brought work",
+                fn=lambda: sum(t[1] for t in totals()[0].values()),
+                kind="counter")
+        m.gauge(p + "fanout_events_total",
+                "stream events the front door wrote to its clients",
+                fn=lambda: sum(t[2] for t in totals()[0].values()),
+                kind="counter")
         self.h_spec_accept = m.histogram(
             p + "spec_accept_len",
             "accepted draft tokens per row per verify round (0..k)",
@@ -646,8 +851,6 @@ class Telemetry:
         now = time.monotonic()
         with self._lock:
             self._clocks[uri] = _Clock(now)
-            if self.keep_request_stamps:
-                self._stamps[uri] = {"arrival": now, "token_times": []}
         self.c_submitted.inc()
         self.events.instant("enqueued", now, EventLog.TID_QUEUE,
                             {"uri": uri})
@@ -695,10 +898,6 @@ class Telemetry:
                 gap = now - ck.last_token
             ck.last_token = now
             ck.n_tokens += 1
-            if self.keep_request_stamps:
-                st = self._stamps.get(uri)
-                if st is not None:
-                    st["token_times"].append(now)
         self.c_tokens.inc()
         if first:
             self.h_ttft.record(now - ck.arrival)
@@ -739,8 +938,8 @@ class Telemetry:
     def req_preempted(self, uri: str, slot: int,
                       prefilling: bool = False) -> None:
         """Partial tokens are discarded and the request requeues: the
-        clock keeps its ORIGINAL arrival (TTFT spans the preemption,
-        like the stamp store) but forgets its token history, so
+        clock keeps its ORIGINAL arrival (TTFT spans the preemption)
+        but forgets its token history, so
         readmission re-records a first token."""
         now = time.monotonic()
         with self._lock:
@@ -750,10 +949,6 @@ class Telemetry:
                 ck.first_token = None
                 ck.last_token = None
                 ck.n_tokens = 0
-            if self.keep_request_stamps:
-                st = self._stamps.get(uri)
-                if st is not None:
-                    st["token_times"] = []
         self.c_preempted.inc()
         self.events.instant(
             "preempted", now, slot,
@@ -868,7 +1063,8 @@ class Telemetry:
     def tick(self, start: float, dur: float,
              samples: Dict[str, float],
              phases: Sequence[Tuple[str, float, float]] = (),
-             folded: int = 0) -> None:
+             folded: int = 0,
+             fanout: Optional[Dict[str, float]] = None) -> None:
         """One engine step: a span on the engine-loop track, a tick
         wall-time histogram sample, and a Perfetto counter track of
         the per-tick gauges (queue depth, row mix, free blocks, ...).
@@ -880,13 +1076,16 @@ class Telemetry:
         cumulative-seconds counters and, unless it is one of the
         ``folded`` leading sums, becomes a span on the engine-loop
         track — the in-step phases nest under the tick's span, the
-        pump's lie between two ticks."""
+        pump's lie between two ticks.  ``fanout`` (``FanoutCursor.
+        take()``) becomes one counter sample of its own."""
         self.c_ticks.inc()
         self.h_tick.record(dur)
         self.events.span("tick", start, dur, EventLog.TID_ENGINE,
                          samples or None)
         if samples:
             self.events.counter_sample("engine", samples, start)
+        if fanout:
+            self.events.counter_sample("fanout", fanout, start)
         for name, wall, cpu in phases:
             self.c_phase_wall[name].inc(wall)
             self.c_phase_cpu[name].inc(cpu)
@@ -957,20 +1156,6 @@ class Telemetry:
         or calls back."""
         self.events.instant("pool_" + kind, None, EventLog.TID_ENGINE,
                             info or None)
-
-    # -- per-request stamp store ---------------------------------------
-
-    def pop_request_stamps(self) -> Dict[str, dict]:
-        """Drain the raw per-request stamps kept while
-        ``keep_request_stamps`` is set: uri -> {"arrival": t,
-        "token_times": [t0, t1, ...]} (``time.monotonic()`` seconds).
-        TTFT = token_times[0] - arrival; TPOT = consecutive
-        token_times deltas.  The same hooks write the always-on
-        histograms, so the two surfaces agree by construction."""
-        with self._lock:
-            out = self._stamps
-            self._stamps = {}
-        return out
 
     # -- export --------------------------------------------------------
 

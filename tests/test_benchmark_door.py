@@ -16,7 +16,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.mark.parametrize("name", ["test_files.py", "test_flops.py",
-                                  "test_family.py"])
+                                  "test_family.py",
+                                  "test_fanout_readers.py"])
 def test_benchmark_contract(name):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(

@@ -194,6 +194,61 @@ class TestRespBroker:
         finally:
             srv.stop()
 
+    @pytest.mark.parametrize("chunk", [1, 2, 7, 64, 65536])
+    def test_reader_parses_a_pipeline_whatever_the_chunks(self, chunk):
+        """The parser keeps an offset into its buffer and cuts the head
+        off only when bytes arrive: a pipeline split at any byte (a
+        CRLF or a bulk string across two recvs) parses as one buffer."""
+        from analytics_zoo_tpu.serving.resp import (_encode_command,
+                                                    _Reader)
+        cmds = [("XADD", f"tok:{i}", "*", "i", i, "t", 7 * i, b"s",
+                 b"123456789") for i in range(40)]
+        cmds.append(("SET", "k", b"a\r\nb" * 3))
+        wire = b"".join(_encode_command(c) for c in cmds)
+
+        class Chunks:           # a socket whose recv returns `chunk` bytes
+            def __init__(self):
+                self.at = 0
+
+            def recv(self, n):
+                out = wire[self.at:self.at + min(n, chunk)]
+                self.at += len(out)
+                return out
+
+        rd = _Reader(Chunks())
+        got = [rd.read() for _ in cmds]
+        assert got == [[a if isinstance(a, bytes) else str(a).encode()
+                        for a in c] for c in cmds]
+        with pytest.raises(ConnectionError):
+            rd.read()
+
+    @pytest.mark.parametrize("obj,wire", [
+        (b"ab", b"$2\r\nab\r\n"),
+        (b"", b"$0\r\n\r\n"),
+        (bytearray(b"q"), b"$1\r\nq\r\n"),
+        ("x", b"$1\r\nx\r\n"),
+        (None, b"$-1\r\n"),
+        (7, b":7\r\n"),
+        (True, b":1\r\n"),
+        ([b"k", (b"1-0", [b"t", b"5"]), []],
+         b"*3\r\n$1\r\nk\r\n*2\r\n$3\r\n1-0\r\n*2\r\n$1\r\nt\r\n"
+         b"$1\r\n5\r\n*0\r\n"),
+    ])
+    def test_encode_writes_resp2(self, obj, wire):
+        """Bytes take the first branch and an array one join: the wire is
+        RESP2's, type by type, and parses back."""
+        from analytics_zoo_tpu.serving.resp import _Reader, encode
+        assert encode(obj) == wire
+
+        class Once:
+            def recv(self, n):
+                return wire
+
+        if not isinstance(obj, (bool, int, str, bytearray)):
+            assert _Reader(Once()).read() == (
+                [b"k", [b"1-0", [b"t", b"5"]], []] if isinstance(obj, list)
+                else obj)
+
 
 # ---------------------------------------------------------------------------
 # end-to-end: queues -> serving loop -> results
