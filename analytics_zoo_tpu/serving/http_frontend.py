@@ -6,7 +6,8 @@ enqueues them on the Redis input stream, awaits the result hash, and
 responds; optional TLS.
 
 Rebuild shape: stdlib ThreadingHTTPServer (one OS thread per in-flight
-request — the actor pool analog), per-thread RESP connections, and the
+request — the actor pool analog), pooled RESP connections, ONE thread
+that writes every SSE token stream (``_StreamHub``), and the
 reference's de-facto observability (queue depth + per-request latency)
 exposed as JSON gauges with p50/p90/p99.
 
@@ -47,12 +48,13 @@ from __future__ import annotations
 
 import base64
 import json
+import selectors
 import ssl
 import threading
 import time
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -69,9 +71,19 @@ from analytics_zoo_tpu.serving.frontdoor import (ThroughputEstimator,
 from analytics_zoo_tpu.serving.policy import (brownout_admit,
                                               brownout_classes)
 from analytics_zoo_tpu.serving.queues import (
-    BacklogFull, ImageBytes, InputQueue, OutputQueue)
+    TERMINALS, BacklogFull, ImageBytes, InputQueue, OutputQueue, TokenTail)
+from analytics_zoo_tpu.serving.resp import RespClient
 from analytics_zoo_tpu.serving.telemetry import (
-    FanoutClock, MetricsRegistry, WindowHistogram, render_prometheus)
+    FanoutAccount, FanoutClock, MetricsRegistry, WindowHistogram,
+    render_prometheus)
+
+#: The longest an SSE stream goes without a byte to its client: a
+#: ``: ping`` comment then touches the socket, so a client that hung up
+#: is found between tokens.  Also the longest block of the hub's read.
+_POLL_S = 1.0
+#: The most entries one stream brings to one read of the hub (``COUNT``
+#: applies a key).
+_READ_COUNT = 256
 
 
 def _decode_value(v):
@@ -115,6 +127,280 @@ class _Percentiles:
             "p90_ms": round(s["p90"] * 1e3, 3),
             "p99_ms": round(s["p99"] * 1e3, 3),
         }
+
+
+def _sse_frame(uri: str, ev: dict) -> bytes:
+    """A token-stream event (``queues.TokenTail.take``) as its SSE frame."""
+    if "token" in ev:
+        return sse_event("token", {"index": ev["index"],
+                                   "token": ev["token"]})
+    if "restart" in ev:
+        # crash-recovery redispatch: the token index resets to 0 and the
+        # generation re-streams — the client must drop what it buffered,
+        # never splice
+        return sse_event("restart", {"uri": uri, "attempt": ev["restart"]})
+    if "done" in ev:
+        return sse_event("done", {"uri": uri})
+    if "cancelled" in ev:
+        return sse_event("cancelled", {"uri": uri})
+    err = ev["error"]
+    # admission-time deadline sheds get their OWN terminal event so
+    # clients can tell "arrived too late" from a server-side failure
+    # without parsing text
+    kind = "deadline_exceeded" if "deadline_exceeded" in err else "error"
+    return sse_event(kind, {"uri": uri, "error": err})
+
+
+class _Tap:
+    """One SSE stream the hub writes: the client's socket (non-blocking),
+    the reader's place in the token stream, and the frames not yet
+    taken by the socket (``pending`` is what a send left, handed to the
+    next send unchanged, as a TLS socket requires).  A tap with frames
+    left is ``parked``: its key leaves the hub's read, so the broker
+    holds what the client has not taken, until the socket is writable
+    again.  ``done`` is set when the hub is through with it; ``outcome``
+    then says how: ``"clean"`` (its terminal, or the timeout's
+    ``error``, went out), ``"disconnect"`` (the client hung up, or took
+    nothing for the stream's timeout), or else a message for an
+    ``error`` event the handler writes itself."""
+
+    __slots__ = ("uri", "sock", "tail", "key", "deadline", "quiet_since",
+                 "out", "pending", "parked", "stalled_since", "ended",
+                 "outcome", "done")
+
+    def __init__(self, uri: str, sock, now: float, timeout: float):
+        self.uri, self.sock = uri, sock
+        self.tail = TokenTail(uri)
+        self.key = self.tail.key.encode()
+        self.deadline = now + timeout       # no stream event by then
+        self.quiet_since = now              # the last byte written
+        self.out: List[bytes] = []
+        self.pending: Optional[bytes] = None
+        self.parked = False
+        self.stalled_since: Optional[float] = None
+        self.ended = False
+        self.outcome = None
+        self.done = threading.Event()
+
+    def unsent(self) -> bytes:
+        return (self.pending or b"") + b"".join(self.out)
+
+
+class _StreamHub:
+    """The front door's one fan-out reader.  One thread follows every
+    live token stream with ONE ``XREAD COUNT 256 BLOCK … STREAMS <every
+    live key> <its last id>`` over one broker connection, and writes
+    each stream's events of a read to its client as one buffer in one
+    non-blocking send.  A handler thread hands it ``(uri, socket)`` with
+    :meth:`follow` and waits on the tap; the follow's ``XADD`` to the
+    hub's private control stream, which every read names, wakes the
+    read, so a new stream joins the next one.
+
+    The rules are a reader's (``queues.TokenTail``: dedup by index,
+    ``restart`` resets it, a terminal ends the stream and its key is
+    deleted), a ``: ping`` after ``poll_s`` with nothing written, an
+    ``error`` event after ``timeout`` with no event.  A client that
+    stops reading parks its tap (the others go on); one that takes
+    nothing for ``timeout`` counts as gone.  The thread books on one
+    ``door`` account of ``fanout``: a wake is a read that returned
+    entries, an event an event written, and a token's lag is its flush
+    stamp against the clock after its send.  It starts with the first
+    stream; after :meth:`stop` it ends when its last stream does; if it
+    fails, every stream it holds is handed back with the failure."""
+
+    def __init__(self, host: str, port: int, timeout: float,
+                 fanout: FanoutClock):
+        self._addr = (host, port)
+        self.timeout, self.poll_s = timeout, _POLL_S
+        self.fanout = fanout
+        self.ctl = f"door:{uuid.uuid4()}"
+        self._lock = threading.Lock()
+        self._joining: List[_Tap] = []
+        self._running = False
+        self._stopping = False
+
+    def follow(self, uri: str, sock, client: RespClient) -> _Tap:
+        """Hand the hub a stream whose head and ``start`` event are
+        written; ``sock`` must be non-blocking.  ``client`` carries the
+        wake-up ``XADD``."""
+        tap = _Tap(uri, sock, time.monotonic(), self.timeout)
+        with self._lock:
+            self._joining.append(tap)
+            start, self._running = not self._running, True
+        if start:
+            threading.Thread(target=self._run, name="zoo-http-stream-hub",
+                             daemon=True).start()
+        client.execute("XADD", self.ctl, "*", "j", "1")
+        return tap
+
+    def stop(self) -> None:
+        """End the thread once the streams it holds are over."""
+        with self._lock:
+            self._stopping = True
+
+    # ---- the thread ---------------------------------------------------
+
+    def _run(self) -> None:
+        acct = self.fanout.open("door")
+        taps: Dict[bytes, _Tap] = {}
+        sel = selectors.DefaultSelector()
+        client = None
+        failure = "the front door stopped"
+        try:
+            client = RespClient(*self._addr)
+            sel.register(client.sock, selectors.EVENT_READ)
+            ctl = self.ctl.encode()
+            drop: List[bytes] = []          # keys to delete before a read
+            while True:
+                if drop:
+                    client.execute("DEL", *drop)
+                    drop = []
+                with self._lock:    # after the control key's DEL: no
+                    joining, self._joining = self._joining, []  # join lost
+                    if self._stopping and not taps and not joining:
+                        return
+                for tap in joining:
+                    if tap.key in taps:
+                        tap.outcome = (f"a stream for {tap.uri!r} is "
+                                       f"already open")
+                        tap.done.set()
+                    else:
+                        taps[tap.key] = tap
+                reading = [t for t in taps.values() if not t.parked]
+                now = time.monotonic()
+                block = min([self.poll_s] + [
+                    min(t.deadline, t.quiet_since + self.poll_s) - now
+                    for t in reading])
+                client.send([("XREAD", "COUNT", _READ_COUNT, "BLOCK",
+                              max(1, int(block * 1000)), "STREAMS", ctl,
+                              *[t.key for t in reading], "0-0",
+                              *[t.tail.last for t in reading])])
+                self._await_reply(sel, taps)
+                reply = client.collect()[0] or ()
+                now = time.monotonic()
+                if reply:
+                    acct.wake()
+                fed = set()
+                for key, entries in reply:
+                    if key == ctl:
+                        drop.append(ctl)
+                        continue
+                    fed.add(key)
+                    self._write(sel, taps, taps[key], entries, now, acct,
+                                drop)
+                for tap in reading:
+                    if tap.key in fed or tap.done.is_set():
+                        continue
+                    if now >= tap.deadline:
+                        tap.ended = True
+                        drop.append(tap.key)
+                        tap.out.append(sse_event("error", {
+                            "uri": tap.uri,
+                            "error": f"no stream event for {tap.uri!r} "
+                                     f"in {self.timeout}s"}))
+                    elif now - tap.quiet_since >= self.poll_s:
+                        tap.out.append(b": ping\n\n")
+                    else:
+                        continue
+                    tap.quiet_since = now
+                    self._send(sel, taps, tap, now)
+        except Exception as e:
+            if not self._stopping:
+                logger.exception("the front door's stream hub failed")
+            failure = f"stream hub failed: {type(e).__name__}: {e}"
+        finally:
+            with self._lock:
+                left = list(taps.values()) + self._joining
+                self._joining = []
+                self._running = False
+            for tap in left:
+                if not tap.done.is_set():
+                    tap.outcome = failure
+                    tap.done.set()
+            sel.close()
+            if client is not None:
+                client.close()
+            self.fanout.close(acct)
+
+    def _await_reply(self, sel, taps: Dict[bytes, _Tap]) -> None:
+        """Wait for the broker's reply; meanwhile send to each parked
+        tap whose socket takes bytes again, and let go of a tap whose
+        client has taken nothing for ``timeout``."""
+        while True:
+            parked = [t for t in taps.values() if t.parked]
+            wait = None
+            if parked:
+                wait = max(0.0, min(t.stalled_since for t in parked)
+                           + self.timeout - time.monotonic())
+            answered = False
+            for key, _ in sel.select(wait):
+                if key.data is None:
+                    answered = True
+                else:
+                    self._send(sel, taps, key.data, time.monotonic())
+            now = time.monotonic()
+            for t in parked:
+                if t.parked and now - t.stalled_since >= self.timeout:
+                    self._finish(sel, taps, t, "disconnect")
+            if answered:
+                return
+
+    def _write(self, sel, taps, tap: _Tap, entries, now: float,
+               acct: FanoutAccount, drop: List[bytes]) -> None:
+        """One read's entries of one stream: one buffer, one send."""
+        events = tap.tail.take(entries)
+        if not events:
+            return
+        for ev in events:
+            if "token" in ev or "restart" in ev:
+                tap.deadline = now + self.timeout
+            elif any(k in ev for k in TERMINALS):
+                tap.ended = True
+                drop.append(tap.key)
+        tap.out.append(b"".join([_sse_frame(tap.uri, ev) for ev in events]))
+        tap.quiet_since = now
+        self._send(sel, taps, tap, now)
+        for ev in events:
+            acct.wrote(ev.get("stamp"))
+
+    def _send(self, sel, taps, tap: _Tap, now: float) -> None:
+        """Give the tap's frames to its socket as far as it takes them
+        without blocking; park it where it does not take them all."""
+        try:
+            while True:
+                if tap.pending is None:
+                    if not tap.out:
+                        break
+                    tap.pending = b"".join(tap.out)
+                    tap.out.clear()
+                n = tap.sock.send(tap.pending)
+                tap.pending = tap.pending[n:] or None
+                tap.stalled_since = None
+        except (BlockingIOError, ssl.SSLWantWriteError,
+                ssl.SSLWantReadError):
+            if tap.stalled_since is None:
+                tap.stalled_since = now
+            if not tap.parked:
+                sel.register(tap.sock, selectors.EVENT_WRITE, tap)
+                tap.parked = True
+            return
+        except OSError:
+            self._finish(sel, taps, tap, "disconnect")
+            return
+        if tap.parked:
+            sel.unregister(tap.sock)
+            tap.parked = False
+        if tap.ended:
+            self._finish(sel, taps, tap, "clean")
+
+    @staticmethod
+    def _finish(sel, taps, tap: _Tap, outcome: str) -> None:
+        if tap.parked:
+            sel.unregister(tap.sock)
+            tap.parked = False
+        taps.pop(tap.key, None)
+        tap.outcome = outcome
+        tap.done.set()
 
 
 class HttpFrontend:
@@ -165,10 +451,13 @@ class HttpFrontend:
         self.c_rejected = self.registry.counter(
             "zoo_http_backpressure_rejections_total",
             "requests answered 429 under a full admission queue")
-        # the stream writers' fan-out accounts: the serving job's clock
+        # the stream hub's fan-out account: the serving job's clock
         # (the process's), or one of the frontend's own when detached
         self.fanout = getattr(getattr(serving, "telemetry", None),
                               "fanout", None) or FanoutClock()
+        # one thread writes every SSE stream (started by the first)
+        self._hub = _StreamHub(redis_host, redis_port, timeout,
+                               self.fanout)
         self.c_disconnects = self.registry.counter(
             "zoo_http_stream_disconnects_total",
             "SSE clients that disconnected mid-stream (each triggers "
@@ -460,14 +749,11 @@ class HttpFrontend:
                     self._stream_sse(pair, uri)
 
             def _stream_sse(self, pair, uri):
-                """Tail the request's token stream onto the socket as
-                SSE.  A failed write means the client hung up: cancel
-                the request so its KV blocks free NOW, not at the TTL
-                prune.  The thread writes on its fan-out account: a wake
-                per stream read that brought entries, an event per
-                event written, and the pump's stamp on a token against
-                the clock after its ``flush()``."""
-                inq, outq = pair
+                """Write the SSE head and ``start`` event, hand the
+                stream to the front door's hub (``_StreamHub``), which
+                writes its events, and wait until it is over.  A client
+                that hung up is cancelled, so its KV blocks free NOW,
+                not at the TTL prune."""
                 self.send_response(200)
                 self.send_header("Content-Type", "text/event-stream")
                 self.send_header("Cache-Control", "no-cache")
@@ -475,83 +761,44 @@ class HttpFrontend:
                 self.send_header("X-Request-Id", uri)
                 self.end_headers()
                 self.close_connection = True
-                clean = False
-                acct = frontend.fanout.open("door")
                 try:
                     self.wfile.write(sse_event("start", {"uri": uri}))
                     self.wfile.flush()
-                    for ev in outq.stream_events(
-                            uri, timeout=frontend.timeout, account=acct):
-                        if "ping" in ev:
-                            # heartbeat: touches the socket so a dead
-                            # client surfaces between tokens
-                            self.wfile.write(b": ping\n\n")
-                            self.wfile.flush()
-                            continue
-                        elif "token" in ev:
-                            self.wfile.write(sse_event(
-                                "token", {"index": ev["index"],
-                                          "token": ev["token"]}))
-                        elif "restart" in ev:
-                            # crash-recovery redispatch: the token
-                            # index resets to 0 and the generation
-                            # re-streams — the client must drop what
-                            # it buffered, never splice
-                            self.wfile.write(sse_event(
-                                "restart",
-                                {"uri": uri,
-                                 "attempt": ev["restart"]}))
-                        elif "done" in ev:
-                            self.wfile.write(sse_event(
-                                "done", {"uri": uri}))
-                            clean = True
-                        elif "cancelled" in ev:
-                            self.wfile.write(sse_event(
-                                "cancelled", {"uri": uri}))
-                            clean = True
-                        else:
-                            err = ev.get("error", "")
-                            # admission-time deadline sheds get their
-                            # OWN terminal event so clients can
-                            # distinguish "arrived too late" from a
-                            # server-side failure without parsing text
-                            kind = ("deadline_exceeded"
-                                    if "deadline_exceeded" in err
-                                    else "error")
-                            self.wfile.write(sse_event(
-                                kind, {"uri": uri, "error": err}))
-                            clean = True
-                        self.wfile.flush()
-                        acct.wrote(ev.get("stamp"))
-                        if clean:
-                            break
-                except (BrokenPipeError, ConnectionResetError,
-                        OSError):
+                    self.connection.setblocking(False)
+                    tap = frontend._hub.follow(uri, self.connection,
+                                               pair[0].client)
+                except OSError:
+                    outcome = "disconnect"
+                else:
+                    frontend._release(pair)
+                    pair = None
+                    tap.done.wait()
+                    outcome = tap.outcome
+                    if outcome not in ("clean", "disconnect"):
+                        # the hub let go of the stream unfinished: the
+                        # client gets what was left and an error event
+                        try:
+                            self.connection.setblocking(True)
+                            self.connection.sendall(
+                                tap.unsent() + sse_event(
+                                    "error", {"uri": uri,
+                                              "error": outcome}))
+                        except OSError:
+                            outcome = "disconnect"
+                if outcome == "disconnect":
                     frontend._count_disconnect(uri)
                     try:
-                        inq.cancel(uri)
+                        pair = pair or frontend._acquire()
+                        pair[0].cancel(uri)
                     except Exception:
                         logger.exception(
                             "disconnect cancel failed for %r", uri)
-                except TimeoutError as e:
-                    try:
-                        self.wfile.write(sse_event(
-                            "error", {"uri": uri, "error": str(e)}))
-                        self.wfile.flush()
-                    except OSError:
-                        pass
-                    clean = True
-                finally:
-                    frontend.fanout.close(acct)
-                if clean:
+                        if pair is not None:
+                            pair[0].close()
+                            pair[1].close()
+                        pair = None
+                if pair is not None:
                     frontend._release(pair)
-                else:
-                    # abandoned mid-generator: the RESP read state is
-                    # clean (each execute completed) but the token
-                    # stream wasn't consumed — don't pool a pair whose
-                    # tok: key may still receive events
-                    pair[0].close()
-                    pair[1].close()
 
         self._server = ThreadingHTTPServer(("0.0.0.0", http_port), Handler)
         if certfile:
@@ -838,6 +1085,7 @@ class HttpFrontend:
         return self
 
     def stop(self):
+        self._hub.stop()
         self._server.shutdown()
         self._server.server_close()
         if self._thread is not None:

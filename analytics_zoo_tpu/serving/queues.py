@@ -70,6 +70,63 @@ class ImageBytes(bytes):
     dense-tensor columns."""
 
 
+#: The events that end a token stream; the reader deletes the key after one.
+TERMINALS = ("done", "cancelled", "error")
+
+
+class TokenTail:
+    """One reader's place in a request's token stream (``tok:<uri>``):
+    the id it has read to and the next token index it expects.
+    :meth:`take` turns a read's entries into events, in order, with the
+    stream's rules: a re-emitted token (a preempted row regenerates its
+    tokens deterministically) is dropped by index, a ``restart``
+    (crash-recovery redispatch) resets the index to 0, and the first
+    terminal ends the list.  ``OutputQueue.stream_events`` and the front
+    door's stream hub both read through it."""
+
+    __slots__ = ("key", "last", "next_index")
+
+    def __init__(self, uri: str):
+        self.key = TOKEN_PREFIX + uri
+        self.last = b"0-0"
+        self.next_index = 0
+
+    def take(self, entries) -> list:
+        """The events of ``entries`` (``[[id, flat fields], ...]``): a
+        token ``{"token", "index"}`` (and ``"stamp"``, the pump's flush
+        stamp, where the entry carries field ``s``), ``{"restart": n}``,
+        then at most one terminal, last."""
+        out = []
+        for eid, flat in entries:
+            self.last = eid
+            f = {flat[i].decode(): flat[i + 1]
+                 for i in range(0, len(flat), 2)}
+            if "restart" in f:
+                # the replay starts over at index 0: the dedup watermark
+                # resets, or every re-emitted token is swallowed
+                self.next_index = 0
+                out.append({"restart": int(f["restart"])})
+            elif "t" in f:
+                idx = int(f.get("i", b"-1"))
+                if idx < self.next_index:       # preemption re-emission
+                    continue
+                self.next_index = idx + 1
+                ev = {"token": int(f["t"]), "index": idx}
+                if "s" in f:
+                    ev["stamp"] = int(f["s"])
+                out.append(ev)
+            elif "done" in f:
+                out.append({"done": True})
+                break
+            elif "cancelled" in f:
+                out.append({"cancelled": True})
+                break
+            elif "error" in f:
+                out.append({"error": f["error"].decode(errors="replace")})
+                break
+        return out
+
+
 class InputQueue:
     """ref-parity: InputQueue(host, port).enqueue(uri, key=ndarray, ...)"""
 
@@ -199,7 +256,7 @@ class OutputQueue:
         return decode_ndarray(fields["value"])
 
     def stream_events(self, uri: str, timeout: float = 30.0,
-                      poll_s: float = 1.0, account=None):
+                      poll_s: float = 1.0):
         """Tail the per-token stream of a ``stream=True`` request.
 
         Yields dicts in emission order: ``{"token": t, "index": i}``
@@ -219,61 +276,29 @@ class OutputQueue:
         Raises ``TimeoutError`` when no event lands for ``timeout``
         seconds.  A token event whose entry carries the pump's flush
         stamp (field ``s``, µs of ``time.monotonic()``) has it under
-        ``"stamp"``.  ``account`` (a ``telemetry.FanoutAccount``) books
-        a wake for each read that returned entries."""
-        key = TOKEN_PREFIX + uri
-        last = b"0-0"
-        next_index = 0
+        ``"stamp"``."""
+        tail = TokenTail(uri)
         deadline = time.monotonic() + timeout
         while True:
             remaining = deadline - time.monotonic()
             if remaining <= 0:
-                self.client.execute("DEL", key)
+                self.client.execute("DEL", tail.key)
                 raise TimeoutError(
                     f"no stream event for {uri!r} in {timeout}s")
             block_ms = max(1, int(min(remaining, poll_s) * 1000))
             resp = self.client.execute(
                 "XREAD", "COUNT", 256, "BLOCK", block_ms,
-                "STREAMS", key, last)
+                "STREAMS", tail.key, tail.last)
             if not resp:
                 yield {"ping": True}
                 continue
-            if account is not None:
-                account.wake()
-            for eid, flat in resp[0][1]:
-                last = eid
-                f = {flat[i].decode(): flat[i + 1]
-                     for i in range(0, len(flat), 2)}
-                if "restart" in f:
-                    # crash-recovery redispatch: the replay starts
-                    # over at index 0, so the dedup watermark must
-                    # reset or every re-emitted token gets swallowed
-                    next_index = 0
-                    deadline = time.monotonic() + timeout
-                    yield {"restart": int(f["restart"])}
-                elif "t" in f:
-                    idx = int(f.get("i", b"-1"))
-                    if idx < next_index:    # preemption re-emission
-                        continue
-                    next_index = idx + 1
-                    deadline = time.monotonic() + timeout
-                    ev = {"token": int(f["t"]), "index": idx}
-                    if "s" in f:
-                        ev["stamp"] = int(f["s"])
+            for ev in tail.take(resp[0][1]):
+                if any(k in ev for k in TERMINALS):
+                    self.client.execute("DEL", tail.key)
                     yield ev
-                elif "done" in f:
-                    self.client.execute("DEL", key)
-                    yield {"done": True}
                     return
-                elif "cancelled" in f:
-                    self.client.execute("DEL", key)
-                    yield {"cancelled": True}
-                    return
-                elif "error" in f:
-                    self.client.execute("DEL", key)
-                    yield {"error":
-                           f["error"].decode(errors="replace")}
-                    return
+                deadline = time.monotonic() + timeout
+                yield ev
 
     def dequeue(self) -> Dict[str, np.ndarray]:
         """Drain every available result (ref: OutputQueue.dequeue).

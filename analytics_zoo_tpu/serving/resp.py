@@ -112,13 +112,24 @@ class _Reader:
 
 class _ServerReader(_Reader):
     """The broker's side of a connection: a ``recv`` that returned
-    bytes is a wake of the connection thread (its fan-out account)."""
+    bytes is a wake of the connection thread (its fan-out account).
+    Replies wait in ``replies`` until the thread has run every command
+    it holds (a pipeline's replies leave in one ``sendall``, as a Redis
+    server writes them), and leave before it waits for more bytes or
+    blocks in a read."""
 
     def __init__(self, sock: socket.socket, acct: FanoutAccount):
         super().__init__(sock)
         self.acct = acct
+        self.replies: List[bytes] = []
+
+    def flush(self) -> None:
+        if self.replies:
+            self.sock.sendall(b"".join(self.replies))
+            self.replies.clear()
 
     def _recv(self) -> bytes:
+        self.flush()
         chunk = super()._recv()
         self.acct.wake()
         return chunk
@@ -137,7 +148,10 @@ class Stream:
         self.entries: List[Tuple[bytes, List[bytes]]] = []  # (id, kv flat)
         self.seq = itertools.count(1)
         self.last_ms = 0
-        self.cond = threading.Condition()
+        self.lock = threading.Lock()
+        # the Events of the reads blocked on this stream: an add sets
+        # each, so a read that names many streams wakes on any of them
+        self.waiters: set = set()
         # consumer groups: name -> {"last": delivered-up-to id,
         #                           "pending": {id: consumer}}
         # (the mechanism behind horizontally-scaled serving workers —
@@ -145,13 +159,14 @@ class Stream:
         self.groups: Dict[bytes, Dict] = {}
 
     def add(self, fields: List[bytes]) -> bytes:
-        with self.cond:
+        with self.lock:
             # ids only grow, as Redis's do, whatever the wall clock does:
             # ``after`` reads the entries as sorted
             self.last_ms = max(self.last_ms, int(time.time() * 1000))
             eid = f"{self.last_ms}-{next(self.seq)}".encode()
             self.entries.append((eid, fields))
-            self.cond.notify_all()
+            for w in self.waiters:
+                w.set()
         return eid
 
     def after(self, last: bytes) -> List[Tuple[bytes, List[bytes]]]:
@@ -159,7 +174,7 @@ class Stream:
         list's tail, found from its end, so that a reader who follows a
         stream pays for what is new and not for all it has read (a token
         stream holds a whole answer until its reader deletes it).  Called
-        with ``cond`` held."""
+        with ``lock`` held."""
         key = _parse_id(last)
         i = len(self.entries)
         while i and _parse_id(self.entries[i - 1][0]) > key:
@@ -199,29 +214,39 @@ def _scan_read_opts(args: List[bytes], i: int):
     return count, block_ms, i
 
 
-def _await_fresh(s: "Stream", block_ms, select,
+def _await_fresh(streams: List["Stream"], block_ms, select,
                  acct: Optional[FanoutAccount] = None):
-    """Run `select()` under s.cond until it yields entries or the block
-    window expires.  select() may mutate claim state (XREADGROUP) — it is
-    always called with the stream lock held, so claims are atomic.  A
-    read that blocked and was woken with entries is a wake of ``acct``."""
-    deadline = None if block_ms is None else \
-        time.monotonic() + block_ms / 1000.0
-    waited = False
-    while True:
-        with s.cond:
-            got = select()
+    """Run ``select()`` until it yields entries or the block window
+    expires (no ``BLOCK``: once).  A blocked read waits on one Event
+    that an add to ANY of ``streams`` sets, and on nothing else.
+    ``select()`` takes the stream locks it needs (an XREADGROUP claim
+    is made under its stream's lock, so claims are atomic).  A read
+    that blocked and was woken with entries is a wake of ``acct``."""
+    if block_ms is None:
+        return select() or None
+    deadline = time.monotonic() + block_ms / 1000.0
+    woken = threading.Event()
+    for s in streams:
+        with s.lock:
+            s.waiters.add(woken)
+    try:
+        waited = False
+        while True:
+            got = select()      # after the waiter is in: no add is lost
             if got:
                 if waited and acct is not None:
                     acct.wake()
                 return got
-            if deadline is None:
-                return None
             remaining = deadline - time.monotonic()
             if remaining <= 0:
                 return None
-            s.cond.wait(remaining)
+            woken.wait(remaining)
+            woken.clear()
             waited = True
+    finally:
+        for s in streams:
+            with s.lock:
+                s.waiters.discard(woken)
 
 
 class RespServer:
@@ -284,15 +309,17 @@ class RespServer:
                 req = reader.read()
                 if req is None:
                     return
+                args = [bytes(x) if isinstance(x, (bytes, bytearray))
+                        else str(x).encode() for x in req]
+                if args and args[0].upper() in (b"XREAD", b"XREADGROUP"):
+                    reader.flush()      # answer what came before a block
                 try:
-                    resp = self._dispatch([bytes(x) if isinstance(
-                        x, (bytes, bytearray)) else str(x).encode()
-                        for x in req], acct)
+                    resp = self._dispatch(args, acct)
                 except RedisError as e:
                     resp = e
                 except Exception as e:  # command bug -> error reply
                     resp = RedisError(str(e))
-                conn.sendall(encode(resp))
+                reader.replies.append(encode(resp))
         except (ConnectionError, OSError):
             pass
         finally:
@@ -300,6 +327,33 @@ class RespServer:
             self.fanout.close(acct)
 
     # ---- commands -----------------------------------------------------
+
+    def _xread(self, keys: List[bytes], ids: List[bytes], count, block_ms,
+               acct: Optional[FanoutAccount]):
+        """``XREAD`` over one stream or many, with Redis's semantics: the
+        reply holds ``[key, entries]`` for each named stream that has
+        entries past its id (at most ``count`` each), or nil; ``$`` is
+        the stream's last id when the read arrives.  A blocked read
+        wakes on an add to any of its streams (``_await_fresh``)."""
+        streams = [self._stream(k) for k in keys]
+        lasts = []
+        for s, last in zip(streams, ids):
+            if last == b"$":
+                with s.lock:
+                    last = s.entries[-1][0] if s.entries else b"0-0"
+            lasts.append(last)
+
+        def select():
+            out = []
+            for key, s, last in zip(keys, streams, lasts):
+                with s.lock:
+                    fresh = s.after(last)
+                if fresh:
+                    out.append([key, [[eid, fv] for eid, fv in
+                                      (fresh[:count] if count else fresh)]])
+            return out
+
+        return _await_fresh(streams, block_ms, select, acct)
 
     def _stream(self, key: bytes) -> Stream:
         with self.lock:
@@ -385,14 +439,14 @@ class RespServer:
             count = int(args[5]) if len(args) > 5 and \
                 args[4].upper() == b"COUNT" else None
 
-            with s.cond:
+            with s.lock:
                 got = [[eid, fv] for eid, fv in s.entries
                        if lo <= _parse_id(eid) <= hi]
             return got[:count] if count else got
         if cmd == b"XDEL":
             s = self._stream(args[1])
             ids = set(args[2:])
-            with s.cond:
+            with s.lock:
                 before = len(s.entries)
                 s.entries = [e for e in s.entries if e[0] not in ids]
                 return before - len(s.entries)
@@ -400,34 +454,27 @@ class RespServer:
             s = self._stream(args[1])
             # XTRIM key MAXLEN n
             n = int(args[3])
-            with s.cond:
+            with s.lock:
                 cut = max(0, len(s.entries) - n)
                 s.entries = s.entries[cut:]
                 return cut
         if cmd == b"XREAD":
-            # XREAD [COUNT c] [BLOCK ms] STREAMS key id
+            # XREAD [COUNT c] [BLOCK ms] STREAMS key [key ...] id [id ...]
             count, block_ms, i = _scan_read_opts(args, 1)
-            key, last = args[i + 1], args[i + 2]
-            s = self._stream(key)
-            if last == b"$":
-                with s.cond:
-                    last = s.entries[-1][0] if s.entries else b"0-0"
-
-            def select():
-                fresh = s.after(last)
-                return fresh[:count] if count else fresh
-
-            got = _await_fresh(s, block_ms, select, acct)
-            if got is None:
-                return None
-            return [[key, [[eid, fv] for eid, fv in got]]]
+            rest = args[i + 1:]
+            if not rest or len(rest) % 2:
+                raise RedisError("Unbalanced 'xread' list of streams: for "
+                                 "each stream key an ID or '$' must be "
+                                 "specified.")
+            n = len(rest) // 2
+            return self._xread(rest[:n], rest[n:], count, block_ms, acct)
         if cmd == b"XGROUP":
             # XGROUP CREATE key group id [MKSTREAM]
             if args[1].upper() != b"CREATE":
                 raise RedisError("only XGROUP CREATE is supported")
             s = self._stream(args[2])
             start = args[4]
-            with s.cond:
+            with s.lock:
                 if args[3] in s.groups:
                     raise RedisError("BUSYGROUP Consumer Group name "
                                      "already exists")
@@ -443,35 +490,36 @@ class RespServer:
             if cursor != b">":
                 raise RedisError("only the '>' cursor is supported")
             s = self._stream(key)
-            with s.cond:
+            with s.lock:
                 if group not in s.groups:
                     raise RedisError(
                         f"NOGROUP no such consumer group {group.decode()}")
 
             def select():
-                # atomic claim under s.cond (held by _await_fresh):
-                # advance the group pointer so no other consumer sees these
-                g = s.groups.get(group)
-                if g is None:
-                    return None
-                fresh = s.after(g["last"])
-                if not fresh:
-                    return None
-                if count:
-                    fresh = fresh[:count]
-                g["last"] = fresh[-1][0]
-                for eid, _ in fresh:
-                    g["pending"][eid] = consumer
-                return fresh
+                # atomic claim under the stream's lock: advance the group
+                # pointer so no other consumer sees these
+                with s.lock:
+                    g = s.groups.get(group)
+                    if g is None:
+                        return None
+                    fresh = s.after(g["last"])
+                    if not fresh:
+                        return None
+                    if count:
+                        fresh = fresh[:count]
+                    g["last"] = fresh[-1][0]
+                    for eid, _ in fresh:
+                        g["pending"][eid] = consumer
+                    return fresh
 
-            got = _await_fresh(s, block_ms, select, acct)
+            got = _await_fresh([s], block_ms, select, acct)
             if got is None:
                 return None
             return [[key, [[eid, fv] for eid, fv in got]]]
         if cmd == b"XACK":
             # XACK key group id [id ...]
             s = self._stream(args[1])
-            with s.cond:
+            with s.lock:
                 g = s.groups.get(args[2])
                 if g is None:
                     return 0
@@ -482,7 +530,7 @@ class RespServer:
         if cmd == b"XPENDING":
             # XPENDING key group -> [count, min-id, max-id, consumers]
             s = self._stream(args[1])
-            with s.cond:
+            with s.lock:
                 g = s.groups.get(args[2])
                 if g is None:
                     return [0, None, None, None]

@@ -421,7 +421,7 @@ class ClusterServing:
         # zoo_engine_* metrics and the event ring interleaves engine
         # spans with serving-side terminal events (abandonment).  Its
         # fan-out clock is the process's: the embedded broker's
-        # connection threads and the front door's stream writers book
+        # connection threads and the front door's stream hub book
         # there, and every engine reads it with a cursor of its own
         self.telemetry = Telemetry()
         self.broker: Optional[RespServer] = None

@@ -20,7 +20,7 @@ surfaces:
   named phase, wall and CPU, with no remainder.
 - :class:`FanoutClock` — the same clock for the threads that carry a
   token from the pump to a client (the broker's connection threads,
-  the front door's stream writers): CPU, wake-ups, events written and
+  the front door's stream hub): CPU, wake-ups, events written and
   the fan-out's delay on a token, one account per thread, read by
   each engine into its flight record.
 - :class:`Telemetry` — the per-engine facade: request-lifecycle hooks
@@ -496,7 +496,8 @@ class LapClock:
 
 #: The kinds of fan-out thread: ``broker`` — a connection thread of the
 #: embedded RESP broker (``resp.RespServer``); ``door`` — the front
-#: door's thread that writes one SSE stream (``http_frontend``).
+#: door's stream hub, the one thread that writes every SSE stream
+#: (``http_frontend._StreamHub``).
 FANOUT_KINDS = ("broker", "door")
 
 #: A fan-out thread reads its CPU clock at a wake only where this many

@@ -169,10 +169,14 @@ def test_the_cpu_clock_is_read_at_most_once_a_gate(monkeypatch):
     clock.close(a)
 
 
-def test_lag_field_travels_from_the_flush_to_the_sse_write():
+def test_lag_field_travels_from_the_flush_to_the_sse_write(monkeypatch):
     """``_flush_emitter`` stamps each token entry; the broker keeps the
-    field; ``stream_events`` passes it on; the SSE writer books write
+    field; ``TokenTail`` passes it on; the SSE writer books write
     time minus stamp.  An entry without the field books no lag."""
+    from analytics_zoo_tpu.serving import telemetry
+    # the front door's writer (its stream hub) outlives the stream: its
+    # CPU is booked at a wake past the gate, not when a stream ends
+    monkeypatch.setattr(telemetry, "FANOUT_CPU_READ_S", 0.0)
     clock = FanoutClock()
     broker = RespServer(port=0, fanout=clock).start()
     fe = HttpFrontend(redis_port=broker.port, timeout=30).start()
